@@ -11,7 +11,9 @@ import sys
 
 def main() -> None:
     # exec-safe dots: benchmarks execute on CPU
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.models.layers import set_exec_safe
+    enable_compile_cache()
     set_exec_safe(True)
 
     from . import (arch_dse, fig2_param_sweep, fig7_significance, fig9_dse,

@@ -103,7 +103,7 @@ def pareto_front(grid: np.ndarray, wl: Workload,
                  metrics: Sequence[str] = DEFAULT_OBJECTIVES,
                  constraints: Optional[Constraints] = None, *,
                  engine: str = "numpy", hierarchical: bool = False,
-                 c: DeviceConstants = CONSTANTS, interpret: bool = True,
+                 c: DeviceConstants = CONSTANTS, interpret: Optional[bool] = None,
                  calibration=None, robust: Optional[str] = None):
     """(front_rows, front_metrics) of non-dominated feasible configs.
 
@@ -141,7 +141,7 @@ def pareto_search_refined(wl: Workload,
                           metrics: Sequence[str] = DEFAULT_OBJECTIVES,
                           hierarchical: bool = True,
                           c: DeviceConstants = CONSTANTS,
-                          interpret: bool = True,
+                          interpret: Optional[bool] = None,
                           calibration=None,
                           robust: Optional[str] = None):
     """Two-pass significance-guided frontier search (Alg. 1 -> Alg. 2).

@@ -114,17 +114,8 @@ class KillSearch(BaseException):
 
 def _retryable_exceptions() -> tuple:
     """Exception types the per-launch retry treats as transient."""
-    excs = [LaunchError, LaunchTimeout]
-    try:
-        from jax.errors import JaxRuntimeError
-        excs.append(JaxRuntimeError)
-    except ImportError:  # pragma: no cover — very old jax
-        try:
-            from jax.lib.xla_extension import XlaRuntimeError
-            excs.append(XlaRuntimeError)
-        except ImportError:
-            pass
-    return tuple(excs)
+    from jax.errors import JaxRuntimeError
+    return (LaunchError, LaunchTimeout, JaxRuntimeError)
 
 
 @dataclasses.dataclass(frozen=True)

@@ -343,7 +343,7 @@ def dxpta_search(wl: Workload, constraints: Constraints = Constraints(),
                  align_dims: Optional[Sequence[int]] = None,
                  prune: Union[bool, str] = True, collect: bool = False,
                  c: DeviceConstants = CONSTANTS, engine: str = "python",
-                 interpret: bool = True, factorized: bool = False,
+                 interpret: Optional[bool] = None, factorized: bool = False,
                  calibration=None,
                  robust: Optional[str] = None) -> SearchResult:
     """The paper's constraint-aware search (Alg. 2).
@@ -1029,7 +1029,6 @@ def _jax_sharded_fn(fn, k: int, mode: str):
     stable) + mesh size — streamed chunk launches reuse one executable."""
     import jax
 
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.launch.mesh import make_candidate_mesh
@@ -1049,9 +1048,9 @@ def _jax_sharded_fn(fn, k: int, mode: str):
             return mask, f[None]
         out_specs = (spec1, spec1)
 
-    return jax.jit(shard_map(body, mesh=mesh,
-                             in_specs=(spec2, spec1, P(None)),
-                             out_specs=out_specs, check_rep=False))
+    return jax.jit(jax.shard_map(body, mesh=mesh,
+                                 in_specs=(spec2, spec1, P(None)),
+                                 out_specs=out_specs, check_vma=False))
 
 
 def _jax_sharded_argmin(fn, sub, cons_vec, shard):
@@ -1118,9 +1117,11 @@ def _rt_fp(tag, wl, constraints, engine, c, interpret, shard, chunk_size,
     on the same engine the head ran on (degradation within a run is fine —
     engines are byte-identical — but resuming under a different engine=
     is a different campaign)."""
+    from repro.kernels.backend import resolve_interpret
     return _fingerprint(tag=tag, wl=wl.name, gemms=wl.gemm_array,
                         act=int(wl.max_act_bytes), cons=repr(constraints),
-                        engine=engine, c=repr(c), interpret=bool(interpret),
+                        engine=engine, c=repr(c),
+                        interpret=resolve_interpret(interpret),
                         shard=shard, chunk=chunk_size, **extra)
 
 
@@ -1614,7 +1615,6 @@ def _jax_factorized_sharded_fn(fn, k: int, mode: str):
     (the 1-D analogue of `_jax_sharded_fn`)."""
     import jax
 
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.launch.mesh import make_candidate_mesh
@@ -1634,9 +1634,9 @@ def _jax_factorized_sharded_fn(fn, k: int, mode: str):
             return mask, f[None]
         out_specs = (spec1, spec1)
 
-    return jax.jit(shard_map(body, mesh=mesh,
-                             in_specs=(spec1, spec1, P(None)),
-                             out_specs=out_specs, check_rep=False))
+    return jax.jit(jax.shard_map(body, mesh=mesh,
+                                 in_specs=(spec1, spec1, P(None)),
+                                 out_specs=out_specs, check_vma=False))
 
 
 def _padded_idx_operands(idx_arr, multiple: int):
@@ -2952,7 +2952,7 @@ def _robust_vertex_search(wl, constraints, cal, engine, grid, n_z,
 def search(wl: Workload, constraints: Constraints = Constraints(), *,
            engine: str = "numpy", grid: Optional[np.ndarray] = None,
            n_z: int = 12, hierarchical: bool = False,
-           c: DeviceConstants = CONSTANTS, interpret: bool = True,
+           c: DeviceConstants = CONSTANTS, interpret: Optional[bool] = None,
            objective: str = "edp",
            pareto_metrics: tuple = DEFAULT_OBJECTIVES,
            shard: Optional[int] = None, chunk_size: Optional[int] = None,
@@ -2977,7 +2977,8 @@ def search(wl: Workload, constraints: Constraints = Constraints(), *,
         grid, then workload evaluation on the survivors only. Safe in both
         modes: prefilter losers are area/power-infeasible, so they can't be
         the min-EDP pick or on the feasible frontier.
-      interpret: Pallas interpret mode (CPU); pass False on a real TPU.
+      interpret: Pallas interpret mode; None (the default) follows the
+        backend (see `repro.kernels.backend.resolve_interpret`).
       objective: "edp" — feasible min-EDP point (a SearchResult) — or
         "pareto" — the whole non-dominated feasible set over
         `pareto_metrics` (a ParetoResult). Frontier backends propose
@@ -3293,7 +3294,7 @@ def search_workloads(wls: Union[Mapping[str, Workload], Sequence[Workload]],
                      grid: Optional[np.ndarray] = None, n_z: int = 12,
                      hierarchical: bool = False,
                      c: DeviceConstants = CONSTANTS,
-                     interpret: bool = True, objective: str = "edp",
+                     interpret: Optional[bool] = None, objective: str = "edp",
                      pareto_metrics: tuple = DEFAULT_OBJECTIVES,
                      shard: Optional[int] = None,
                      chunk_size: Optional[int] = None,

@@ -1,7 +1,8 @@
 """Pallas TPU kernels for the perf-critical compute of the DxPTA system:
 the photonic DDot GEMM simulation (4-bit QAT/serving path) and the DSE
-config-grid evaluator. Validated on CPU with interpret=True against the
-pure-jnp oracles in ref.py.
+config-grid evaluator. They compile with Mosaic on a TPU and run in
+interpret mode on the CPU backend (`backend.resolve_interpret`), where
+they are validated against the pure-jnp oracles in ref.py.
 """
 from .ops import (ddot_matmul, decode_rows_device, dse_eval_grid,
                   dse_pareto_multi, dse_pareto_multi_factorized,

@@ -21,11 +21,14 @@ vs the integer reference.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .backend import resolve_interpret
 
 QMAX = 7.0  # symmetric 4-bit: values in [-7, 7]
 
@@ -59,7 +62,7 @@ def _ddot_kernel(noise_rms: float, nk: int,
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "noise_rms",
                                              "interpret"))
 def ddot_gemm_quantized(qa, qb, sa, sb, z, *, bm=256, bn=256, bk=512,
-                        noise_rms: float = 0.0, interpret: bool = True):
+                        noise_rms: float = 0.0, interpret: Optional[bool] = None):
     """Blocked quantized GEMM on pre-quantized operands.
 
     Args:
@@ -91,5 +94,5 @@ def ddot_gemm_quantized(qa, qb, sa, sb, z, *, bm=256, bn=256, bk=512,
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32),
                         pltpu.VMEM((bm, bn), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(qa, qb, sa, sb, z)

@@ -52,12 +52,15 @@ tested against (see kernels/ref.py).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core.photonic_model import DeviceConstants
+
+from .backend import resolve_interpret
 
 BLOCK = 2048  # configs per grid step (16 sublane rows x 128 lanes)
 
@@ -66,9 +69,15 @@ BLOCK = 2048  # configs per grid step (16 sublane rows x 128 lanes)
 # the block can be much wider than the grid-operand kernels': under
 # interpret mode the per-block dispatch overhead dominates the whole
 # launch, and 8x wider blocks cut it 8x (the decoded frontier kernel keeps
-# BLOCK — its pairwise dominance pass is O(block^2)). Mosaic VMEM limits
-# for this width on real TPUs are untested; see ROADMAP open items.
+# BLOCK — its pairwise dominance pass is O(block^2)). The decoded search
+# tile is a dense (DECODE_BLOCK // LANES, LANES) array, 64 KiB per float32
+# temporary, well inside the chip's scoped VMEM.
 DECODE_BLOCK = 16384
+
+# TPU vector lane width: per-block reduction outputs are stored as
+# lane-dense (rows, LANES) tiles (a 1-lane block is not a legal Mosaic
+# block) and the jitted wrapper keeps lane 0 of each.
+LANES = 128
 
 # Per-workload rows in the fused-search reduction output.
 SEARCH_ROWS = 3  # (best_edp, best_idx, n_feasible)
@@ -85,7 +94,7 @@ MAX_FRONT = 128
 PARETO_HEADER = 2  # (local front count, block feasible count)
 PARETO_ROWS = PARETO_HEADER + MAX_FRONT
 
-# Column chunk of the in-kernel pairwise dominance pass ((DOM_CHUNK, BLOCK)
+# Row chunk of the in-kernel pairwise dominance pass ((DOM_CHUNK, BLOCK)
 # comparison tiles instead of one (BLOCK, BLOCK) matrix).
 DOM_CHUNK = 256
 
@@ -195,37 +204,50 @@ def _config_metrics(gemms, wl_scalars, c: DeviceConstants,
 
 
 def _cfg_cols(cfg_ref):
-    return (cfg_ref[0, :], cfg_ref[1, :], cfg_ref[2, :], cfg_ref[3, :],
-            cfg_ref[4, :])
+    """The five (1, BLOCK) config rows of a grid-operand tile."""
+    return tuple(cfg_ref[r:r + 1, :] for r in range(5))
+
+
+def _lane_iota(shape, dtype=jnp.int32):
+    """Row-major position of every element of a 2-D tile (a 1-D iota does
+    not lower on the TPU)."""
+    rows = jax.lax.broadcasted_iota(dtype, shape, 0)
+    lanes = jax.lax.broadcasted_iota(dtype, shape, 1)
+    return rows * shape[1] + lanes
+
+
+def _store_scalars(out_ref, r0, vals):
+    """Write scalars to rows r0, r0 + 1, ... of a (rows, LANES) output
+    tile, each broadcast across the lanes (the wrapper keeps lane 0)."""
+    for k, v in enumerate(vals):
+        out_ref[r0 + k:r0 + k + 1, :] = jnp.full((1, LANES), v, jnp.float32)
 
 
 def _dse_kernel(gemms, wl_scalars, c: DeviceConstants, cfg_ref, out_ref):
     area, power, energy, latency = _config_metrics(
         gemms, wl_scalars, c, *_cfg_cols(cfg_ref))
-    out_ref[0, :] = area
-    out_ref[1, :] = power
-    out_ref[2, :] = energy
-    out_ref[3, :] = latency
+    out_ref[0:1, :] = area
+    out_ref[1:2, :] = power
+    out_ref[2:3, :] = energy
+    out_ref[3:4, :] = latency
 
 
-def _decode_block(radices, axes_ref, meta_ref, block=BLOCK):
+def _decode_block(radices, axes_ref, meta_ref, shape=(1, BLOCK)):
     """On-device candidate generation: one block's configs from its index.
 
     The factorized kernels never see a (5, G) config operand — each lane
     reconstructs its own candidate row from the launch's base offset plus
     the per-axis candidate vectors:
 
-      global index = meta[0, 0] (chunk base) + program_id * BLOCK + lane,
+      global index = meta[0, 0] (chunk base) + program_id * block
+                     + row-major position in the `shape` tile,
 
     mixed-radix decoded with the static `radices` (meshgrid axis order
     t, c, v, h, lambda — N_lambda fastest) via the same
     core.factorized.decode_digits the host engines use — host and device
-    decodes cannot diverge — then mapped to candidate values with one
-    clamped gather per axis out of the axes_ref row (the previous one-hot
-    select cost `radix` vector selects per axis; the gather is a single
-    take, which is what makes the decoded engines beat their grid-operand
-    counterparts under interpret mode — Mosaic lowering of the 1-D gather
-    is an open item in ROADMAP.md).
+    decodes cannot diverge — then mapped to candidate values with a
+    clamped select chain per axis over the axes_ref row (`radix - 1`
+    vector selects: the TPU lowers no 1-D gather).
 
     Validity is a *slab* test, not just a span test: meta rows are
     [start, end, lo_t, hi_t, lo_c, hi_c, lo_v, hi_v, lo_h, hi_h,
@@ -239,14 +261,14 @@ def _decode_block(radices, axes_ref, meta_ref, block=BLOCK):
     non-members) gather a clamped — still valid, never div-by-zero —
     candidate value and are masked out of every reduction.
 
-    Returns ((n_t, n_c, n_h, n_v, n_lambda) float32 columns, float32 global
-    indices, validity mask). Emitted indices are exact for spaces below
-    2**24 points (float32 mantissa), like every kernel index here.
+    Returns ((n_t, n_c, n_h, n_v, n_lambda) float32 `shape` tiles, float32
+    global indices, validity mask). Emitted indices are exact for spaces
+    below 2**24 points (float32 mantissa), like every kernel index here.
     """
     from repro.core.factorized import decode_digits
 
-    gidx = (meta_ref[0, 0] + pl.program_id(0) * block
-            + jax.lax.iota(jnp.int32, block))
+    block = shape[0] * shape[1]
+    gidx = (meta_ref[0, 0] + pl.program_id(0) * block + _lane_iota(shape))
     digits = decode_digits(gidx, radices, jnp)
     d_t, d_c, d_v, d_h, d_l = digits
 
@@ -256,7 +278,11 @@ def _decode_block(radices, axes_ref, meta_ref, block=BLOCK):
             & (d < meta_ref[0, 3 + 2 * ax])
 
     def pick(row, digit):
-        return jnp.take(axes_ref[row, :], digit, axis=0, mode="clip")
+        # axes[row, clip(digit, 0, radix - 1)] as an ascending select chain.
+        val = jnp.full(shape, axes_ref[row, 0], jnp.float32)
+        for k in range(1, int(radices[row])):
+            val = jnp.where(digit >= k, axes_ref[row, k], val)
+        return val
 
     cols = (pick(0, d_t), pick(1, d_c), pick(3, d_h),
             pick(2, d_v), pick(4, d_l))
@@ -298,10 +324,13 @@ def _search_reduce(workloads, c: DeviceConstants, cols, valid, idx,
                 nf = jnp.sum(ok.astype(jnp.float32))
 
                 def feasible():
-                    i = jnp.argmin(edp)
-                    carried = carry_ref[w, 0] <= edp[i]
-                    return (jnp.where(carried, carry_ref[w, 0], edp[i]),
-                            jnp.where(carried, CARRY_IDX, idx[i]), nf)
+                    # First-hit argmin without a dynamic index: the block
+                    # minimum, then the smallest index attaining it.
+                    best = jnp.min(edp)
+                    at = jnp.min(jnp.where(edp == best, idx, jnp.inf))
+                    carried = carry_ref[w, 0] <= best
+                    return (jnp.where(carried, carry_ref[w, 0], best),
+                            jnp.where(carried, CARRY_IDX, at), nf)
 
                 def infeasible():
                     return carry_ref[w, 0], jnp.float32(CARRY_IDX), nf
@@ -317,10 +346,8 @@ def _search_reduce(workloads, c: DeviceConstants, cols, valid, idx,
         def dead(w=w):
             return carry_ref[w, 0], jnp.float32(CARRY_IDX), jnp.float32(0.0)
 
-        edp_out, idx_out, nf_out = jax.lax.cond(any_valid, live, dead)
-        out_ref[SEARCH_ROWS * w + 0, 0] = edp_out
-        out_ref[SEARCH_ROWS * w + 1, 0] = idx_out
-        out_ref[SEARCH_ROWS * w + 2, 0] = nf_out
+        _store_scalars(out_ref, SEARCH_ROWS * w,
+                       jax.lax.cond(any_valid, live, dead))
 
 
 def _dse_search_kernel(workloads, c: DeviceConstants,
@@ -337,9 +364,9 @@ def _dse_search_kernel(workloads, c: DeviceConstants,
     block feasible count.
     """
     cols = _cfg_cols(cfg_ref)
-    valid = mask_ref[0, :] > 0.0
-    base = (pl.program_id(0) * BLOCK).astype(jnp.float32)
-    idx = base + jax.lax.iota(jnp.float32, cols[0].shape[0])
+    valid = mask_ref[...] > 0.0
+    idx = (pl.program_id(0) * BLOCK + _lane_iota((1, BLOCK))).astype(
+        jnp.float32)
     _search_reduce(workloads, c, cols, valid, idx, cons_ref, carry_ref,
                    out_ref)
 
@@ -353,91 +380,79 @@ def _dse_search_decode_kernel(workloads, radices, c: DeviceConstants,
     (the decode already knows it), so the host wrapper needs no per-shard
     base bookkeeping."""
     cols, idx, valid = _decode_block(radices, axes_ref, meta_ref,
-                                     DECODE_BLOCK)
+                                     (DECODE_BLOCK // LANES, LANES))
     _search_reduce(workloads, c, cols, valid, idx, cons_ref, carry_ref,
                    out_ref)
 
 
 def _block_front(objs, ok):
-    """(BLOCK,) mask of block-locally non-dominated feasible configs.
+    """(1, BLOCK) mask of block-locally non-dominated feasible configs.
 
-    objs: tuple of (BLOCK,) objective vectors (minimized); ok: feasibility.
+    objs: tuple of (1, BLOCK) objective rows (minimized); ok: feasibility.
     Infeasible rows get +inf objectives, so they never dominate (inf <= x is
     false) and are excluded from the front by the `ok &`. Exact ties are
     kept (dominance needs a strict < somewhere).
 
-    The block is presorted by objective 0 (ascending, +inf last), which
-    makes the pairwise pass triangular: a dominator's objective 0 is <= its
-    victim's, so after the sort only earlier rows can dominate later ones
-    and each (DOM_CHUNK, ·) tile compares its rows against the columns at
-    and after it instead of the whole block — half the comparisons of the
-    old full (DOM_CHUNK, BLOCK) sweep. Rows tied on objective 0 can hide a
-    dominator *behind* its victim; those pairs are skipped, which only
-    grows the emitted candidate superset (the host's float64 refinement
-    restores the exact frontier — same soundness argument as MAX_FRONT
-    truncation). Chunks whose rows are all infeasible (+inf sorts them
-    last) early-exit via lax.cond, so sparse-feasibility blocks pay for the
-    feasible prefix only.
+    Full pairwise dominance in (DOM_CHUNK, BLOCK) tiles: each chunk of
+    potential dominators, turned into a column, is compared with every
+    config of the block. No presort (the TPU lowers no sort), so the mask
+    is the exact block-local front. Chunks with no feasible row cannot
+    dominate anything and skip their tile via lax.cond, so
+    sparse-feasibility blocks pay for their feasible chunks only.
     """
     o = [jnp.where(ok, x, jnp.inf) for x in objs]
-    n = o[0].shape[0]
-    order = jnp.argsort(o[0])
-    so = [x[order] for x in o]
-    segments = []
+    n = o[0].shape[1]
+    dominated = jnp.zeros((1, n), jnp.float32)
     for s in range(0, n, DOM_CHUNK):
         hi = min(s + DOM_CHUNK, n)
-        rows = [x[:hi] for x in so]      # every potential dominator
-        cols = [x[s:hi] for x in so]     # this chunk's candidates
 
-        def tile(rows=rows, cols=cols, s=s, hi=hi):
+        def tile(s=s, hi=hi):
             le = None
             lt = None
-            for rx, cx in zip(rows, cols):
-                l_ = rx[:, None] <= cx[None, :]
-                t_ = rx[:, None] < cx[None, :]
+            for x in o:
+                r = x[:, s:hi].reshape(hi - s, 1)  # dominators as a column
+                l_ = r <= x
+                t_ = r < x
                 le = l_ if le is None else (le & l_)
                 lt = t_ if lt is None else (lt | t_)
-            # Strictly-earlier rows only: sorted row i may dominate sorted
-            # column s + j just when i < s + j.
-            r_i = jax.lax.iota(jnp.int32, hi)
-            c_i = s + jax.lax.iota(jnp.int32, hi - s)
-            return jnp.any(le & lt & (r_i[:, None] < c_i[None, :]), axis=0)
+            return jnp.any(le & lt, axis=0, keepdims=True).astype(
+                jnp.float32)
 
-        segments.append(jax.lax.cond(
-            jnp.isfinite(so[0][s]), tile,
-            lambda hi=hi, s=s: jnp.zeros(hi - s, dtype=bool)))
-    dominated = jnp.concatenate(segments)
-    unsorted = jnp.zeros(n, dtype=bool).at[order].set(dominated)
-    return ok & ~unsorted
+        dominated = jnp.maximum(dominated, jax.lax.cond(
+            jnp.any(ok[:, s:hi]), tile,
+            lambda: jnp.zeros((1, n), jnp.float32)))
+    return ok & (dominated == 0.0)
 
 
-def _carry_dominated(carry_pts, objs):
-    """(BLOCK,) mask of rows strictly dominated by a carried frontier point.
+def _carry_dominated(carry_ref, r0, objs):
+    """(1, BLOCK) mask of configs strictly dominated by a carried point.
 
-    carry_pts: (CARRY_FRONT, d) objective rows carried in from earlier
-    chunks (+inf padding — inf <= x is false, so padding never dominates);
-    objs: tuple of d (BLOCK,) objective vectors. Exact ties survive
-    (dominance needs a strict < somewhere), matching `_block_front`.
+    carry_ref rows [r0, r0 + CARRY_FRONT) hold the (CARRY_FRONT, d)
+    objective points carried in from earlier chunks (+inf padding —
+    inf <= x is false, so padding never dominates); objs: tuple of d
+    (1, BLOCK) objective rows. Exact ties survive (dominance needs a strict
+    < somewhere), matching `_block_front`.
     """
     le = None
     lt = None
     for j, x in enumerate(objs):
-        cj = carry_pts[:, j]
-        l_ = cj[:, None] <= x[None, :]
-        t_ = cj[:, None] < x[None, :]
+        cj = carry_ref[r0:r0 + CARRY_FRONT, j:j + 1]
+        l_ = cj <= x
+        t_ = cj < x
         le = l_ if le is None else (le & l_)
         lt = t_ if lt is None else (lt | t_)
-    return jnp.any(le & lt, axis=0)
+    return jnp.any(le & lt, axis=0, keepdims=True)
 
 
 def _pareto_reduce(workloads, objectives, has_carry: bool,
-                   c: DeviceConstants, cols, valid, base,
+                   c: DeviceConstants, cols, valid,
                    cons_ref, carry_ref, out_ref):
-    """Shared per-block dominance reduction body (grid-operand and decode
-    kernels). `base` is the float32 global index of the block's first lane;
-    emitted indices are base + local offset."""
-    local = jax.lax.iota(jnp.float32, cols[0].shape[0])
-    n = cols[0].shape[0]
+    """Shared per-block dominance body (grid-operand and decode kernels).
+
+    Emits two (1, BLOCK) rows per workload — the feasibility mask and the
+    block-local front mask, as 0/1 floats. The jitted wrapper reduces them
+    to the per-block header and index rows (`_front_rows`) before anything
+    leaves the device."""
     for w, (gemms, wl_scalars) in enumerate(workloads):
         area, power, energy, latency = _config_metrics(
             gemms, wl_scalars, c, *cols)
@@ -449,17 +464,34 @@ def _pareto_reduce(workloads, objectives, has_carry: bool,
         objs = tuple(vals[k] for k in objectives)
         front = _block_front(objs, ok)
         if has_carry:
-            carry_pts = carry_ref[w * CARRY_FRONT:(w + 1) * CARRY_FRONT, :]
             front = front & ~_carry_dominated(
-                carry_pts, tuple(jnp.where(ok, x, jnp.inf) for x in objs))
-        # Compact the front's local indices to the row prefix via sort
-        # (non-members key to n, sorting after every member).
-        key = jnp.sort(jnp.where(front, local, float(n)))[:MAX_FRONT]
-        gidx = jnp.where(key < n, base + key, -1.0)
-        r0 = PARETO_ROWS * w
-        out_ref[r0 + 0, 0] = jnp.sum(front.astype(jnp.float32))
-        out_ref[r0 + 1, 0] = jnp.sum(ok.astype(jnp.float32))
-        out_ref[r0 + PARETO_HEADER:r0 + PARETO_ROWS, 0] = gidx
+                carry_ref, w * CARRY_FRONT,
+                tuple(jnp.where(ok, x, jnp.inf) for x in objs))
+        out_ref[2 * w:2 * w + 1, :] = ok.astype(jnp.float32)
+        out_ref[2 * w + 1:2 * w + 2, :] = front.astype(jnp.float32)
+
+
+def _front_rows(masks, first, w: int, n_blocks: int):
+    """(2W, n_blocks * BLOCK) kernel masks -> (PARETO_ROWS * W, n_blocks).
+
+    Per workload and block: the local-front size, the feasible count, then
+    the first MAX_FRONT front members' indices (`first` + launch offset,
+    float32, ascending) padded with -1. Runs in XLA inside the wrapper's
+    jit, so only these per-block rows reach the host."""
+    m = masks.reshape(w, 2, n_blocks, BLOCK)
+    ok, front = m[:, 0] > 0.0, m[:, 1] > 0.0
+    local = jnp.arange(BLOCK, dtype=jnp.float32)
+    # Non-members key to BLOCK, sorting after every member.
+    key = jnp.sort(jnp.where(front, local, float(BLOCK)),
+                   axis=-1)[..., :MAX_FRONT]
+    base = (first + jnp.arange(n_blocks, dtype=jnp.int32) * BLOCK).astype(
+        jnp.float32)
+    gidx = jnp.where(key < BLOCK, base[None, :, None] + key, -1.0)
+    rows = jnp.concatenate(
+        [jnp.sum(front, axis=-1, dtype=jnp.float32)[:, None],
+         jnp.sum(ok, axis=-1, dtype=jnp.float32)[:, None],
+         jnp.swapaxes(gidx, 1, 2)], axis=1)
+    return rows.reshape(w * PARETO_ROWS, n_blocks)
 
 
 def _dse_pareto_kernel(workloads, objectives, has_carry: bool,
@@ -467,24 +499,19 @@ def _dse_pareto_kernel(workloads, objectives, has_carry: bool,
                        cfg_ref, mask_ref, cons_ref, carry_ref, out_ref):
     """Per-block dominance reduction over one (5, BLOCK) config tile.
 
-    Emits PARETO_ROWS rows per workload: the block's local-front size, its
-    feasible count, then up to MAX_FRONT global config indices of the local
-    non-dominated set (-1 padding). Local fronts are a superset filter —
-    any point dominated inside its block is dominated globally — so the
-    host only merges the per-block candidate lists; the (4, G) metrics
-    array never leaves the device. carry_ref holds (W * CARRY_FRONT, d)
-    running-front objective points from earlier chunks of a streamed sweep
-    (+inf rows when there is no carry): block candidates strictly dominated
-    by a carried point are pruned before emission, so streamed candidate
-    lists stay bounded by the frontier, not the grid. `has_carry` is
-    static: one-shot launches (no carry possible) specialize the whole
-    (CARRY_FRONT, BLOCK) prune away instead of comparing against +inf.
+    Local fronts are a superset filter — any point dominated inside its
+    block is dominated globally — so the host only merges the per-block
+    candidate lists; the (4, G) metrics array never leaves the device.
+    carry_ref holds (W * CARRY_FRONT, d) running-front objective points
+    from earlier chunks of a streamed sweep (+inf rows when there is no
+    carry): block candidates strictly dominated by a carried point are
+    pruned before emission, so streamed candidate lists stay bounded by the
+    frontier, not the grid. `has_carry` is static: one-shot launches (no
+    carry possible) specialize the whole (CARRY_FRONT, BLOCK) prune away
+    instead of comparing against +inf.
     """
-    cols = _cfg_cols(cfg_ref)
-    valid = mask_ref[0, :] > 0.0
-    base = (pl.program_id(0) * BLOCK).astype(jnp.float32)
-    _pareto_reduce(workloads, objectives, has_carry, c, cols, valid, base,
-                   cons_ref, carry_ref, out_ref)
+    _pareto_reduce(workloads, objectives, has_carry, c, _cfg_cols(cfg_ref),
+                   mask_ref[...] > 0.0, cons_ref, carry_ref, out_ref)
 
 
 def _dse_pareto_decode_kernel(workloads, objectives, has_carry: bool,
@@ -492,10 +519,9 @@ def _dse_pareto_decode_kernel(workloads, objectives, has_carry: bool,
                               axes_ref, meta_ref, cons_ref, carry_ref,
                               out_ref):
     """Factorized-space variant of `_dse_pareto_kernel`: configs decoded on
-    device from the chunk base + per-axis candidate vectors, and emitted
-    candidate indices are global flat-space indices."""
-    cols, idx, valid = _decode_block(radices, axes_ref, meta_ref)
-    _pareto_reduce(workloads, objectives, has_carry, c, cols, valid, idx[0],
+    device from the chunk base + per-axis candidate vectors."""
+    cols, _, valid = _decode_block(radices, axes_ref, meta_ref)
+    _pareto_reduce(workloads, objectives, has_carry, c, cols, valid,
                    cons_ref, carry_ref, out_ref)
 
 
@@ -505,8 +531,8 @@ def _decode_rows_kernel(radices, axes_ref, meta_ref, out_ref):
     against `config_grid` rows directly."""
     cols, _, valid = _decode_block(radices, axes_ref, meta_ref)
     for r, col in enumerate(cols):
-        out_ref[r, :] = col
-    out_ref[5, :] = valid.astype(jnp.float32)
+        out_ref[r:r + 1, :] = col
+    out_ref[5:6, :] = valid.astype(jnp.float32)
 
 
 def _pad_cols(cfg_cols, mask=None):
@@ -525,10 +551,25 @@ def _pad_cols(cfg_cols, mask=None):
     return cfg_cols, mask
 
 
+def _search_out_spec(w: int, n_blocks: int):
+    """Lane-dense (SEARCH_ROWS * W, LANES) output tile per grid step, and
+    the full (SEARCH_ROWS * W, n_blocks * LANES) output shape."""
+    rows = SEARCH_ROWS * w
+    return (pl.BlockSpec((rows, LANES), lambda i: (0, i)),
+            jax.ShapeDtypeStruct((rows, n_blocks * LANES), jnp.float32))
+
+
+def _pareto_out_spec(w: int, n_blocks: int):
+    """(2W, BLOCK) mask tile per grid step (see `_pareto_reduce`)."""
+    return (pl.BlockSpec((2 * w, BLOCK), lambda i: (0, i)),
+            jax.ShapeDtypeStruct((2 * w, n_blocks * BLOCK), jnp.float32))
+
+
 @functools.partial(jax.jit, static_argnames=("gemms", "wl_scalars",
                                              "constants", "interpret"))
 def dse_eval_padded(cfg_cols, *, gemms: tuple, wl_scalars: tuple,
-                    constants: DeviceConstants, interpret: bool = True):
+                    constants: DeviceConstants,
+                    interpret: Optional[bool] = None):
     """cfg_cols: (5, G) float32, any G -> (4, G) [area, power, energy,
     latency]. Pads to a BLOCK multiple internally and trims the result."""
     _, g = cfg_cols.shape
@@ -540,7 +581,7 @@ def dse_eval_padded(cfg_cols, *, gemms: tuple, wl_scalars: tuple,
         in_specs=[pl.BlockSpec((5, BLOCK), lambda i: (0, i))],
         out_specs=pl.BlockSpec((4, BLOCK), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((4, cfg_cols.shape[1]), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(cfg_cols)
     return out[:, :g]
 
@@ -548,7 +589,8 @@ def dse_eval_padded(cfg_cols, *, gemms: tuple, wl_scalars: tuple,
 @functools.partial(jax.jit, static_argnames=("workloads", "constants",
                                              "interpret"))
 def dse_search_padded(cfg_cols, mask, cons, carry, *, workloads: tuple,
-                      constants: DeviceConstants, interpret: bool = True):
+                      constants: DeviceConstants,
+                      interpret: Optional[bool] = None):
     """Fused single-pass DSE search over a (5, G) config grid, any G.
 
     Args:
@@ -576,18 +618,19 @@ def dse_search_padded(cfg_cols, mask, cons, carry, *, workloads: tuple,
     n_blocks = cfg_cols.shape[1] // BLOCK
     w = len(workloads)
     kernel = functools.partial(_dse_search_kernel, workloads, constants)
-    return pl.pallas_call(
+    out_spec, out_shape = _search_out_spec(w, n_blocks)
+    out = pl.pallas_call(
         kernel,
         grid=(n_blocks,),
         in_specs=[pl.BlockSpec((5, BLOCK), lambda i: (0, i)),
                   pl.BlockSpec((1, BLOCK), lambda i: (0, i)),
                   pl.BlockSpec((w, 4), lambda i: (0, 0)),
                   pl.BlockSpec((w, 1), lambda i: (0, 0))],
-        out_specs=pl.BlockSpec((SEARCH_ROWS * w, 1), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((SEARCH_ROWS * w, n_blocks),
-                                       jnp.float32),
-        interpret=interpret,
+        out_specs=out_spec,
+        out_shape=out_shape,
+        interpret=resolve_interpret(interpret),
     )(cfg_cols, mask, cons, carry)
+    return out[:, ::LANES]
 
 
 @functools.partial(jax.jit, static_argnames=("workloads", "objectives",
@@ -596,7 +639,7 @@ def dse_search_padded(cfg_cols, mask, cons, carry, *, workloads: tuple,
 def dse_pareto_padded(cfg_cols, mask, cons, carry, *, workloads: tuple,
                       objectives: tuple, has_carry: bool = True,
                       constants: DeviceConstants,
-                      interpret: bool = True):
+                      interpret: Optional[bool] = None):
     """Fused frontier-candidate search over a (5, G) config grid, any G.
 
     Same operand contract as `dse_search_padded` (dynamic (W, 4) constraint
@@ -612,9 +655,9 @@ def dse_pareto_padded(cfg_cols, mask, cons, carry, *, workloads: tuple,
     Returns (PARETO_ROWS * W, n_blocks) float32: per workload w, row
     [r0 + 0] the block's true local-front size (> MAX_FRONT signals the
     emitted index list was truncated), [r0 + 1] the block feasible count,
-    rows [r0 + 2 .. r0 + 2 + MAX_FRONT) global config indices of local
-    non-dominated configs, -1-padded, with r0 = PARETO_ROWS * w. Config
-    indices are exact for G < 2**24 (float32 mantissa).
+    rows [r0 + 2 .. r0 + 2 + MAX_FRONT) launch-local config indices of
+    local non-dominated configs, -1-padded, with r0 = PARETO_ROWS * w.
+    Config indices are exact for G < 2**24 (float32 mantissa).
     """
     cfg_cols, mask = _pad_cols(cfg_cols, mask)
     n_blocks = cfg_cols.shape[1] // BLOCK
@@ -622,18 +665,19 @@ def dse_pareto_padded(cfg_cols, mask, cons, carry, *, workloads: tuple,
     d = len(objectives)
     kernel = functools.partial(_dse_pareto_kernel, workloads, objectives,
                                has_carry, constants)
-    return pl.pallas_call(
+    out_spec, out_shape = _pareto_out_spec(w, n_blocks)
+    masks = pl.pallas_call(
         kernel,
         grid=(n_blocks,),
         in_specs=[pl.BlockSpec((5, BLOCK), lambda i: (0, i)),
                   pl.BlockSpec((1, BLOCK), lambda i: (0, i)),
                   pl.BlockSpec((w, 4), lambda i: (0, 0)),
                   pl.BlockSpec((w * CARRY_FRONT, d), lambda i: (0, 0))],
-        out_specs=pl.BlockSpec((PARETO_ROWS * w, 1), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((PARETO_ROWS * w, n_blocks),
-                                       jnp.float32),
-        interpret=interpret,
+        out_specs=out_spec,
+        out_shape=out_shape,
+        interpret=resolve_interpret(interpret),
     )(cfg_cols, mask, cons, carry)
+    return _front_rows(masks, 0, w, n_blocks)
 
 
 # ---------------------------------------------------------------------------
@@ -662,7 +706,8 @@ def _axes_meta_specs(axes, w: int, extra):
                                              "interpret"))
 def dse_search_decoded(axes, meta, cons, carry, *, radices: tuple,
                        n_blocks: int, workloads: tuple,
-                       constants: DeviceConstants, interpret: bool = True):
+                       constants: DeviceConstants,
+                       interpret: Optional[bool] = None):
     """Fused search over the index span (and slab digit ranges) named by
     the (1, META_COLS) meta row, over a product space with static
     `radices`; same operand contract and output layout as
@@ -671,16 +716,17 @@ def dse_search_decoded(axes, meta, cons, carry, *, radices: tuple,
     w = len(workloads)
     kernel = functools.partial(_dse_search_decode_kernel, workloads,
                                tuple(radices), constants)
-    return pl.pallas_call(
+    out_spec, out_shape = _search_out_spec(w, n_blocks)
+    out = pl.pallas_call(
         kernel,
         grid=(n_blocks,),
         in_specs=_axes_meta_specs(axes, w,
                                   pl.BlockSpec((w, 1), lambda i: (0, 0))),
-        out_specs=pl.BlockSpec((SEARCH_ROWS * w, 1), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((SEARCH_ROWS * w, n_blocks),
-                                       jnp.float32),
-        interpret=interpret,
+        out_specs=out_spec,
+        out_shape=out_shape,
+        interpret=resolve_interpret(interpret),
     )(axes, meta, cons, carry)
+    return out[:, ::LANES]
 
 
 @functools.partial(jax.jit, static_argnames=("radices", "n_blocks",
@@ -690,7 +736,8 @@ def dse_search_decoded(axes, meta, cons, carry, *, radices: tuple,
 def dse_pareto_decoded(axes, meta, cons, carry, *, radices: tuple,
                        n_blocks: int, workloads: tuple, objectives: tuple,
                        has_carry: bool = True,
-                       constants: DeviceConstants, interpret: bool = True):
+                       constants: DeviceConstants,
+                       interpret: Optional[bool] = None):
     """Frontier-candidate search over an index span of a product space;
     same output layout as `dse_pareto_padded` with global candidate
     indices."""
@@ -699,22 +746,23 @@ def dse_pareto_decoded(axes, meta, cons, carry, *, radices: tuple,
     kernel = functools.partial(_dse_pareto_decode_kernel, workloads,
                                objectives, has_carry, tuple(radices),
                                constants)
-    return pl.pallas_call(
+    out_spec, out_shape = _pareto_out_spec(w, n_blocks)
+    masks = pl.pallas_call(
         kernel,
         grid=(n_blocks,),
         in_specs=_axes_meta_specs(
             axes, w, pl.BlockSpec((w * CARRY_FRONT, d), lambda i: (0, 0))),
-        out_specs=pl.BlockSpec((PARETO_ROWS * w, 1), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((PARETO_ROWS * w, n_blocks),
-                                       jnp.float32),
-        interpret=interpret,
+        out_specs=out_spec,
+        out_shape=out_shape,
+        interpret=resolve_interpret(interpret),
     )(axes, meta, cons, carry)
+    return _front_rows(masks, meta[0, 0], w, n_blocks)
 
 
 @functools.partial(jax.jit, static_argnames=("radices", "n_blocks",
                                              "interpret"))
 def dse_decode_rows(axes, meta, *, radices: tuple, n_blocks: int,
-                    interpret: bool = True):
+                    interpret: Optional[bool] = None):
     """(6, n_blocks * BLOCK) [five decoded config rows; validity] for the
     index span + slab ranges named by the (1, META_COLS) meta row — the
     decode-proof kernel the mixed-radix property tests drive."""
@@ -725,5 +773,5 @@ def dse_decode_rows(axes, meta, *, radices: tuple, n_blocks: int,
                   pl.BlockSpec((1, META_COLS), lambda i: (0, 0))],
         out_specs=pl.BlockSpec((6, BLOCK), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((6, n_blocks * BLOCK), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(axes, meta)

@@ -15,11 +15,14 @@ in interpret mode (tests/test_flash_attention.py).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .backend import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -68,7 +71,7 @@ def _flash_kernel(causal: bool, scale: float, nk: int, bq: int, bk: int,
 @functools.partial(jax.jit, static_argnames=("causal", "bq", "bk",
                                              "interpret"))
 def flash_attention_bhsd(q, k, v, *, causal: bool = True, bq: int = 128,
-                         bk: int = 128, interpret: bool = True):
+                         bk: int = 128, interpret: Optional[bool] = None):
     """q, k, v: (BH, S, D) same-length self-attention -> (BH, S, D).
 
     S must be a multiple of the block sizes (ops.flash_attention pads).
@@ -92,5 +95,5 @@ def flash_attention_bhsd(q, k, v, *, causal: bool = True, bq: int = 128,
         scratch_shapes=[pltpu.VMEM((bq, 1), jnp.float32),
                         pltpu.VMEM((bq, 1), jnp.float32),
                         pltpu.VMEM((bq, d), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v)

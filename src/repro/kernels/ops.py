@@ -7,10 +7,8 @@
   * dse_eval_grid / pallas_grid_search — the DSE grid evaluated by the
     dse_eval kernel, same result format as core.search.evaluate_grid.
 
-On this CPU container kernels run with interpret=True (Pallas executes the
-kernel body with jax ops); on a real TPU pass interpret=False for compiled
-Mosaic kernels. All padding/quantization pre-passes live here so the kernels
-see aligned, pre-quantized operands only.
+All padding/quantization pre-passes live here so the kernels see aligned,
+pre-quantized operands only.
 """
 from __future__ import annotations
 
@@ -60,7 +58,7 @@ def _pad_to(x, m0, m1):
 def ddot_matmul(a, b, *, noise_rms: float = 0.0,
                 key: Optional[jax.Array] = None,
                 bm: int = 256, bn: int = 256, bk: int = 512,
-                interpret: bool = True):
+                interpret: Optional[bool] = None):
     """Photonic-PTA simulated matmul: a (M, K) @ b (K, N) -> (M, N) f32.
 
     Handles arbitrary shapes by padding to block multiples. Exact vs
@@ -91,8 +89,8 @@ def _rup(x, m):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
-def photonic_matmul(a, b, noise_rms: float = 0.0, interpret: bool = True,
-                    key_data: int = 0):
+def photonic_matmul(a, b, noise_rms: float = 0.0,
+                    interpret: Optional[bool] = None, key_data: int = 0):
     key = jax.random.key(key_data) if noise_rms > 0.0 else None
     return ddot_matmul(a, b, noise_rms=noise_rms, key=key,
                        interpret=interpret)
@@ -118,14 +116,20 @@ photonic_matmul.defvjp(_photonic_fwd, _photonic_bwd)
 
 def dse_eval_grid(grid: np.ndarray, wl: Workload,
                   c: DeviceConstants = CONSTANTS,
-                  interpret: bool = True) -> np.ndarray:
+                  interpret: Optional[bool] = None) -> np.ndarray:
     """(G, 5) config grid -> (G, 4) [area, power, energy, latency] via the
-    dse_eval Pallas kernel. Any G — the kernel wrapper pads + trims."""
-    cols = jnp.asarray(np.asarray(grid).T, jnp.float32)
+    dse_eval Pallas kernel. Any G: the grid is padded to a BLOCK multiple
+    here, as the search wrappers pad theirs, so the metrics come out of
+    the same compiled arithmetic as the search kernels' (the carried-front
+    prune compares the two bit for bit)."""
+    g = np.asarray(grid)
+    cols = np.ones((5, -(-len(g) // _dse.BLOCK) * _dse.BLOCK), np.float32)
+    cols[:, :len(g)] = g.T
     gemms, wl_scalars = workload_statics(wl, c)
-    out = _dse.dse_eval_padded(cols, gemms=gemms, wl_scalars=wl_scalars,
-                               constants=c, interpret=interpret)
-    return np.asarray(out).T
+    out = _dse.dse_eval_padded(jnp.asarray(cols), gemms=gemms,
+                               wl_scalars=wl_scalars, constants=c,
+                               interpret=interpret)
+    return np.asarray(out)[:, :len(g)].T
 
 
 def _constraint_rows(constraints_seq) -> jnp.ndarray:
@@ -169,7 +173,6 @@ def _sharded_kernel_fn(kind: str, statics: tuple, k: int):
     interpret). Keyed on the kernel statics + mesh size, so a streamed
     sweep's chunk launches reuse one compiled executable per chunk shape.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.launch.mesh import make_candidate_mesh
@@ -197,10 +200,10 @@ def _sharded_kernel_fn(kind: str, statics: tuple, k: int):
                                           constants=constants,
                                           interpret=interpret)
 
-    return jax.jit(shard_map(body, mesh=mesh,
-                             in_specs=(spec, spec, P(None, None),
-                                       P(None, None)),
-                             out_specs=spec, check_rep=False))
+    return jax.jit(jax.shard_map(body, mesh=mesh,
+                                 in_specs=(spec, spec, P(None, None),
+                                           P(None, None)),
+                                 out_specs=spec, check_vma=False))
 
 
 def _sharded_kernel_out(grid: np.ndarray, shard: int, kind: str,
@@ -241,7 +244,7 @@ def _sharded_kernel_out(grid: np.ndarray, shard: int, kind: str,
 
 def dse_search_grid(grid: np.ndarray, wl: Workload, constraints,
                     c: DeviceConstants = CONSTANTS,
-                    interpret: bool = True, *, shard=None, carry_edp=None):
+                    interpret: Optional[bool] = None, *, shard=None, carry_edp=None):
     """Fused single-pass search: (best_idx, best_edp, n_feasible).
 
     The Pallas kernel applies the constraint mask, computes EDP and reduces
@@ -276,7 +279,7 @@ def _bucketed_cols(grid: np.ndarray):
 
 def dse_search_multi(grid: np.ndarray, wls, constraints_seq,
                      c: DeviceConstants = CONSTANTS,
-                     interpret: bool = True, *, shard=None, carry_edp=None):
+                     interpret: Optional[bool] = None, *, shard=None, carry_edp=None):
     """Batched fused search: W workloads x one grid in a single launch.
 
     `shard=N` fans the candidate axis out over up to N devices with
@@ -330,7 +333,7 @@ def dse_search_multi(grid: np.ndarray, wls, constraints_seq,
 
 
 def dse_pareto_multi(grid: np.ndarray, wls, constraints_seq,
-                     c: DeviceConstants = CONSTANTS, interpret: bool = True,
+                     c: DeviceConstants = CONSTANTS, interpret: Optional[bool] = None,
                      objectives: tuple = ("area", "power", "edp"),
                      *, shard=None, carry_points=None):
     """Batched frontier-candidate search: W workloads x one grid, one launch.
@@ -473,7 +476,6 @@ def _sharded_decoded_fn(kind: str, statics: tuple, k: int, radices: tuple,
     per-shard [base, end) spans are sharded over the candidate mesh, the
     tiny axes/cons/carry operands are replicated, and each shard runs
     `n_blocks` blocks of its own index range."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.launch.mesh import make_candidate_mesh
@@ -500,10 +502,10 @@ def _sharded_decoded_fn(kind: str, statics: tuple, k: int, radices: tuple,
                 objectives=objectives, has_carry=has_carry,
                 constants=constants, interpret=interpret)
 
-    return jax.jit(shard_map(body, mesh=mesh,
-                             in_specs=(P(None, None), meta_spec,
-                                       P(None, None), P(None, None)),
-                             out_specs=out_spec, check_rep=False))
+    return jax.jit(jax.shard_map(body, mesh=mesh,
+                                 in_specs=(P(None, None), meta_spec,
+                                           P(None, None), P(None, None)),
+                                 out_specs=out_spec, check_vma=False))
 
 
 def _check_decode_span(limit: int):
@@ -566,7 +568,7 @@ def _decoded_launch(space, start: int, count: int, kind: str, statics: tuple,
 def dse_search_multi_factorized(space, start: int, count: int, wls,
                                 constraints_seq,
                                 c: DeviceConstants = CONSTANTS,
-                                interpret: bool = True, *, shard=None,
+                                interpret: Optional[bool] = None, *, shard=None,
                                 carry_edp=None, slab=None):
     """Batched fused search over an index span of a product space.
 
@@ -606,7 +608,7 @@ def dse_search_multi_factorized(space, start: int, count: int, wls,
 def dse_pareto_multi_factorized(space, start: int, count: int, wls,
                                 constraints_seq,
                                 c: DeviceConstants = CONSTANTS,
-                                interpret: bool = True,
+                                interpret: Optional[bool] = None,
                                 objectives: tuple = ("area", "power", "edp"),
                                 *, shard=None, carry_points=None, slab=None):
     """Batched frontier-candidate search over an index span of a product
@@ -659,7 +661,7 @@ def dse_pareto_multi_factorized(space, start: int, count: int, wls,
 
 def dse_search_spans_factorized(space, items, wls, constraints_seq,
                                 c: DeviceConstants = CONSTANTS,
-                                interpret: bool = True, *, shard=None,
+                                interpret: Optional[bool] = None, *, shard=None,
                                 carry_edp=None):
     """Compose `dse_search_multi_factorized` launches over a work list.
 
@@ -693,7 +695,7 @@ def dse_search_spans_factorized(space, items, wls, constraints_seq,
 
 def dse_pareto_spans_factorized(space, items, wls, constraints_seq,
                                 c: DeviceConstants = CONSTANTS,
-                                interpret: bool = True,
+                                interpret: Optional[bool] = None,
                                 objectives: tuple = ("area", "power", "edp"),
                                 *, shard=None, carry_points=None):
     """Compose `dse_pareto_multi_factorized` launches over a work list of
@@ -724,7 +726,7 @@ def dse_pareto_spans_factorized(space, items, wls, constraints_seq,
 
 
 def decode_rows_device(space, start: int, count: int,
-                       interpret: bool = True, slab=None) -> np.ndarray:
+                       interpret: Optional[bool] = None, slab=None) -> np.ndarray:
     """(count, 5) int64 rows of space.to_grid()[start:start+count], decoded
     *on device* by the Pallas mixed-radix kernel — the testable surface of
     the in-kernel candidate generation. With `slab` (five [lo, hi) digit
@@ -743,7 +745,7 @@ def decode_rows_device(space, start: int, count: int,
 
 def pallas_grid_search(grid: np.ndarray, wl: Workload, constraints,
                        c: DeviceConstants = CONSTANTS,
-                       interpret: bool = True):
+                       interpret: Optional[bool] = None):
     """Legacy two-pass kernel path: materializes the full (G, 4) metrics on
     the host, then selects with numpy (mirrors grid_search_vectorized's
     rule). Kept as the baseline the fused `dse_search_grid` is benchmarked
@@ -764,7 +766,7 @@ def pallas_grid_search(grid: np.ndarray, wl: Workload, constraints,
 # ---------------------------------------------------------------------------
 
 def flash_attention(q, k, v, *, causal: bool = True, bq: int = 128,
-                    bk: int = 128, interpret: bool = True):
+                    bk: int = 128, interpret: Optional[bool] = None):
     """Fused attention for (B, S, H, D) tensors with GQA support.
 
     K/V with fewer heads than Q are broadcast per group; sequences are

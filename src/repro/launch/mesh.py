@@ -14,9 +14,14 @@ import jax
 
 
 def make_production_mesh(*, multi_pod: bool = False):
+    """The production mesh with Auto axes: the model stack is laid out by
+    sharding constraints that GSPMD propagates, not by sharding in types
+    (`jax.make_mesh`'s default axis type, Explicit, would demand an
+    output sharding at every gather and update)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh():

@@ -90,8 +90,7 @@ def _dse_main(args) -> None:
     names = (list(paper_workloads.PAPER_WORKLOADS) if args.workload == "all"
              else [args.workload])
     svc = SearchService(n_z=args.n_z, engine=args.engine,
-                        interpret=not args.tpu, shard=args.shard,
-                        chunk_size=args.chunk_size,
+                        shard=args.shard, chunk_size=args.chunk_size,
                         checkpoint_root=args.checkpoint_root,
                         workers=args.workers)
     boxes = [("paper defaults", Constraints())]
@@ -148,8 +147,7 @@ def _scenarios_main(args) -> None:
     cons = {spec.split(":", 1)[0]: _parse_scenario(spec.split(":", 1)[1])
             for spec in args.box} if args.box else {}
     svc = SearchService(n_z=args.n_z, engine=args.engine,
-                        interpret=not args.tpu, shard=args.shard,
-                        chunk_size=args.chunk_size)
+                        shard=args.shard, chunk_size=args.chunk_size)
     print(f"service: {args.engine} engine, {args.n_z}^5 space; grid: "
           f"{len(models)} model(s) x {len(args.kind)} kind(s) -> "
           f"{grid.size} scenarios")
@@ -164,6 +162,8 @@ def _scenarios_main(args) -> None:
 
 def main(argv=None) -> None:
     """Dispatch to a subcommand (``tokens`` when none is given)."""
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     argv = list(sys.argv[1:] if argv is None else argv)
     if not argv or argv[0] not in ("tokens", "dse", "scenarios"):
         argv.insert(0, "tokens")  # original flag-only invocation
@@ -200,8 +200,6 @@ def main(argv=None) -> None:
                     help="after serving, prune completed-query checkpoint "
                          "dirs under --checkpoint-root down to the newest "
                          "KEEP (manifest-validated; foreign dirs skipped)")
-    ds.add_argument("--tpu", action="store_true",
-                    help="disable Pallas interpret mode")
 
     sc = sub.add_parser("scenarios", help="model-zoo scenario co-search")
     sc.add_argument("--model", action="append", default=[],
@@ -231,8 +229,6 @@ def main(argv=None) -> None:
                     choices=("edp", "pareto"))
     sc.add_argument("--shard", type=int, default=None)
     sc.add_argument("--chunk-size", type=int, default=None)
-    sc.add_argument("--tpu", action="store_true",
-                    help="disable Pallas interpret mode")
 
     args = ap.parse_args(argv)
     if args.cmd == "scenarios":

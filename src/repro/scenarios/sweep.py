@@ -165,7 +165,7 @@ def sweep(grid: Union[ScenarioGrid, Sequence[Scenario]],
           service: Optional[SearchService] = None,
           engine: str = "jax", n_z: int = 12, space=None,
           objective: str = "edp", pareto_metrics: Optional[tuple] = None,
-          interpret: bool = True, c: DeviceConstants = CONSTANTS,
+          interpret: Optional[bool] = None, c: DeviceConstants = CONSTANTS,
           calibration=None, robust: Optional[str] = None
           ) -> SweepReport:
     """Run every scenario of `grid` through one `SearchService`.

@@ -85,10 +85,10 @@ class SearchService:
     """Persistent DSE server: memoized, batched, warm-started searches.
 
     Construction fixes the *space side* of every query — the factorized
-    product space, the engine, device constants, sharding/streaming shape
-    and the Pallas interpret flag — because those are what the resident
-    caches key on. The *question side* (workload, constraint box,
-    objective) arrives per query.
+    product space, the engine, device constants and sharding/streaming
+    shape — because those are what the resident caches key on. The
+    *question side* (workload, constraint box, objective) arrives per
+    query.
 
     Args:
       space: candidate sets of the product space (anything
@@ -97,7 +97,8 @@ class SearchService:
       n_z: per-axis candidate count of the default space.
       engine: numpy | jax | pallas — all byte-identical; the engine only
         decides where evaluation runs.
-      interpret: Pallas interpret mode (CPU); pass False on a real TPU.
+      interpret: Pallas interpret mode; None (the default) follows the
+        backend (see `repro.kernels.backend.resolve_interpret`).
       shard / chunk_size: forwarded to every search (see `search`).
       checkpoint_root: when set, every cold search runs under a
         `core.runtime` policy checkpointing into a service-owned
@@ -145,7 +146,7 @@ class SearchService:
     """
 
     def __init__(self, *, space=None, n_z: int = 12, engine: str = "jax",
-                 interpret: bool = True, shard: Optional[int] = None,
+                 interpret: Optional[bool] = None, shard: Optional[int] = None,
                  chunk_size: Optional[int] = None,
                  checkpoint_root: Optional[str] = None,
                  c: DeviceConstants = CONSTANTS,

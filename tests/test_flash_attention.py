@@ -1,4 +1,4 @@
-"""Flash-attention kernel vs plain-softmax oracle (interpret=True)."""
+"""Flash-attention kernel vs plain-softmax oracle (interpret mode on CPU)."""
 import jax
 import jax.numpy as jnp
 import numpy as np

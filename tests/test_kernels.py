@@ -1,4 +1,4 @@
-"""Per-kernel allclose tests vs the pure-jnp oracles (interpret=True on CPU).
+"""Per-kernel allclose tests vs the pure-jnp oracles (interpret mode on CPU).
 
 Sweeps shapes (including non-block-multiples) and dtypes per the kernel
 deliverable requirements.
@@ -217,3 +217,66 @@ def test_dse_pareto_kernel_candidates_cover_frontier(gsize):
     refined = rows[ok][pareto_mask(pts)]
     refined = refined[np.lexsort(refined.T[::-1])]
     assert np.array_equal(refined, front_ref)
+
+
+# ---------------------------------------------------------------------------
+# Interpret mode follows the backend; the compile cache follows the env
+# ---------------------------------------------------------------------------
+
+def test_interpret_mode_follows_backend(monkeypatch):
+    from repro.kernels import backend
+    assert backend.resolve_interpret() is True       # this suite: CPU
+    assert backend.resolve_interpret(True) is True
+    assert backend.resolve_interpret(False) is False  # described-TPU compile
+    monkeypatch.setattr(backend.jax, "default_backend", lambda: "tpu")
+    assert backend.resolve_interpret() is False
+    assert backend.resolve_interpret(False) is False
+    with pytest.raises(ValueError, match="interpret mode was requested"):
+        backend.resolve_interpret(True)
+
+
+def test_no_public_entry_point_defaults_to_interpret():
+    import importlib
+    import inspect
+
+    from repro.serve import SearchService
+    search, pareto, sweep, dse_eval, ops = (
+        importlib.import_module(m) for m in (
+            "repro.core.search", "repro.core.pareto", "repro.scenarios.sweep",
+            "repro.kernels.dse_eval", "repro.kernels.ops"))
+    fns = [search.search, search.search_workloads, search.dxpta_search,
+           pareto.pareto_front, pareto.pareto_search_refined, sweep.sweep,
+           SearchService.__init__]
+    fns += [f for m in (ops, dse_eval) for _, f in inspect.getmembers(m)
+            if callable(f) and getattr(f, "__module__", "") == m.__name__]
+    checked = 0
+    for f in fns:
+        p = inspect.signature(f).parameters.get("interpret")
+        if p is not None and p.default is not inspect.Parameter.empty:
+            assert p.default is None, f
+            checked += 1
+    assert checked >= 20
+
+
+@pytest.mark.parametrize("env_dir", [None, "custom"])
+def test_compile_cache_dir(monkeypatch, tmp_path, env_dir):
+    from repro.launch import compile_cache
+    before = (jax.config.jax_compilation_cache_dir,
+              jax.config.jax_enable_compilation_cache)
+    if env_dir is None:
+        monkeypatch.delenv(compile_cache.ENV_VAR, raising=False)
+        want = str(compile_cache.REPO_CACHE_DIR)
+    else:
+        want = str(tmp_path / env_dir)
+        monkeypatch.setenv(compile_cache.ENV_VAR, want)
+        jax.config.update("jax_compilation_cache_dir", want)  # JAX's own read
+    try:
+        assert compile_cache.enable_compile_cache() == want
+        assert jax.config.jax_compilation_cache_dir == want
+        assert jax.config.jax_enable_compilation_cache
+        assert compile_cache.REPO_CACHE_DIR.name == ".jax_cache"
+        assert compile_cache.REPO_CACHE_DIR.parent == \
+            compile_cache.Path(__file__).resolve().parents[1]
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before[0])
+        jax.config.update("jax_enable_compilation_cache", before[1])

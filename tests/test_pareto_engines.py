@@ -93,8 +93,8 @@ def test_all_engines_identical_per_workload(wname):
 
 
 def test_engines_on_full_grid_match():
-    # The acceptance bar: identical frontiers on the full 12^5 grid under
-    # interpret=True. numpy flat is the float64 reference; the other
+    # The acceptance bar: identical frontiers on the full 12^5 grid in
+    # interpret mode. numpy flat is the float64 reference; the other
     # backends run hierarchical (the prefilter only drops area/power-
     # infeasible configs, which can never reach the feasible frontier).
     wl = load("deit-b")
@@ -189,6 +189,35 @@ def test_pallas_block_overflow_at_real_bound_host_refine_taken():
     _assert_same_front(ref, got, "real-bound overflow")
     n_copies = int((got.front == best.as_array()).all(axis=1).sum())
     assert n_copies == dse_eval.BLOCK + dse_eval.MAX_FRONT + 33
+
+
+@pytest.mark.parametrize("objectives", [("area", "power", "edp"),
+                                        ("energy", "latency")])
+def test_pallas_block_front_is_exact_local_front(objectives):
+    # The kernel's dominance pass is full pairwise (no presort), so each
+    # block emits exactly its local non-dominated feasible set in the
+    # kernel's float32 metric space — no superset slack.
+    from repro.kernels import dse_eval, dse_eval_grid, dse_pareto_multi
+    wl = load("deit-t")
+    cons = Constraints()
+    grid = _sample_grid(7, size=6000)
+    assert len(grid) > 2 * dse_eval.BLOCK
+    (cand, _, n_over), = dse_pareto_multi(grid, [wl], [cons],
+                                          objectives=objectives)
+    assert n_over == 0
+    m = dse_eval_grid(grid, wl).astype(np.float32)
+    bounds = np.float32([cons.area_mm2, cons.power_w, cons.energy_j,
+                         cons.latency_s])
+    ok = (m < bounds).all(axis=1)
+    vals = {"area": m[:, 0], "power": m[:, 1], "energy": m[:, 2],
+            "latency": m[:, 3], "edp": m[:, 2] * m[:, 3]}
+    pts = np.stack([vals[k] for k in objectives], axis=1)
+    want = []
+    for lo in range(0, len(grid), dse_eval.BLOCK):
+        idx = np.arange(lo, min(lo + dse_eval.BLOCK, len(grid)))
+        idx = idx[ok[idx]]
+        want.append(idx[pareto_mask(pts[idx])])
+    assert np.array_equal(cand, np.concatenate(want))
 
 
 def test_pallas_block_overflow_falls_back_exact():

@@ -1,0 +1,134 @@
+"""Compile the DSE kernels for a described TPU v5e with Mosaic.
+
+Interpret mode runs the kernel bodies as plain jax ops and accepts what
+the chip's compiler refuses (1-lane blocks, 1-D gathers, sorts, dynamic
+indices). These tests lower each search-path kernel at the launch shapes
+`chip_smoke.py` drives — deit-b and bert-l statics over the 20^5 space and
+the 12^5 grid-operand bucket — with `interpret=False` against a described
+`v5e:2x2` topology, so a kernel the chip cannot compile fails here without
+a chip. Nothing runs; results are pinned by the interpret-mode suites.
+
+The topology is described inside a module-scoped fixture (never at import
+or collection time): only the worker that runs this file loads the TPU
+compiler library.
+"""
+from __future__ import annotations
+
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from repro.core.paper_workloads import load
+from repro.core.performance_model import workload_statics
+from repro.core.photonic_model import CONSTANTS
+from repro.kernels import dse_eval as K
+
+R20 = (20,) * 5
+OBJECTIVES = ("area", "power", "edp")
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure means "no TPU compiler"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_compile_cache():
+    """A described-topology compile is written to the persistent cache but
+    cannot be read back without a chip; keep the cache off meanwhile."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.fixture
+def compile_v5e(one_chip, no_compile_cache):
+    """compile_v5e(fn, *(shape, dtype)) -> the compiled executable."""
+    def run(fn, *shapes):
+        args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+                for s, d in shapes]
+        return jax.jit(fn).lower(*args).compile()
+    return run
+
+
+def _workloads(name):
+    return (workload_statics(load(name), CONSTANTS),)
+
+
+def _f32(*shape):
+    return shape, jnp.float32
+
+
+META = ((1, K.META_COLS), jnp.int32)
+AXES_20 = _f32(5, 20)
+
+
+@pytest.mark.parametrize("name", ["deit-b", "bert-l"])
+def test_dse_eval_padded_compiles(compile_v5e, name):
+    (gemms, wl_scalars), = _workloads(name)
+    fn = functools.partial(K.dse_eval_padded, gemms=gemms,
+                           wl_scalars=wl_scalars, constants=CONSTANTS,
+                           interpret=False)
+    compile_v5e(fn, _f32(5, 16 * K.BLOCK))
+
+
+def test_dse_search_padded_compiles(compile_v5e):
+    # Branch-and-bound's fine survivors: one 8-block grid-operand bucket.
+    g = 8 * K.BLOCK
+    fn = functools.partial(K.dse_search_padded, workloads=_workloads("deit-b"),
+                           constants=CONSTANTS, interpret=False)
+    compile_v5e(fn, _f32(5, g), _f32(1, g), _f32(1, 4), _f32(1, 1))
+
+
+def test_dse_search_decoded_compiles(compile_v5e):
+    # Branch-and-bound's coarse slabs: one DECODE_BLOCK per launch.
+    fn = functools.partial(K.dse_search_decoded, radices=R20, n_blocks=1,
+                           workloads=_workloads("deit-b"),
+                           constants=CONSTANTS, interpret=False)
+    compile_v5e(fn, AXES_20, META, _f32(1, 4), _f32(1, 1))
+
+
+def test_dse_pareto_padded_compiles(compile_v5e):
+    # The materialized 12^5 grid, bucketed to 128 blocks, with a carry.
+    g = 128 * K.BLOCK
+    fn = functools.partial(K.dse_pareto_padded,
+                           workloads=_workloads("bert-l"),
+                           objectives=OBJECTIVES, has_carry=True,
+                           constants=CONSTANTS, interpret=False)
+    compile_v5e(fn, _f32(5, g), _f32(1, g), _f32(1, 4),
+                _f32(K.CARRY_FRONT, len(OBJECTIVES)))
+
+
+def test_dse_pareto_decoded_compiles(compile_v5e):
+    # The whole 20^5 space in one launch: 2048 blocks of BLOCK lanes.
+    fn = functools.partial(K.dse_pareto_decoded, radices=R20, n_blocks=2048,
+                           workloads=_workloads("bert-l"),
+                           objectives=OBJECTIVES, has_carry=False,
+                           constants=CONSTANTS, interpret=False)
+    compile_v5e(fn, AXES_20, META, _f32(1, 4),
+                _f32(K.CARRY_FRONT, len(OBJECTIVES)))
+
+
+def test_dse_decode_rows_compiles(compile_v5e):
+    fn = functools.partial(K.dse_decode_rows, radices=R20, n_blocks=4,
+                           interpret=False)
+    compile_v5e(fn, AXES_20, META)
