@@ -1,0 +1,7 @@
+"""Median caller-side latency of every window query, failed ones
+included. Host clock."""
+from stats import percentile
+
+
+def read(run):
+    return percentile(run.latencies_s, 50) * 1e3
