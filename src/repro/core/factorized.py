@@ -55,6 +55,7 @@ import numpy as np
 from .arch_params import config_grid
 from .performance_model import cycle_factor_tables
 from .photonic_model import CONSTANTS, DeviceConstants, eval_hw
+from ..tracing import traced
 
 # Meshgrid axis order of the product space (see config_grid): N_t slowest,
 # N_lambda fastest. Note V before H — but column order is (t, c, h, v, l).
@@ -543,6 +544,7 @@ class SlabBoundEvaluator:
             cache[rng] = ext
         return ext
 
+    @traced("search.bounds")
     def lower_bounds_batch(self, ranges_batch) -> Dict[str, np.ndarray]:
         """{metric: (B,) lower-bound array} over a batch of slabs, every
         REPORT_METRICS key. One vectorized arithmetic pass: per-slab

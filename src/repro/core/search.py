@@ -97,6 +97,7 @@ from .runtime import (SearchRuntime, activate as _activate_rt,
                       fingerprint as _fingerprint)
 from .significance import SignificanceScore, observe_significance, significant_params
 from .workload import Workload
+from ..tracing import span, traced
 
 # Metric arrays reported per frontier point (every evaluate_grid key).
 REPORT_METRICS = ("area", "power", "energy", "latency", "util", "edp")
@@ -550,6 +551,7 @@ def hw_prefilter(grid: np.ndarray, wl: Workload, constraints: Constraints,
     return hw_prefilter_masks(grid, [wl], [constraints], c)[0]
 
 
+@traced("search.refine")
 def _make_result(cfg_row, n_feasible: int, wl: Workload, c: DeviceConstants,
                  n_evaluated: int, n_workload_evals: int,
                  wall: float) -> SearchResult:
@@ -762,8 +764,9 @@ def _sequential_pareto(grid, wl: Workload, constraints: Constraints,
 
 def _pareto_result(cand_rows, n_feasible, wl, constraints, c, objectives,
                    n_evaluated, n_wl, t0) -> ParetoResult:
-    front, met, _ = _pareto_from_rows(cand_rows, wl, constraints, c,
-                                      objectives)
+    with span("search.refine"):
+        front, met, _ = _pareto_from_rows(cand_rows, wl, constraints, c,
+                                          objectives)
     return ParetoResult(front=front, metrics=met, objectives=objectives,
                         n_evaluated=n_evaluated, n_feasible=n_feasible,
                         n_workload_evals=n_wl,
@@ -1311,6 +1314,7 @@ def _empty_run_state():
             {k: np.zeros(0, np.float64) for k in REPORT_METRICS})
 
 
+@traced("search.refine")
 def _merge_running_front(run_rows, run_met, cand_rows, wl, constraints, c,
                          objectives):
     """Fold one chunk/shard's candidate rows into the bounded running
@@ -1383,8 +1387,9 @@ def _pareto_streamed(grid, wl, constraints, engine, hierarchical, c,
             rt.unit_done(fp, u, encode_front(run_rows, run_met,
                                              REPORT_METRICS),
                          {"nf": nf, "n_wl": n_wl, "n_over": n_over})
-    front, met, _ = _pareto_from_rows(run_rows, wl, constraints, c,
-                                      objectives, m=run_met)
+    with span("search.refine"):
+        front, met, _ = _pareto_from_rows(run_rows, wl, constraints, c,
+                                          objectives, m=run_met)
     res = ParetoResult(front=front, metrics=met, objectives=objectives,
                        n_evaluated=n, n_feasible=nf, n_workload_evals=n_wl,
                        wall_time_s=time.perf_counter() - t0,
@@ -1900,8 +1905,9 @@ def _pareto_factorized(fspace, wl, constraints, engine, c, interpret,
             rt.unit_done(fp, u, encode_front(run_rows, run_met,
                                              REPORT_METRICS),
                          {"nf": nf, "n_wl": n_wl, "n_over": n_over})
-    front, met, _ = _pareto_from_rows(run_rows, wl, constraints, c,
-                                      objectives, m=run_met)
+    with span("search.refine"):
+        front, met, _ = _pareto_from_rows(run_rows, wl, constraints, c,
+                                          objectives, m=run_met)
     res = ParetoResult(front=front, metrics=met, objectives=objectives,
                        n_evaluated=fspace.size, n_feasible=nf,
                        n_workload_evals=n_wl,
@@ -2025,6 +2031,7 @@ def _slab_first_indices(radices, ranges_list) -> np.ndarray:
     return arr[:, :, 0] @ strides
 
 
+@traced("search.descend")
 def _bnb_descend(fspace, ev, prune_mask_fn, start, start_lbs, leaf_size,
                  stats, c, led=None):
     """Shared slab-tree descent: process the active set — a (B, 5, 2)
@@ -2398,9 +2405,10 @@ def _search_factorized_bnb(fspace, wl, constraints, engine, c, interpret,
             # The pruning incumbent is the winner's float64 reference EDP,
             # so the slab schedule is identical no matter which engine
             # proposed the winner.
-            cfg = PTAConfig.from_array(fspace.decode([merged[0]])[0])
-            _, _, energy, latency = eval_full(cfg, wl, c)[:4]
-            state["inc"] = calc_edp(energy, latency)
+            with span("search.refine"):
+                cfg = PTAConfig.from_array(fspace.decode([merged[0]])[0])
+                _, _, energy, latency = eval_full(cfg, wl, c)[:4]
+                state["inc"] = calc_edp(energy, latency)
 
     def snapshot():
         st = encode_best_indexed(state["best"])
@@ -2658,8 +2666,9 @@ def _pareto_factorized_bnb(fspace, wl, constraints, engine, c, interpret,
         if rt is not None:
             snapshot()
             unit += 1
-    front, met, _ = _pareto_from_rows(state["rows"], wl, constraints, c,
-                                      objectives, m=state["met"])
+    with span("search.refine"):
+        front, met, _ = _pareto_from_rows(state["rows"], wl, constraints, c,
+                                          objectives, m=state["met"])
     res = ParetoResult(front=front, metrics=met, objectives=objectives,
                        n_evaluated=fspace.size, n_feasible=state["nf"],
                        n_workload_evals=state["n_eval"],
@@ -2723,8 +2732,10 @@ def _workloads_pallas_factorized(wls, names, cons_for, fspace, c, interpret,
     wall = time.perf_counter() - t0
     out = {}
     for nm in names:
-        front, met, _ = _pareto_from_rows(run[nm][0], wls[nm], cons_for(nm),
-                                          c, metrics, m=run[nm][1])
+        with span("search.refine"):
+            front, met, _ = _pareto_from_rows(run[nm][0], wls[nm],
+                                              cons_for(nm), c, metrics,
+                                              m=run[nm][1])
         out[nm] = ParetoResult(front=front, metrics=met, objectives=metrics,
                                n_evaluated=fspace.size, n_feasible=nf[nm],
                                n_workload_evals=n_wl, wall_time_s=wall,
@@ -2949,6 +2960,7 @@ def _robust_vertex_search(wl, constraints, cal, engine, grid, n_z,
     return res
 
 
+@traced("search")
 def search(wl: Workload, constraints: Constraints = Constraints(), *,
            engine: str = "numpy", grid: Optional[np.ndarray] = None,
            n_z: int = 12, hierarchical: bool = False,
@@ -3277,8 +3289,10 @@ def _workloads_pallas_streamed(wls, names, cons_for, grid, hierarchical, c,
     wall = time.perf_counter() - t0
     out = {}
     for nm in names:
-        front, met, _ = _pareto_from_rows(run[nm][0], wls[nm], cons_for(nm),
-                                          c, metrics, m=run[nm][1])
+        with span("search.refine"):
+            front, met, _ = _pareto_from_rows(run[nm][0], wls[nm],
+                                              cons_for(nm), c, metrics,
+                                              m=run[nm][1])
         out[nm] = ParetoResult(front=front, metrics=met, objectives=metrics,
                                n_evaluated=n, n_feasible=nf[nm],
                                n_workload_evals=n_wl, wall_time_s=wall,
@@ -3286,6 +3300,7 @@ def _workloads_pallas_streamed(wls, names, cons_for, grid, hierarchical, c,
     return out
 
 
+@traced("search")
 def search_workloads(wls: Union[Mapping[str, Workload], Sequence[Workload]],
                      constraints: Union[Constraints,
                                         Mapping[str, Constraints]]

@@ -582,6 +582,7 @@ def dse_eval_padded(cfg_cols, *, gemms: tuple, wl_scalars: tuple,
         out_specs=pl.BlockSpec((4, BLOCK), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((4, cfg_cols.shape[1]), jnp.float32),
         interpret=resolve_interpret(interpret),
+        name="dse_eval_padded",
     )(cfg_cols)
     return out[:, :g]
 
@@ -629,6 +630,7 @@ def dse_search_padded(cfg_cols, mask, cons, carry, *, workloads: tuple,
         out_specs=out_spec,
         out_shape=out_shape,
         interpret=resolve_interpret(interpret),
+        name="dse_search_padded",
     )(cfg_cols, mask, cons, carry)
     return out[:, ::LANES]
 
@@ -676,6 +678,7 @@ def dse_pareto_padded(cfg_cols, mask, cons, carry, *, workloads: tuple,
         out_specs=out_spec,
         out_shape=out_shape,
         interpret=resolve_interpret(interpret),
+        name="dse_pareto_padded",
     )(cfg_cols, mask, cons, carry)
     return _front_rows(masks, 0, w, n_blocks)
 
@@ -725,6 +728,7 @@ def dse_search_decoded(axes, meta, cons, carry, *, radices: tuple,
         out_specs=out_spec,
         out_shape=out_shape,
         interpret=resolve_interpret(interpret),
+        name="dse_search_decoded",
     )(axes, meta, cons, carry)
     return out[:, ::LANES]
 
@@ -755,6 +759,7 @@ def dse_pareto_decoded(axes, meta, cons, carry, *, radices: tuple,
         out_specs=out_spec,
         out_shape=out_shape,
         interpret=resolve_interpret(interpret),
+        name="dse_pareto_decoded",
     )(axes, meta, cons, carry)
     return _front_rows(masks, meta[0, 0], w, n_blocks)
 
@@ -774,4 +779,5 @@ def dse_decode_rows(axes, meta, *, radices: tuple, n_blocks: int,
         out_specs=pl.BlockSpec((6, BLOCK), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((6, n_blocks * BLOCK), jnp.float32),
         interpret=resolve_interpret(interpret),
+        name="dse_decode_rows",
     )(axes, meta)

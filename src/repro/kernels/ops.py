@@ -24,6 +24,7 @@ from repro.core.arch_params import PTAConfig
 from repro.core.performance_model import workload_statics
 from repro.core.photonic_model import CONSTANTS, DeviceConstants
 from repro.core.workload import Workload
+from repro.tracing import span
 
 from . import ddot_gemm as _ddot
 from . import dse_eval as _dse
@@ -45,6 +46,13 @@ def _integrity_check(out, what: str):
     a = np.asarray(out)
     if a.dtype.kind == "f" and np.isnan(a).any():
         raise _runtime.NanDetected(f"NaN in {what} kernel output block")
+
+
+def _wait(out) -> np.ndarray:
+    """A launch's output on the host: device execution plus readback, the
+    one blocking step of a launch."""
+    with span("launch.wait"):
+        return np.asarray(out)
 
 
 def _pad_to(x, m0, m1):
@@ -123,13 +131,15 @@ def dse_eval_grid(grid: np.ndarray, wl: Workload,
     the same compiled arithmetic as the search kernels' (the carried-front
     prune compares the two bit for bit)."""
     g = np.asarray(grid)
-    cols = np.ones((5, -(-len(g) // _dse.BLOCK) * _dse.BLOCK), np.float32)
-    cols[:, :len(g)] = g.T
-    gemms, wl_scalars = workload_statics(wl, c)
-    out = _dse.dse_eval_padded(jnp.asarray(cols), gemms=gemms,
-                               wl_scalars=wl_scalars, constants=c,
-                               interpret=interpret)
-    return np.asarray(out)[:, :len(g)].T
+    g_pad = -(-len(g) // _dse.BLOCK) * _dse.BLOCK
+    with span("launch", lanes=g_pad):
+        cols = np.ones((5, g_pad), np.float32)
+        cols[:, :len(g)] = g.T
+        gemms, wl_scalars = workload_statics(wl, c)
+        out = _dse.dse_eval_padded(jnp.asarray(cols), gemms=gemms,
+                                   wl_scalars=wl_scalars, constants=c,
+                                   interpret=interpret)
+        return _wait(out)[:, :len(g)].T
 
 
 def _constraint_rows(constraints_seq) -> jnp.ndarray:
@@ -238,8 +248,7 @@ def _sharded_kernel_out(grid: np.ndarray, shard: int, kind: str,
     spec = candidate_spec(2, 1)
     assert sanitize_spec(cols.shape, spec, {CANDIDATE_AXIS: k}) == spec
     fn = _sharded_kernel_fn(kind, statics, k)
-    return np.asarray(fn(cols, mask, cons, carry)), shard_size, \
-        blocks_per_shard
+    return _wait(fn(cols, mask, cons, carry)), shard_size, blocks_per_shard
 
 
 def dse_search_grid(grid: np.ndarray, wl: Workload, constraints,
@@ -295,41 +304,43 @@ def dse_search_multi(grid: np.ndarray, wls, constraints_seq,
     stands. n_feasible counts this grid only — streaming callers
     accumulate it across chunks themselves.
     """
-    workloads = tuple(workload_statics(wl, c) for wl in wls)
-    cons = _constraint_rows(constraints_seq)
-    carry = _search_carry_rows(carry_edp, len(workloads))
+    with span("launch") as sp:
+        workloads = tuple(workload_statics(wl, c) for wl in wls)
+        cons = _constraint_rows(constraints_seq)
+        carry = _search_carry_rows(carry_edp, len(workloads))
 
-    if shard is not None and int(shard) > 1:
-        out, shard_size, blocks_per_shard = _sharded_kernel_out(
-            grid, shard, "search", (workloads, c, interpret), cons, carry)
-        col_base = (np.arange(out.shape[1], dtype=np.int64)
-                    // blocks_per_shard) * shard_size
-    else:
-        cols, mask = _bucketed_cols(grid)
-        out = np.asarray(_dse.dse_search_padded(
-            cols, mask, cons, carry, workloads=workloads, constants=c,
-            interpret=interpret))
-        col_base = np.zeros(out.shape[1], np.int64)
-    _integrity_check(out, "dse_search")
-    best_idx, best_edp, n_feasible = [], [], []
-    for w in range(len(workloads)):
-        edp_b, idx_b, nf_b = out[_dse.SEARCH_ROWS * w:
-                                 _dse.SEARCH_ROWS * (w + 1)]
-        nf = int(round(float(nf_b.sum())))
-        n_feasible.append(nf)
-        # Shard-local indices -> grid-global (sentinels stay put).
-        idx_g = np.where(idx_b >= 0, idx_b + col_base, idx_b)
-        # Min EDP across blocks; ties broken towards the lowest global
-        # index, matching the sequential/numpy engines' first-hit rule
-        # (CARRY_IDX sorts before every real index, so a carried tie wins).
-        jb = np.lexsort((idx_g, edp_b))[0]
-        i = int(idx_g[jb])
-        best_edp.append(float(edp_b[jb]))
-        if nf == 0 and carry_edp is None:
-            best_idx.append(-1)
-            continue
-        best_idx.append(i if i >= 0 else int(_dse.CARRY_IDX))
-    return best_idx, best_edp, n_feasible
+        if shard is not None and int(shard) > 1:
+            out, shard_size, blocks_per_shard = _sharded_kernel_out(
+                grid, shard, "search", (workloads, c, interpret), cons, carry)
+            col_base = (np.arange(out.shape[1], dtype=np.int64)
+                        // blocks_per_shard) * shard_size
+        else:
+            cols, mask = _bucketed_cols(grid)
+            out = _wait(_dse.dse_search_padded(
+                cols, mask, cons, carry, workloads=workloads, constants=c,
+                interpret=interpret))
+            col_base = np.zeros(out.shape[1], np.int64)
+        sp.set_metadata(lanes=out.shape[1] * _dse.BLOCK)
+        _integrity_check(out, "dse_search")
+        best_idx, best_edp, n_feasible = [], [], []
+        for w in range(len(workloads)):
+            edp_b, idx_b, nf_b = out[_dse.SEARCH_ROWS * w:
+                                     _dse.SEARCH_ROWS * (w + 1)]
+            nf = int(round(float(nf_b.sum())))
+            n_feasible.append(nf)
+            # Shard-local indices -> grid-global (sentinels stay put).
+            idx_g = np.where(idx_b >= 0, idx_b + col_base, idx_b)
+            # Min EDP across blocks; ties broken towards the lowest global
+            # index, matching the sequential/numpy engines' first-hit rule
+            # (CARRY_IDX sorts before every real index, so a carried tie wins).
+            jb = np.lexsort((idx_g, edp_b))[0]
+            i = int(idx_g[jb])
+            best_edp.append(float(edp_b[jb]))
+            if nf == 0 and carry_edp is None:
+                best_idx.append(-1)
+                continue
+            best_idx.append(i if i >= 0 else int(_dse.CARRY_IDX))
+        return best_idx, best_edp, n_feasible
 
 
 def dse_pareto_multi(grid: np.ndarray, wls, constraints_seq,
@@ -363,53 +374,55 @@ def dse_pareto_multi(grid: np.ndarray, wls, constraints_seq,
     differently than under float64 — real design points never ride that
     edge.
     """
-    workloads = tuple(workload_statics(wl, c) for wl in wls)
-    cons = _constraint_rows(constraints_seq)
-    objectives = tuple(objectives)
-    has_carry = carry_points is not None and any(
-        p is not None and len(p) for p in carry_points)
-    carry = _front_carry_rows(carry_points, len(workloads), len(objectives))
+    with span("launch") as sp:
+        workloads = tuple(workload_statics(wl, c) for wl in wls)
+        cons = _constraint_rows(constraints_seq)
+        objectives = tuple(objectives)
+        has_carry = carry_points is not None and any(
+            p is not None and len(p) for p in carry_points)
+        carry = _front_carry_rows(carry_points, len(workloads), len(objectives))
 
-    if shard is not None and int(shard) > 1:
-        out, shard_size, blocks_per_shard = _sharded_kernel_out(
-            grid, shard, "pareto",
-            (workloads, objectives, has_carry, c, interpret), cons, carry)
-        n_cols = out.shape[1]
-        col_base = (np.arange(n_cols, dtype=np.int64)
-                    // blocks_per_shard) * shard_size
-        blk_lo = col_base + (np.arange(n_cols, dtype=np.int64)
-                             % blocks_per_shard) * _dse.BLOCK
-    else:
-        cols, mask = _bucketed_cols(grid)
-        out = np.asarray(_dse.dse_pareto_padded(
-            cols, mask, cons, carry, workloads=workloads,
-            objectives=objectives, has_carry=has_carry, constants=c,
-            interpret=interpret))
-        n_cols = out.shape[1]
-        col_base = np.zeros(n_cols, np.int64)
-        blk_lo = np.arange(n_cols, dtype=np.int64) * _dse.BLOCK
-    _integrity_check(out, "dse_pareto")
-    results = []
-    for w in range(len(workloads)):
-        rows = out[_dse.PARETO_ROWS * w:_dse.PARETO_ROWS * (w + 1)]
-        counts, nfeas_b = rows[0], rows[1]
-        # Shard-local block indices -> grid-global via the column's base.
-        idx = rows[_dse.PARETO_HEADER:] + col_base[None, :]
-        cand = idx[rows[_dse.PARETO_HEADER:] >= 0].astype(np.int64)
-        overflowed = np.nonzero(counts > _dse.MAX_FRONT)[0]
-        if len(overflowed):
-            log.warning("pareto kernel: %d block(s) overflowed MAX_FRONT"
-                        "=%d; falling back to whole-block candidates "
-                        "(exact, host-refined)", len(overflowed),
-                        _dse.MAX_FRONT)
-        for b in overflowed:
-            lo = int(blk_lo[b])
-            cand = np.concatenate(
-                [cand, np.arange(lo, min(lo + _dse.BLOCK, len(grid)))])
-        results.append((np.unique(cand),
-                        int(round(float(nfeas_b.sum()))),
-                        int(len(overflowed))))
-    return results
+        if shard is not None and int(shard) > 1:
+            out, shard_size, blocks_per_shard = _sharded_kernel_out(
+                grid, shard, "pareto",
+                (workloads, objectives, has_carry, c, interpret), cons, carry)
+            n_cols = out.shape[1]
+            col_base = (np.arange(n_cols, dtype=np.int64)
+                        // blocks_per_shard) * shard_size
+            blk_lo = col_base + (np.arange(n_cols, dtype=np.int64)
+                                 % blocks_per_shard) * _dse.BLOCK
+        else:
+            cols, mask = _bucketed_cols(grid)
+            out = _wait(_dse.dse_pareto_padded(
+                cols, mask, cons, carry, workloads=workloads,
+                objectives=objectives, has_carry=has_carry, constants=c,
+                interpret=interpret))
+            n_cols = out.shape[1]
+            col_base = np.zeros(n_cols, np.int64)
+            blk_lo = np.arange(n_cols, dtype=np.int64) * _dse.BLOCK
+        sp.set_metadata(lanes=n_cols * _dse.BLOCK)
+        _integrity_check(out, "dse_pareto")
+        results = []
+        for w in range(len(workloads)):
+            rows = out[_dse.PARETO_ROWS * w:_dse.PARETO_ROWS * (w + 1)]
+            counts, nfeas_b = rows[0], rows[1]
+            # Shard-local block indices -> grid-global via the column's base.
+            idx = rows[_dse.PARETO_HEADER:] + col_base[None, :]
+            cand = idx[rows[_dse.PARETO_HEADER:] >= 0].astype(np.int64)
+            overflowed = np.nonzero(counts > _dse.MAX_FRONT)[0]
+            if len(overflowed):
+                log.warning("pareto kernel: %d block(s) overflowed MAX_FRONT"
+                            "=%d; falling back to whole-block candidates "
+                            "(exact, host-refined)", len(overflowed),
+                            _dse.MAX_FRONT)
+            for b in overflowed:
+                lo = int(blk_lo[b])
+                cand = np.concatenate(
+                    [cand, np.arange(lo, min(lo + _dse.BLOCK, len(grid)))])
+            results.append((np.unique(cand),
+                            int(round(float(nfeas_b.sum()))),
+                            int(len(overflowed))))
+        return results
 
 
 # ---------------------------------------------------------------------------
@@ -542,7 +555,7 @@ def _decoded_launch(space, start: int, count: int, kind: str, statics: tuple,
         bases = start + np.arange(k) * bps * block
         meta = _meta_rows(radices, bases, limit, slab)
         fn = _sharded_decoded_fn(kind, statics, k, radices, bps)
-        out = np.asarray(fn(axes_cols, jnp.asarray(meta), cons, carry))
+        out = _wait(fn(axes_cols, jnp.asarray(meta), cons, carry))
         blk_lo = (np.repeat(meta[:, 0].astype(np.int64), bps)
                   + np.tile(np.arange(bps, dtype=np.int64), k) * block)
         return out, blk_lo
@@ -562,7 +575,7 @@ def _decoded_launch(space, start: int, count: int, kind: str, statics: tuple,
             n_blocks=n_blocks, workloads=workloads, objectives=objectives,
             has_carry=has_carry, constants=constants, interpret=interpret)
     blk_lo = start + np.arange(n_blocks, dtype=np.int64) * block
-    return np.asarray(out), blk_lo
+    return _wait(out), blk_lo
 
 
 def dse_search_multi_factorized(space, start: int, count: int, wls,
@@ -580,29 +593,31 @@ def dse_search_multi_factorized(space, start: int, count: int, wls,
     slab's members in-kernel — the bound-guided search launches each
     surviving slab over its bounding index range this way.
     """
-    workloads = tuple(workload_statics(wl, c) for wl in wls)
-    cons = _constraint_rows(constraints_seq)
-    carry = _search_carry_rows(carry_edp, len(workloads))
-    out, _ = _decoded_launch(space, start, count, "search",
-                             (workloads, c, interpret), cons, carry, shard,
-                             slab)
-    _integrity_check(out, "dse_search_decoded")
-    best_idx, best_edp, n_feasible = [], [], []
-    for w in range(len(workloads)):
-        edp_b, idx_b, nf_b = out[_dse.SEARCH_ROWS * w:
-                                 _dse.SEARCH_ROWS * (w + 1)]
-        nf = int(round(float(nf_b.sum())))
-        n_feasible.append(nf)
-        # Indices are already global; min EDP with ties to the lowest index
-        # (CARRY_IDX sorts before every real index, so a carried tie wins).
-        jb = np.lexsort((idx_b, edp_b))[0]
-        i = int(idx_b[jb])
-        best_edp.append(float(edp_b[jb]))
-        if nf == 0 and carry_edp is None:
-            best_idx.append(-1)
-            continue
-        best_idx.append(i if i >= 0 else int(_dse.CARRY_IDX))
-    return best_idx, best_edp, n_feasible
+    with span("launch") as sp:
+        workloads = tuple(workload_statics(wl, c) for wl in wls)
+        cons = _constraint_rows(constraints_seq)
+        carry = _search_carry_rows(carry_edp, len(workloads))
+        out, blk_lo = _decoded_launch(space, start, count, "search",
+                                      (workloads, c, interpret), cons, carry,
+                                      shard, slab)
+        sp.set_metadata(lanes=len(blk_lo) * _dse.DECODE_BLOCK)
+        _integrity_check(out, "dse_search_decoded")
+        best_idx, best_edp, n_feasible = [], [], []
+        for w in range(len(workloads)):
+            edp_b, idx_b, nf_b = out[_dse.SEARCH_ROWS * w:
+                                     _dse.SEARCH_ROWS * (w + 1)]
+            nf = int(round(float(nf_b.sum())))
+            n_feasible.append(nf)
+            # Indices are already global; min EDP with ties to the lowest index
+            # (CARRY_IDX sorts before every real index, so a carried tie wins).
+            jb = np.lexsort((idx_b, edp_b))[0]
+            i = int(idx_b[jb])
+            best_edp.append(float(edp_b[jb]))
+            if nf == 0 and carry_edp is None:
+                best_idx.append(-1)
+                continue
+            best_idx.append(i if i >= 0 else int(_dse.CARRY_IDX))
+        return best_idx, best_edp, n_feasible
 
 
 def dse_pareto_multi_factorized(space, start: int, count: int, wls,
@@ -618,41 +633,43 @@ def dse_pareto_multi_factorized(space, start: int, count: int, wls,
     `dse_search_multi_factorized` (an overflowing block's whole-block
     fallback is clipped back to slab members, so candidate lists never leak
     lanes the launch was asked to mask)."""
-    workloads = tuple(workload_statics(wl, c) for wl in wls)
-    cons = _constraint_rows(constraints_seq)
-    objectives = tuple(objectives)
-    has_carry = carry_points is not None and any(
-        p is not None and len(p) for p in carry_points)
-    carry = _front_carry_rows(carry_points, len(workloads), len(objectives))
-    out, blk_lo = _decoded_launch(
-        space, start, count, "pareto",
-        (workloads, objectives, has_carry, c, interpret), cons, carry,
-        shard, slab)
-    limit = min(start + count, space.size)
-    _integrity_check(out, "dse_pareto_decoded")
-    results = []
-    for w in range(len(workloads)):
-        rows = out[_dse.PARETO_ROWS * w:_dse.PARETO_ROWS * (w + 1)]
-        counts, nfeas_b = rows[0], rows[1]
-        idx = rows[_dse.PARETO_HEADER:]
-        cand = idx[idx >= 0].astype(np.int64)
-        overflowed = np.nonzero(counts > _dse.MAX_FRONT)[0]
-        if len(overflowed):
-            log.warning("pareto decode kernel: %d block(s) overflowed "
-                        "MAX_FRONT=%d; falling back to whole-block "
-                        "candidates (exact, host-refined)",
-                        len(overflowed), _dse.MAX_FRONT)
-        for b in overflowed:
-            lo = int(blk_lo[b])
-            fallback = np.arange(lo, min(lo + _dse.BLOCK, limit))
-            if slab is not None:
-                fallback = fallback[
-                    _slab_member_mask(space.radices, slab, fallback)]
-            cand = np.concatenate([cand, fallback])
-        results.append((np.unique(cand),
-                        int(round(float(nfeas_b.sum()))),
-                        int(len(overflowed))))
-    return results
+    with span("launch") as sp:
+        workloads = tuple(workload_statics(wl, c) for wl in wls)
+        cons = _constraint_rows(constraints_seq)
+        objectives = tuple(objectives)
+        has_carry = carry_points is not None and any(
+            p is not None and len(p) for p in carry_points)
+        carry = _front_carry_rows(carry_points, len(workloads), len(objectives))
+        out, blk_lo = _decoded_launch(
+            space, start, count, "pareto",
+            (workloads, objectives, has_carry, c, interpret), cons, carry,
+            shard, slab)
+        sp.set_metadata(lanes=len(blk_lo) * _dse.BLOCK)
+        limit = min(start + count, space.size)
+        _integrity_check(out, "dse_pareto_decoded")
+        results = []
+        for w in range(len(workloads)):
+            rows = out[_dse.PARETO_ROWS * w:_dse.PARETO_ROWS * (w + 1)]
+            counts, nfeas_b = rows[0], rows[1]
+            idx = rows[_dse.PARETO_HEADER:]
+            cand = idx[idx >= 0].astype(np.int64)
+            overflowed = np.nonzero(counts > _dse.MAX_FRONT)[0]
+            if len(overflowed):
+                log.warning("pareto decode kernel: %d block(s) overflowed "
+                            "MAX_FRONT=%d; falling back to whole-block "
+                            "candidates (exact, host-refined)",
+                            len(overflowed), _dse.MAX_FRONT)
+            for b in overflowed:
+                lo = int(blk_lo[b])
+                fallback = np.arange(lo, min(lo + _dse.BLOCK, limit))
+                if slab is not None:
+                    fallback = fallback[
+                        _slab_member_mask(space.radices, slab, fallback)]
+                cand = np.concatenate([cand, fallback])
+            results.append((np.unique(cand),
+                            int(round(float(nfeas_b.sum()))),
+                            int(len(overflowed))))
+        return results
 
 
 # ---------------------------------------------------------------------------
@@ -732,15 +749,16 @@ def decode_rows_device(space, start: int, count: int,
     the in-kernel candidate generation. With `slab` (five [lo, hi) digit
     ranges), only the span's slab-member lanes survive the validity mask —
     the decoded form of `space.decode(slab_indices(...))`."""
-    axes_cols, radices = _axes_operand(space)
     n_blocks = max(1, -(-count // _dse.BLOCK))
     limit = min(start + count, space.size)
     _check_decode_span(limit)
-    meta = jnp.asarray(_meta_rows(radices, [start], limit, slab))
-    out = np.asarray(_dse.dse_decode_rows(axes_cols, meta, radices=radices,
-                                          n_blocks=n_blocks,
-                                          interpret=interpret))
-    return out[:5, out[5] > 0.0].T.astype(np.int64)
+    with span("launch", lanes=n_blocks * _dse.BLOCK):
+        axes_cols, radices = _axes_operand(space)
+        meta = jnp.asarray(_meta_rows(radices, [start], limit, slab))
+        out = _wait(_dse.dse_decode_rows(axes_cols, meta, radices=radices,
+                                         n_blocks=n_blocks,
+                                         interpret=interpret))
+        return out[:5, out[5] > 0.0].T.astype(np.int64)
 
 
 def pallas_grid_search(grid: np.ndarray, wl: Workload, constraints,

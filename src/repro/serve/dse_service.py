@@ -37,7 +37,6 @@ import collections
 import contextlib
 import dataclasses
 import logging
-import time
 from typing import Dict, List, Mapping, Optional, Union
 
 import numpy as np
@@ -56,6 +55,7 @@ from repro.core.search import (DEFAULT_OBJECTIVES, ParetoResult,
                                _resolve_robust, _search_factorized_bnb,
                                search, search_workloads)
 from repro.core.workload import Workload
+from repro.tracing import span, traced
 
 from .batching import QueryBatcher, ServeQuery
 from .cache import (Box, base_key, box_constraints, box_contains,
@@ -206,6 +206,7 @@ class SearchService:
 
     # -- public surface ----------------------------------------------------
 
+    @traced("service.query")
     def query(self, wl: Workload,
               constraints: Union[Constraints, Mapping] = Constraints(), *,
               objective: str = "edp",
@@ -249,6 +250,7 @@ class SearchService:
             canonical_box(constraints)), objective=objective,
             pareto_metrics=pareto_metrics, deadline_s=deadline_s))
 
+    @traced("service.query")
     def drain(self) -> List[Union[Result, QueryTimeout]]:
         """Answer every queued question, in arrival order.
 
@@ -461,7 +463,31 @@ class SearchService:
     def _delta(self, base: _BaseEntry, q: ServeQuery) -> Result:
         """Warm constraint-delta answer: filter the point store, re-price
         the pruned slabs, descend only the revived ones."""
-        t0 = time.perf_counter()
+        with span("service.reprice"):
+            warm = self._reprice(base, q)
+        cons = q.constraints
+        metrics = self._metrics(q)
+        with span("search"), \
+                self._maybe_executor(q.wl, cons, q.objective, metrics) as ex:
+            if q.objective == "edp":
+                res = _search_factorized_bnb(
+                    self.space, q.wl, cons, self.engine, self.c,
+                    self.interpret, self.shard, self.chunk_size,
+                    warm=warm, executor=ex)
+            else:
+                res = _pareto_factorized_bnb(
+                    self.space, q.wl, cons, self.engine, self.c,
+                    self.interpret, metrics, self.shard, self.chunk_size,
+                    warm=warm, executor=ex)
+        if self.calibration is not None:
+            res.band = _measure_band(res, self.calibration, q.wl)
+        self.stats["slabs_repriced"] += len(base.ledger.pruned)
+        self.stats["slabs_revived"] += len(warm.start)
+        return res
+
+    def _reprice(self, base: _BaseEntry, q: ServeQuery) -> WarmStart:
+        """The stored points feasible under the query's box, and the
+        stored slabs its box and their best point cannot kill."""
         cons = q.constraints
         dead = _bnb_infeasible_mask(base.ledger.bounds, cons)
         if q.objective == "edp":
@@ -475,38 +501,17 @@ class SearchService:
                 dead |= np.asarray(base.ledger.bounds["edp"]) > best[1]
             else:
                 best = (-1, float("inf"))
-            warm = WarmStart(
+            return WarmStart(
                 start=base.ledger.pruned[~dead],
-                lbs={k2: v[~dead]
-                     for k2, v in base.ledger.bounds.items()},
+                lbs={k2: v[~dead] for k2, v in base.ledger.bounds.items()},
                 best=best, nf=int(ok.sum()))
-            with self._maybe_executor(q.wl, cons, "edp", None) as ex:
-                res = _search_factorized_bnb(
-                    self.space, q.wl, cons, self.engine, self.c,
-                    self.interpret, self.shard, self.chunk_size,
-                    warm=warm, executor=ex)
-        else:
-            metrics = self._metrics(q)
-            front, met, nf = _pareto_from_rows(base.rows, q.wl, cons,
-                                               self.c, metrics, m=base.met)
-            pts = (np.stack([met[k] for k in metrics], axis=1)
-                   if len(front) else np.zeros((0, len(metrics))))
-            dead |= _bnb_dominated_vs(pts, base.ledger.bounds, metrics)
-            warm = WarmStart(
-                start=base.ledger.pruned[~dead],
-                lbs={k2: v[~dead]
-                     for k2, v in base.ledger.bounds.items()},
-                rows=front, met=met, nf=nf)
-            with self._maybe_executor(q.wl, cons, "pareto", metrics) as ex:
-                res = _pareto_factorized_bnb(
-                    self.space, q.wl, cons, self.engine, self.c,
-                    self.interpret, metrics, self.shard, self.chunk_size,
-                    warm=warm, executor=ex)
-        if self.calibration is not None:
-            res.band = _measure_band(res, self.calibration, q.wl)
-        self.stats["slabs_repriced"] += len(base.ledger.pruned)
-        self.stats["slabs_revived"] += int((~dead).sum())
-        log.debug("delta query served warm in %.3fms: %d/%d slabs revived",
-                  (time.perf_counter() - t0) * 1e3, int((~dead).sum()),
-                  len(base.ledger.pruned))
-        return res
+        metrics = self._metrics(q)
+        front, met, nf = _pareto_from_rows(base.rows, q.wl, cons, self.c,
+                                           metrics, m=base.met)
+        pts = (np.stack([met[k] for k in metrics], axis=1)
+               if len(front) else np.zeros((0, len(metrics))))
+        dead |= _bnb_dominated_vs(pts, base.ledger.bounds, metrics)
+        return WarmStart(
+            start=base.ledger.pruned[~dead],
+            lbs={k2: v[~dead] for k2, v in base.ledger.bounds.items()},
+            rows=front, met=met, nf=nf)

@@ -78,6 +78,14 @@ def _f32(*shape):
     return shape, jnp.float32
 
 
+def _custom_call(compiled, name: str) -> bool:
+    """The compiled program runs a Mosaic custom call named `name` (the
+    kernel's stable `pallas_call` name, which the device trace shows)."""
+    return any(line.lstrip().removeprefix("ROOT ").startswith(f"%{name}")
+               and 'custom_call_target="tpu_custom_call"' in line
+               for line in compiled.as_text().splitlines())
+
+
 META = ((1, K.META_COLS), jnp.int32)
 AXES_20 = _f32(5, 20)
 
@@ -88,7 +96,8 @@ def test_dse_eval_padded_compiles(compile_v5e, name):
     fn = functools.partial(K.dse_eval_padded, gemms=gemms,
                            wl_scalars=wl_scalars, constants=CONSTANTS,
                            interpret=False)
-    compile_v5e(fn, _f32(5, 16 * K.BLOCK))
+    assert _custom_call(compile_v5e(fn, _f32(5, 16 * K.BLOCK)),
+                        "dse_eval_padded")
 
 
 def test_dse_search_padded_compiles(compile_v5e):
@@ -96,7 +105,9 @@ def test_dse_search_padded_compiles(compile_v5e):
     g = 8 * K.BLOCK
     fn = functools.partial(K.dse_search_padded, workloads=_workloads("deit-b"),
                            constants=CONSTANTS, interpret=False)
-    compile_v5e(fn, _f32(5, g), _f32(1, g), _f32(1, 4), _f32(1, 1))
+    assert _custom_call(
+        compile_v5e(fn, _f32(5, g), _f32(1, g), _f32(1, 4), _f32(1, 1)),
+        "dse_search_padded")
 
 
 def test_dse_search_decoded_compiles(compile_v5e):
@@ -104,7 +115,8 @@ def test_dse_search_decoded_compiles(compile_v5e):
     fn = functools.partial(K.dse_search_decoded, radices=R20, n_blocks=1,
                            workloads=_workloads("deit-b"),
                            constants=CONSTANTS, interpret=False)
-    compile_v5e(fn, AXES_20, META, _f32(1, 4), _f32(1, 1))
+    assert _custom_call(compile_v5e(fn, AXES_20, META, _f32(1, 4),
+                                    _f32(1, 1)), "dse_search_decoded")
 
 
 def test_dse_pareto_padded_compiles(compile_v5e):
@@ -114,8 +126,10 @@ def test_dse_pareto_padded_compiles(compile_v5e):
                            workloads=_workloads("bert-l"),
                            objectives=OBJECTIVES, has_carry=True,
                            constants=CONSTANTS, interpret=False)
-    compile_v5e(fn, _f32(5, g), _f32(1, g), _f32(1, 4),
-                _f32(K.CARRY_FRONT, len(OBJECTIVES)))
+    assert _custom_call(
+        compile_v5e(fn, _f32(5, g), _f32(1, g), _f32(1, 4),
+                    _f32(K.CARRY_FRONT, len(OBJECTIVES))),
+        "dse_pareto_padded")
 
 
 def test_dse_pareto_decoded_compiles(compile_v5e):
@@ -124,11 +138,13 @@ def test_dse_pareto_decoded_compiles(compile_v5e):
                            workloads=_workloads("bert-l"),
                            objectives=OBJECTIVES, has_carry=False,
                            constants=CONSTANTS, interpret=False)
-    compile_v5e(fn, AXES_20, META, _f32(1, 4),
-                _f32(K.CARRY_FRONT, len(OBJECTIVES)))
+    assert _custom_call(
+        compile_v5e(fn, AXES_20, META, _f32(1, 4),
+                    _f32(K.CARRY_FRONT, len(OBJECTIVES))),
+        "dse_pareto_decoded")
 
 
 def test_dse_decode_rows_compiles(compile_v5e):
     fn = functools.partial(K.dse_decode_rows, radices=R20, n_blocks=4,
                            interpret=False)
-    compile_v5e(fn, AXES_20, META)
+    assert _custom_call(compile_v5e(fn, AXES_20, META), "dse_decode_rows")
