@@ -37,7 +37,7 @@ import collections
 import contextlib
 import dataclasses
 import logging
-from typing import Dict, List, Mapping, Optional, Union
+from typing import Dict, List, Mapping, Optional, Tuple, Union
 
 import numpy as np
 
@@ -187,8 +187,8 @@ class SearchService:
         self._queue = QueryBatcher()
         self.stats = {"queries": 0, "memo_hits": 0, "warm": 0, "cold": 0,
                       "batched_calls": 0, "slabs_repriced": 0,
-                      "slabs_revived": 0, "evicted_bases": 0,
-                      "timeouts": 0}
+                      "slabs_revived": 0, "slabs_dominance_tested": 0,
+                      "evicted_bases": 0, "timeouts": 0}
         # Frozen-dataclass reprs are deterministic and carry every field,
         # so this digest changes whenever the priced cost model does —
         # including the exact constants corner `robust=` resolved to.
@@ -463,8 +463,9 @@ class SearchService:
     def _delta(self, base: _BaseEntry, q: ServeQuery) -> Result:
         """Warm constraint-delta answer: filter the point store, re-price
         the pruned slabs, descend only the revived ones."""
-        with span("service.reprice"):
-            warm = self._reprice(base, q)
+        with span("service.reprice") as sp:
+            warm, tested = self._reprice(base, q)
+            sp.set_metadata(slabs_tested=tested)
         cons = q.constraints
         metrics = self._metrics(q)
         with span("search"), \
@@ -483,11 +484,15 @@ class SearchService:
             res.band = _measure_band(res, self.calibration, q.wl)
         self.stats["slabs_repriced"] += len(base.ledger.pruned)
         self.stats["slabs_revived"] += len(warm.start)
+        self.stats["slabs_dominance_tested"] += tested
         return res
 
-    def _reprice(self, base: _BaseEntry, q: ServeQuery) -> WarmStart:
+    def _reprice(self, base: _BaseEntry,
+                 q: ServeQuery) -> Tuple[WarmStart, int]:
         """The stored points feasible under the query's box, and the
-        stored slabs its box and their best point cannot kill."""
+        stored slabs its box and their best point cannot kill; with the
+        number of slabs whose corners were tested for Pareto dominance
+        (0 in EDP mode)."""
         cons = q.constraints
         dead = _bnb_infeasible_mask(base.ledger.bounds, cons)
         if q.objective == "edp":
@@ -504,14 +509,19 @@ class SearchService:
             return WarmStart(
                 start=base.ledger.pruned[~dead],
                 lbs={k2: v[~dead] for k2, v in base.ledger.bounds.items()},
-                best=best, nf=int(ok.sum()))
+                best=best, nf=int(ok.sum())), 0
         metrics = self._metrics(q)
         front, met, nf = _pareto_from_rows(base.rows, q.wl, cons, self.c,
                                            metrics, m=base.met)
-        pts = (np.stack([met[k] for k in metrics], axis=1)
-               if len(front) else np.zeros((0, len(metrics))))
-        dead |= _bnb_dominated_vs(pts, base.ledger.bounds, metrics)
+        # Only the slabs the box leaves alive can change by dominance.
+        live = np.flatnonzero(~dead)
+        tested = len(live) if len(front) else 0
+        if tested:
+            pts = np.stack([met[k] for k in metrics], axis=1)
+            dead[live] = _bnb_dominated_vs(
+                pts, {k: base.ledger.bounds[k][live] for k in metrics},
+                metrics)
         return WarmStart(
             start=base.ledger.pruned[~dead],
             lbs={k2: v[~dead] for k2, v in base.ledger.bounds.items()},
-            rows=front, met=met, nf=nf)
+            rows=front, met=met, nf=nf), tested

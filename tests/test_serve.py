@@ -18,7 +18,9 @@ from repro.core.factorized import LedgerRecorder, SlabLedger
 from repro.core.paper_workloads import load
 from repro.core.photonic_model import CONSTANTS
 from repro.core.runtime import query_checkpoint_dir, query_policy
-from repro.core.search import (WarmStart, _search_factorized_bnb)
+from repro.core.search import (WarmStart, _bnb_dominated_vs,
+                               _bnb_infeasible_mask, _pareto_from_rows,
+                               _search_factorized_bnb)
 from repro.serve import (QueryBatcher, SearchService, ServeQuery,
                          box_constraints, box_contains, canonical_box,
                          launch_key, query_key, workload_key)
@@ -210,6 +212,126 @@ def test_incomparable_box_keeps_standing_base():
     assert svc.stats["cold"] == 2
     svc.query(WL, Constraints(power_w=4.2))          # still warm @ 4.5 base
     assert svc.stats["warm"] == 1
+
+
+# ---------------------------------------------------------------------------
+# Warm Pareto re-price: corner dominance on the box's live slabs only.
+# ---------------------------------------------------------------------------
+
+# DeiT-B over 1..8 under a 1.3x paper box: the cold ledger keeps 172
+# pruned slabs, enough for boxes inside it to revive some and kill others.
+REPRICE_SPACE = FactorizedSpace.full(8)
+REPRICE_WL = load("deit-b")
+REPRICE_BASE = {"area_mm2": 65.0, "power_w": 6.5, "energy_mj": 65.0,
+                "latency_ms": 13.0}
+_BOUNDS = tuple(REPRICE_BASE)
+_rng = np.random.default_rng(15)
+REPRICE_CASES = {
+    "base": (1.0, 1.0, 1.0, 1.0),
+    "revives": (0.888, 0.948, 0.764, 0.838),  # some dominated, some live
+    "empty-front": (0.518, 0.885, 0.693, 0.993),  # live slabs, no point
+    "kills-all": (1.0, 1.0, 1.0, 1e-7),      # every slab by constraint
+    **{f"random{i}": tuple(_rng.uniform(0.4, 1.0, 4)) for i in range(14)},
+}
+
+
+def _reprice_query(factors):
+    box = {k: REPRICE_BASE[k] * f for k, f in zip(_BOUNDS, factors)}
+    return ServeQuery(wl=REPRICE_WL, constraints=box_constraints(
+        canonical_box(box)), objective="pareto")
+
+
+@pytest.fixture(scope="module")
+def reprice_service():
+    svc = SearchService(space=REPRICE_SPACE, engine="numpy")
+    svc.query(REPRICE_WL, REPRICE_BASE, objective="pareto")
+    (base,) = svc._base.values()
+    return svc, base
+
+
+def _full_ledger_reprice(svc, base, q):
+    """The re-price as a dominance test over every stored slab."""
+    cons = q.constraints
+    metrics = svc._metrics(q)
+    front, met, nf = _pareto_from_rows(base.rows, q.wl, cons, svc.c,
+                                       metrics, m=base.met)
+    pts = (np.stack([met[k] for k in metrics], axis=1)
+           if len(front) else np.zeros((0, len(metrics))))
+    dead = (_bnb_infeasible_mask(base.ledger.bounds, cons)
+            | _bnb_dominated_vs(pts, base.ledger.bounds, metrics))
+    return WarmStart(
+        start=base.ledger.pruned[~dead],
+        lbs={k: v[~dead] for k, v in base.ledger.bounds.items()},
+        rows=front, met=met, nf=nf)
+
+
+@pytest.mark.parametrize("case", list(REPRICE_CASES))
+def test_warm_pareto_reprice_matches_full_ledger(reprice_service, case):
+    svc, base = reprice_service
+    q = _reprice_query(REPRICE_CASES[case])
+    got, tested = svc._reprice(base, q)
+    ref = _full_ledger_reprice(svc, base, q)
+    live = int((~_bnb_infeasible_mask(base.ledger.bounds,
+                                      q.constraints)).sum())
+    assert tested == (live if len(ref.rows) else 0)
+    assert got.start.dtype == ref.start.dtype
+    assert np.array_equal(got.start, ref.start)
+    assert got.lbs.keys() == ref.lbs.keys()
+    for k in ref.lbs:
+        assert got.lbs[k].dtype == ref.lbs[k].dtype, k
+        assert np.array_equal(got.lbs[k], ref.lbs[k]), k
+    assert np.array_equal(got.rows, ref.rows)
+    assert got.met.keys() == ref.met.keys()
+    for k in ref.met:
+        assert np.array_equal(got.met[k], ref.met[k]), k
+    assert got.nf == ref.nf and got.best == ref.best
+    # Each named case still is what its name says.
+    if case == "revives":
+        assert len(got.rows) and 0 < len(got.start) < tested
+    elif case == "empty-front":
+        assert got.nf == 0 and tested == 0 and len(got.start) == live > 0
+    elif case == "kills-all":
+        assert live == 0 and len(got.start) == 0
+
+
+def test_dominance_counter_counts_warm_pareto_deltas_only(monkeypatch):
+    import contextlib
+    from repro.serve import dse_service
+    spans = []
+
+    class _Span(contextlib.nullcontext):
+        def __enter__(self):
+            return self
+
+        def set_metadata(self, **stats):
+            spans.append((self.enter_result, stats))
+
+    monkeypatch.setattr(dse_service, "span", lambda name: _Span(name))
+    svc = SearchService(space=REPRICE_SPACE, engine="numpy")
+    svc.query(REPRICE_WL, REPRICE_BASE)
+    svc.query(REPRICE_WL, _reprice_query(REPRICE_CASES["revives"])
+              .constraints)
+    assert svc.stats["warm"] == 1 and svc.stats["slabs_repriced"] > 0
+    assert svc.stats["slabs_dominance_tested"] == 0    # EDP traffic only
+    svc.query(REPRICE_WL, REPRICE_BASE, objective="pareto")
+    assert svc.stats["slabs_dominance_tested"] == 0    # a cold query
+    q = _reprice_query(REPRICE_CASES["revives"])
+    svc.query(REPRICE_WL, q.constraints, objective="pareto")
+    base = svc._base[svc._keys(q)[2]]
+    tested = int((~_bnb_infeasible_mask(base.ledger.bounds,
+                                        q.constraints)).sum())
+    assert tested > 0
+    assert svc.stats["slabs_dominance_tested"] == tested
+    assert svc.stats["slabs_dominance_tested"] <= svc.stats["slabs_repriced"]
+    svc.query(REPRICE_WL, q.constraints, objective="pareto")  # memo hit
+    for case in ("kills-all", "empty-front"):    # no test runs
+        svc.query(REPRICE_WL, _reprice_query(REPRICE_CASES[case])
+                  .constraints, objective="pareto")
+    assert svc.stats["warm"] == 4
+    assert svc.stats["slabs_dominance_tested"] == tested
+    # The re-price span carries each warm query's count.
+    assert spans == [("service.reprice", {"slabs_tested": n})
+                     for n in (0, tested, 0, 0)]
 
 
 # ---------------------------------------------------------------------------
