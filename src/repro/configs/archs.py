@@ -32,7 +32,7 @@ OLMOE_1B_7B = ModelConfig(
 # --- deepseek-v3-671b [moe+MLA] — arXiv:2412.19437 ------------------------
 DEEPSEEK_V3_671B = ModelConfig(
     name="deepseek-v3-671b", family="mla_moe", n_layers=61, d_model=7168,
-    n_heads=128, n_kv_heads=128, d_ff=2048, vocab=129280,
+    n_heads=128, n_kv_heads=128, d_ff=18432, vocab=129280,
     mla=MLAConfig(q_lora_rank=1536, kv_lora_rank=512, rope_head_dim=64,
                   nope_head_dim=128, v_head_dim=128),
     moe=MoEConfig(n_experts=256, top_k=8, d_expert=2048, n_shared=1,

@@ -7,18 +7,43 @@ Per-family GEMM decomposition notes (DESIGN.md §5):
   * attention-free recurrences (RWKV WKV, Mamba selective scan) are
     element-wise -> electronic unit; their projections are GEMMs;
   * sliding-window layers have window-bounded score GEMMs;
-  * MoE experts contribute expected top-k load (B*S*top_k/E rows each);
+  * MoE experts are priced as a batch touches them under uniform routing:
+    T routed token rows touch D = round(E*(1-(1-k/E)^T)) distinct experts
+    (clamped to [1, min(E, T*k)]), and the T*k slots fill those D in
+    integer rows (`expert_rows`); weights stream D experts a MoE layer;
   * MLA low-rank compress/expand are GEMMs;
   * decode workloads have M = batch (tiny-M GEMMs -> poor DDot-array
-    utilization; visible in the DSE results).
+    utilization; visible in the DSE results), and each step also reads the
+    KV cache off chip (`_kv_cache_bytes`).
 """
 from __future__ import annotations
 
 from typing import List
 
-from repro.configs.base import ModelConfig, ShapeConfig
+from repro.configs.base import ModelConfig, MoEConfig, ShapeConfig
+from repro.tracing import span
 
 from .workload import Gemm, Workload
+
+MOE_FAMILIES = ("moe", "mla_moe")
+
+
+def experts_touched(moe: MoEConfig, tokens: int) -> int:
+    """Distinct routed experts `tokens` routed rows touch under uniform
+    top-k routing: the expectation E*(1 - (1 - k/E)^T), rounded, clamped
+    to [1, min(E, T*k)]."""
+    e, k = moe.n_experts, moe.top_k
+    d = round(e * (1.0 - (1.0 - k / e) ** tokens))
+    return max(1, min(e, tokens * k, d))
+
+
+def expert_rows(moe: MoEConfig, tokens: int) -> List[tuple]:
+    """[(rows, experts)]: the T*k routing slots split over the D touched
+    experts in integer rows, r = (T*k) // D each and T*k - r*D of them
+    one row more."""
+    d = experts_touched(moe, tokens)
+    r, extra = divmod(tokens * moe.top_k, d)
+    return [(rows, n) for rows, n in ((r + 1, extra), (r, d - extra)) if n]
 
 
 def _attn_gemms(cfg, n_ctx, bt, batch, layers, gemms: List[Gemm],
@@ -50,12 +75,13 @@ def _mla_gemms(cfg, n_ctx, bt, batch, layers, gemms: List[Gemm],
         gemms.append(Gemm(bt, d, h * qd, layers))
     gemms.append(Gemm(bt, d, m.kv_lora_rank + m.rope_head_dim, layers))
     if decode:
-        # absorbed form: q->latent, scores/ctx against rank-R cache
+        # absorbed form: q->latent per head, then every head of a sequence
+        # scores and reads the one shared rank-R cache in a single GEMM
         gemms.append(Gemm(bt, m.nope_head_dim, m.kv_lora_rank, layers * h))
-        gemms.append(Gemm(q_tokens, m.kv_lora_rank + m.rope_head_dim, n_ctx,
-                          layers * batch * h))
-        gemms.append(Gemm(q_tokens, n_ctx, m.kv_lora_rank,
-                          layers * batch * h))
+        gemms.append(Gemm(q_tokens * h, m.kv_lora_rank + m.rope_head_dim,
+                          n_ctx, layers * batch))
+        gemms.append(Gemm(q_tokens * h, n_ctx, m.kv_lora_rank,
+                          layers * batch))
         gemms.append(Gemm(bt, m.kv_lora_rank, m.v_head_dim, layers * h))
     else:
         gemms.append(Gemm(bt, m.kv_lora_rank,
@@ -74,9 +100,11 @@ def _moe_gemms(cfg, bt, layers, gemms: List[Gemm]):
     mo = cfg.moe
     d = cfg.d_model
     gemms.append(Gemm(bt, d, mo.n_experts, layers))            # router
-    rows = max(1, bt * mo.top_k // mo.n_experts)               # per expert
-    gemms.append(Gemm(rows, d, mo.d_expert, 2 * layers * mo.n_experts))
-    gemms.append(Gemm(rows, mo.d_expert, d, layers * mo.n_experts))
+    split = expert_rows(mo, bt)                                # bt routed rows
+    for rows, n in split:
+        gemms.append(Gemm(rows, d, mo.d_expert, 2 * layers * n))
+    for rows, n in split:
+        gemms.append(Gemm(rows, mo.d_expert, d, layers * n))
     if mo.n_shared:
         ds = (mo.d_shared or mo.d_expert) * mo.n_shared
         gemms.append(Gemm(bt, d, ds, 2 * layers))
@@ -134,16 +162,67 @@ def _elec_ops(cfg, n_ctx, bt, batch, layers, decode=False):
         ops += bt * d_in * 2 * layers                       # conv + gates
     else:
         ops += batch * cfg.n_heads * q_tokens * n_ctx * 3 * layers  # softmax
-        ops += bt * cfg.d_ff * layers                       # activation
+        if cfg.family in MOE_FAMILIES and cfg.moe:
+            # activations at each layer's real width: the dense FFN of
+            # the leading layers, then the T*k routed expert rows and the
+            # shared experts' rows of every MoE layer
+            mo = cfg.moe
+            dense = min(mo.first_dense_layers, layers)
+            ops += bt * cfg.d_ff * dense
+            ops += (bt * mo.top_k * mo.d_expert
+                    + bt * (mo.d_shared or mo.d_expert) * mo.n_shared) \
+                * (layers - dense)
+        else:
+            ops += bt * cfg.d_ff * layers                   # activation
     return float(ops)
 
 
-def _weight_bytes(cfg, weight_bits=4):
-    return cfg.param_count() * weight_bits / 8.0
+def _attn_layers(cfg):
+    """(window or None, window-bounded layers, global layers): every
+    swa_pattern-th layer global, the rest local; swa_pattern 0 with a
+    window makes every layer local."""
+    window = cfg.sliding_window or None
+    n_global = (cfg.n_layers // cfg.swa_pattern
+                if (window and cfg.swa_pattern) else
+                (0 if window else cfg.n_layers))
+    return window, cfg.n_layers - n_global, n_global
 
 
-def _active_weight_bytes(cfg, weight_bits=4):
-    return cfg.active_param_count() * weight_bits / 8.0
+def _weight_bytes(cfg, tokens, weight_bits=4):
+    """Weights streamed once for `tokens` routed rows: every parameter,
+    except that a MoE layer streams only the experts the rows touch."""
+    params = cfg.param_count()
+    if cfg.family in MOE_FAMILIES and cfg.moe:
+        mo = cfg.moe
+        idle = mo.n_experts - experts_touched(mo, tokens)
+        params -= 3 * cfg.d_model * mo.d_expert * idle \
+            * (cfg.n_layers - mo.first_dense_layers)
+    return params * weight_bits / 8.0
+
+
+def _kv_cache_bytes(cfg, n_ctx, batch, act_bits=4):
+    """KV cache one decode step reads off chip, at `act_bits`: MLA's
+    latent (kv_lora + rope values a token a layer); GQA's K and V of every
+    attention layer, window-bounded layers capped at their window (and
+    enc-dec's cross-attention K and V over its source); none for RWKV."""
+    fam = cfg.family
+    if fam == "rwkv":
+        return 0.0
+    if fam == "mla_moe":
+        m = cfg.mla
+        values = n_ctx * cfg.n_layers * (m.kv_lora_rank + m.rope_head_dim)
+    else:
+        if fam == "hybrid_ssm":
+            positions = n_ctx * (cfg.n_layers // cfg.ssm.attn_every)
+        elif fam == "encdec":
+            positions = (n_ctx + n_ctx // 2) * cfg.dec_layers
+        else:
+            window, n_local, n_global = _attn_layers(cfg)
+            positions = (n_global * n_ctx
+                         + n_local * (min(n_ctx, window) if window
+                                      else n_ctx))
+        values = positions * 2 * cfg.n_kv_heads * cfg.resolved_head_dim
+    return batch * values * act_bits / 8.0
 
 
 def _build(cfg: ModelConfig, name, seq, batch, *, decode=False,
@@ -190,11 +269,7 @@ def _build(cfg: ModelConfig, name, seq, batch, *, decode=False,
         _ffn_gemms(cfg, bt, n_shared, gemms)
         layers_for_elec = cfg.n_layers
     else:
-        window = cfg.sliding_window or None
-        n_global = (cfg.n_layers // cfg.swa_pattern
-                    if (window and cfg.swa_pattern) else
-                    (0 if window else cfg.n_layers))
-        n_local = cfg.n_layers - n_global
+        window, n_local, n_global = _attn_layers(cfg)
         if fam == "mla_moe":
             _mla_gemms(cfg, n_ctx, bt, batch, cfg.n_layers, gemms,
                        decode=decode)
@@ -205,7 +280,7 @@ def _build(cfg: ModelConfig, name, seq, batch, *, decode=False,
             if n_global:
                 _attn_gemms(cfg, n_ctx, bt, batch, n_global, gemms,
                             decode=decode)
-        if fam in ("moe", "mla_moe"):
+        if fam in MOE_FAMILIES:
             mo = cfg.moe
             n_moe = cfg.n_layers - mo.first_dense_layers
             if mo.first_dense_layers:
@@ -218,9 +293,11 @@ def _build(cfg: ModelConfig, name, seq, batch, *, decode=False,
     gemms.append(Gemm(bt, cfg.d_model, cfg.vocab, 1))   # LM head
 
     elec = _elec_ops(cfg, n_ctx, bt, batch, layers_for_elec, decode)
-    wb = _active_weight_bytes(cfg) if decode else _weight_bytes(cfg)
+    wb = _weight_bytes(cfg, bt)
     max_act = bt * max(cfg.d_ff, 3 * cfg.d_model) * act_bits / 8.0
     act_io = bt * cfg.d_model * 2 * act_bits / 8.0
+    if decode:
+        act_io += _kv_cache_bytes(cfg, n_ctx, batch, act_bits)
     return Workload(name=name, gemms=tuple(gemms), elec_ops=elec,
                     weight_bytes=float(wb), act_io_bytes=float(act_io),
                     max_act_bytes=float(max_act), batch=batch)
@@ -269,14 +346,15 @@ def workload_for(cfg: ModelConfig, shape: ShapeConfig) -> Workload:
     decode length ("decode" kind only). Historically the decode length was
     hard-coded to 32 here, which silently gave every decode shape —
     `decode_32k` and `long_500k` alike — the same generation length; now
-    it threads through from the shape.
+    it threads through from the shape. Runs inside the span `extract`.
     """
-    if shape.kind == "train":
-        return training_workload(cfg, shape.seq_len, shape.global_batch)
-    if shape.kind == "prefill":
-        return prefill_workload(cfg, shape.seq_len, shape.global_batch)
-    if shape.kind != "decode":
-        raise ValueError(f"unknown shape kind {shape.kind!r}; pick "
-                         f"'train', 'prefill' or 'decode'")
-    return serving_workload(cfg, shape.seq_len, shape.global_batch,
-                            new_tokens=shape.new_tokens)
+    with span("extract"):
+        if shape.kind == "train":
+            return training_workload(cfg, shape.seq_len, shape.global_batch)
+        if shape.kind == "prefill":
+            return prefill_workload(cfg, shape.seq_len, shape.global_batch)
+        if shape.kind != "decode":
+            raise ValueError(f"unknown shape kind {shape.kind!r}; pick "
+                             f"'train', 'prefill' or 'decode'")
+        return serving_workload(cfg, shape.seq_len, shape.global_batch,
+                                new_tokens=shape.new_tokens)

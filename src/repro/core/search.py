@@ -97,7 +97,7 @@ from .runtime import (SearchRuntime, activate as _activate_rt,
                       fingerprint as _fingerprint)
 from .significance import SignificanceScore, observe_significance, significant_params
 from .workload import Workload
-from ..tracing import span, traced
+from ..tracing import gemm_lane_tally, span, traced
 
 # Metric arrays reported per frontier point (every evaluate_grid key).
 REPORT_METRICS = ("area", "power", "energy", "latency", "util", "edp")
@@ -166,6 +166,12 @@ class SearchResult:
     sched: Optional[object] = dataclasses.field(default=None, repr=False,
                                                 compare=False)
 
+    # Lanes x GEMM rows the device launches of this search ran, padding
+    # and masked lanes included (`repro.tracing.gemm_lane_tally`); 0 on
+    # host engines. Excluded from equality like the ledger: lane padding is
+    # how an engine ran, not the answer.
+    n_gemm_lanes: int = dataclasses.field(default=0, compare=False)
+
     @property
     def feasible(self) -> bool:
         """True when the search found any constraint-satisfying config."""
@@ -222,6 +228,9 @@ class ParetoResult:
     # Parallel slab scheduler telemetry, as on SearchResult (workers=N).
     sched: Optional[object] = dataclasses.field(default=None, repr=False,
                                                 compare=False)
+
+    # Lanes x GEMM rows launched, as on SearchResult.
+    n_gemm_lanes: int = dataclasses.field(default=0, compare=False)
 
     @property
     def size(self) -> int:
@@ -2960,7 +2969,22 @@ def _robust_vertex_search(wl, constraints, cal, engine, grid, n_z,
     return res
 
 
+def _counts_gemm_lanes(fn):
+    """Set `n_gemm_lanes` on what a search returns (a result, or a dict of
+    them, each reporting the whole batch's count as it does its wall
+    time): the lanes x GEMM rows launched while it ran."""
+    @functools.wraps(fn)
+    def inner(*args, **kwargs):
+        with gemm_lane_tally() as tally:
+            out = fn(*args, **kwargs)
+        for r in (out.values() if isinstance(out, dict) else (out,)):
+            r.n_gemm_lanes = tally.n
+        return out
+    return inner
+
+
 @traced("search")
+@_counts_gemm_lanes
 def search(wl: Workload, constraints: Constraints = Constraints(), *,
            engine: str = "numpy", grid: Optional[np.ndarray] = None,
            n_z: int = 12, hierarchical: bool = False,
@@ -3301,6 +3325,7 @@ def _workloads_pallas_streamed(wls, names, cons_for, grid, hierarchical, c,
 
 
 @traced("search")
+@_counts_gemm_lanes
 def search_workloads(wls: Union[Mapping[str, Workload], Sequence[Workload]],
                      constraints: Union[Constraints,
                                         Mapping[str, Constraints]]

@@ -24,7 +24,7 @@ from repro.core.arch_params import PTAConfig
 from repro.core.performance_model import workload_statics
 from repro.core.photonic_model import CONSTANTS, DeviceConstants
 from repro.core.workload import Workload
-from repro.tracing import span
+from repro.tracing import count_gemm_lanes, span
 
 from . import ddot_gemm as _ddot
 from . import dse_eval as _dse
@@ -53,6 +53,14 @@ def _wait(out) -> np.ndarray:
     one blocking step of a launch."""
     with span("launch.wait"):
         return np.asarray(out)
+
+
+def _launched(sp, lanes: int, workloads=()) -> None:
+    """One launch of `lanes` lanes: its `launch` span's stat, and its lanes
+    x GEMM rows (summed over its workloads) to every open
+    `gemm_lane_tally`."""
+    sp.set_metadata(lanes=lanes)
+    count_gemm_lanes(lanes * sum(len(g) for g, _ in workloads))
 
 
 def _pad_to(x, m0, m1):
@@ -132,10 +140,11 @@ def dse_eval_grid(grid: np.ndarray, wl: Workload,
     prune compares the two bit for bit)."""
     g = np.asarray(grid)
     g_pad = -(-len(g) // _dse.BLOCK) * _dse.BLOCK
-    with span("launch", lanes=g_pad):
+    with span("launch") as sp:
         cols = np.ones((5, g_pad), np.float32)
         cols[:, :len(g)] = g.T
         gemms, wl_scalars = workload_statics(wl, c)
+        _launched(sp, g_pad, ((gemms, wl_scalars),))
         out = _dse.dse_eval_padded(jnp.asarray(cols), gemms=gemms,
                                    wl_scalars=wl_scalars, constants=c,
                                    interpret=interpret)
@@ -320,7 +329,7 @@ def dse_search_multi(grid: np.ndarray, wls, constraints_seq,
                 cols, mask, cons, carry, workloads=workloads, constants=c,
                 interpret=interpret))
             col_base = np.zeros(out.shape[1], np.int64)
-        sp.set_metadata(lanes=out.shape[1] * _dse.BLOCK)
+        _launched(sp, out.shape[1] * _dse.BLOCK, workloads)
         _integrity_check(out, "dse_search")
         best_idx, best_edp, n_feasible = [], [], []
         for w in range(len(workloads)):
@@ -400,7 +409,7 @@ def dse_pareto_multi(grid: np.ndarray, wls, constraints_seq,
             n_cols = out.shape[1]
             col_base = np.zeros(n_cols, np.int64)
             blk_lo = np.arange(n_cols, dtype=np.int64) * _dse.BLOCK
-        sp.set_metadata(lanes=n_cols * _dse.BLOCK)
+        _launched(sp, n_cols * _dse.BLOCK, workloads)
         _integrity_check(out, "dse_pareto")
         results = []
         for w in range(len(workloads)):
@@ -600,7 +609,7 @@ def dse_search_multi_factorized(space, start: int, count: int, wls,
         out, blk_lo = _decoded_launch(space, start, count, "search",
                                       (workloads, c, interpret), cons, carry,
                                       shard, slab)
-        sp.set_metadata(lanes=len(blk_lo) * _dse.DECODE_BLOCK)
+        _launched(sp, len(blk_lo) * _dse.DECODE_BLOCK, workloads)
         _integrity_check(out, "dse_search_decoded")
         best_idx, best_edp, n_feasible = [], [], []
         for w in range(len(workloads)):
@@ -644,7 +653,7 @@ def dse_pareto_multi_factorized(space, start: int, count: int, wls,
             space, start, count, "pareto",
             (workloads, objectives, has_carry, c, interpret), cons, carry,
             shard, slab)
-        sp.set_metadata(lanes=len(blk_lo) * _dse.BLOCK)
+        _launched(sp, len(blk_lo) * _dse.BLOCK, workloads)
         limit = min(start + count, space.size)
         _integrity_check(out, "dse_pareto_decoded")
         results = []
@@ -752,7 +761,8 @@ def decode_rows_device(space, start: int, count: int,
     n_blocks = max(1, -(-count // _dse.BLOCK))
     limit = min(start + count, space.size)
     _check_decode_span(limit)
-    with span("launch", lanes=n_blocks * _dse.BLOCK):
+    with span("launch") as sp:
+        _launched(sp, n_blocks * _dse.BLOCK)
         axes_cols, radices = _axes_operand(space)
         meta = jnp.asarray(_meta_rows(radices, [start], limit, slab))
         out = _wait(_dse.dse_decode_rows(axes_cols, meta, radices=radices,

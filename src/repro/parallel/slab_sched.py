@@ -74,6 +74,7 @@ and the counters} after every merge, through the same step-atomic layer.
 from __future__ import annotations
 
 import collections
+import contextvars
 import dataclasses
 import functools
 import threading
@@ -242,7 +243,10 @@ class SlabScheduler:
     def _spawn(self, replacement=False):
         wid = self._next_wid
         self._next_wid += 1
-        t = threading.Thread(target=self._worker_loop, args=(wid,),
+        # A copy of the spawning context, so the workers' launches count
+        # in the search's `gemm_lane_tally`.
+        t = threading.Thread(target=contextvars.copy_context().run,
+                             args=(self._worker_loop, wid),
                              name=f"slab-worker-{wid}", daemon=True)
         self._threads[wid] = t
         if replacement:

@@ -55,7 +55,7 @@ from repro.core.search import (DEFAULT_OBJECTIVES, ParetoResult,
                                _resolve_robust, _search_factorized_bnb,
                                search, search_workloads)
 from repro.core.workload import Workload
-from repro.tracing import span, traced
+from repro.tracing import gemm_lane_tally, span, traced
 
 from .batching import QueryBatcher, ServeQuery
 from .cache import (Box, base_key, box_constraints, box_contains,
@@ -468,7 +468,7 @@ class SearchService:
             sp.set_metadata(slabs_tested=tested)
         cons = q.constraints
         metrics = self._metrics(q)
-        with span("search"), \
+        with gemm_lane_tally() as tally, span("search"), \
                 self._maybe_executor(q.wl, cons, q.objective, metrics) as ex:
             if q.objective == "edp":
                 res = _search_factorized_bnb(
@@ -480,6 +480,7 @@ class SearchService:
                     self.space, q.wl, cons, self.engine, self.c,
                     self.interpret, metrics, self.shard, self.chunk_size,
                     warm=warm, executor=ex)
+        res.n_gemm_lanes = tally.n
         if self.calibration is not None:
             res.band = _measure_band(res, self.calibration, q.wl)
         self.stats["slabs_repriced"] += len(base.ledger.pruned)

@@ -22,7 +22,8 @@ import pytest
 
 from repro.configs.base import (MLAConfig, ModelConfig, MoEConfig,
                                 ShapeConfig, SSMConfig)
-from repro.core.extract import _elec_ops, workload_for
+from repro.core.extract import (_elec_ops, expert_rows, experts_touched,
+                                workload_for)
 
 S, B = 4, 2           # prefill/train tokens x batch
 CTX, NT = 8, 3        # decode context x generated tokens
@@ -118,6 +119,21 @@ def test_swa_family_golden():
 # moe
 # ---------------------------------------------------------------------------
 
+def _expert_macs(split, d, d_expert, layers):
+    """Routed experts' up+gate and down GEMMs for [(rows, experts)]."""
+    return sum(rows * d * d_expert * 2 * layers * n
+               + rows * d_expert * d * layers * n for rows, n in split)
+
+
+# Routing of the 4-expert top-2 goldens (E=4, k=2), worked by hand:
+#   prefill/train, T = B*S = 8 rows: 4*(1 - (1/2)^8) = 3.98 -> D = 4,
+#     16 slots over 4 experts -> 4 rows each;
+#   decode, T = B = 2 rows: 4*(1 - (1/2)^2) = 3 -> D = 3, 4 slots over 3
+#     experts -> r = 1, one expert with 2 rows and two with 1.
+PREFILL_SPLIT = [(4, 4)]
+DECODE_SPLIT = [(2, 1), (1, 2)]
+
+
 def test_moe_family_golden():
     cfg = ModelConfig(
         name="g-moe", family="moe", n_layers=3, d_model=8, n_heads=2,
@@ -125,44 +141,53 @@ def test_moe_family_golden():
         moe=MoEConfig(n_experts=4, top_k=2, d_expert=8, n_shared=1,
                       d_shared=8, first_dense_layers=1))
 
-    def moe_macs(bt):
+    def moe_macs(bt, split):
         n_moe = 2                                  # 3 layers - 1 dense
-        rows = max(1, bt * 2 // 4)                 # expected top-k load
         return (_ffn_macs(bt, 8, 16, 1)            # leading dense FFN
                 + bt * 8 * 4 * n_moe               # router
-                + rows * 8 * 8 * 2 * n_moe * 4     # expert up+gate
-                + rows * 8 * 8 * n_moe * 4         # expert down
+                + _expert_macs(split, 8, 8, n_moe)  # touched experts
                 + bt * 8 * 8 * 2 * n_moe           # shared up+gate
                 + bt * 8 * 8 * n_moe)              # shared down
 
+    def elec(bt, q_tokens, ctx):
+        # activations: dense d_ff on layer 1, then T*k*d_expert routed
+        # plus T*d_shared shared on each of the 2 MoE layers
+        return (bt * 8 * 10 * 3 + B * 2 * q_tokens * ctx * 3 * 3
+                + bt * 16 * 1 + (bt * 2 * 8 + bt * 8 * 1) * 2)
+
     bt = B * S
-    pre_macs = (_attn_macs(bt, S, S, 8, 2, 2, 4, 3, B) + moe_macs(bt)
-                + bt * 8 * VOCAB)
-    pre_elec = _elec(bt, 8, 16, 2, S, S, B, 3)
-    dec_macs = (_attn_macs(B, 1, CTX, 8, 2, 2, 4, 3, B) + moe_macs(B)
-                + B * 8 * VOCAB)
-    dec_elec = _elec(B, 8, 16, 2, 1, CTX, B, 3)
-    _check(cfg, pre_macs, pre_elec, dec_macs, dec_elec)
+    pre_macs = (_attn_macs(bt, S, S, 8, 2, 2, 4, 3, B)
+                + moe_macs(bt, PREFILL_SPLIT) + bt * 8 * VOCAB)
+    dec_macs = (_attn_macs(B, 1, CTX, 8, 2, 2, 4, 3, B)
+                + moe_macs(B, DECODE_SPLIT) + B * 8 * VOCAB)
+    assert elec(bt, S, S) == 1920 + 576 + 128 + 384 == 3008
+    _check(cfg, pre_macs, elec(bt, S, S), dec_macs, elec(B, 1, CTX))
 
 
 # ---------------------------------------------------------------------------
 # mla_moe
 # ---------------------------------------------------------------------------
 
+MLA_CFG = ModelConfig(
+    name="g-mla", family="mla_moe", n_layers=3, d_model=8, n_heads=2,
+    d_ff=16, vocab=VOCAB,
+    mla=MLAConfig(q_lora_rank=6, kv_lora_rank=5, rope_head_dim=2,
+                  nope_head_dim=4, v_head_dim=4),
+    moe=MoEConfig(n_experts=4, top_k=2, d_expert=8, first_dense_layers=1))
+
+
 def test_mla_moe_family_golden():
-    mla = MLAConfig(q_lora_rank=6, kv_lora_rank=5, rope_head_dim=2,
-                    nope_head_dim=4, v_head_dim=4)
-    cfg = ModelConfig(
-        name="g-mla", family="mla_moe", n_layers=3, d_model=8, n_heads=2,
-        d_ff=16, vocab=VOCAB, mla=mla,
-        moe=MoEConfig(n_experts=4, top_k=2, d_expert=8,
-                      first_dense_layers=1))
+    cfg = MLA_CFG
     L, H, qd = 3, 2, 4 + 2                         # qd = nope + rope
 
-    def moe_macs(bt):
-        n_moe, rows = 2, max(1, bt * 2 // 4)
+    def moe_macs(bt, split):
+        n_moe = 2
         return (_ffn_macs(bt, 8, 16, 1) + bt * 8 * 4 * n_moe
-                + rows * 8 * 8 * 2 * n_moe * 4 + rows * 8 * 8 * n_moe * 4)
+                + _expert_macs(split, 8, 8, n_moe))
+
+    def elec(bt, q_tokens, ctx):
+        return (bt * 8 * 10 * L + B * H * q_tokens * ctx * 3 * L
+                + bt * 16 * 1 + bt * 2 * 8 * 2)   # dense, then T*k*d_expert
 
     bt = B * S
     pre_macs = (bt * 8 * 6 * L + bt * 6 * (H * qd) * L     # Q down/up
@@ -171,8 +196,7 @@ def test_mla_moe_family_golden():
                 + S * qd * S * L * B * H                   # scores
                 + S * S * 4 * L * B * H                    # AV
                 + bt * (H * 4) * 8 * L                     # out proj
-                + moe_macs(bt) + bt * 8 * VOCAB)
-    pre_elec = _elec(bt, 8, 16, H, S, S, B, L)
+                + moe_macs(bt, PREFILL_SPLIT) + bt * 8 * VOCAB)
     dec_macs = (B * 8 * 6 * L + B * 6 * (H * qd) * L
                 + B * 8 * 7 * L                            # KV-latent down
                 + B * 4 * 5 * L * H                        # q absorb
@@ -180,9 +204,92 @@ def test_mla_moe_family_golden():
                 + 1 * CTX * 5 * L * B * H                  # latent AV
                 + B * 5 * 4 * L * H                        # V up
                 + B * (H * 4) * 8 * L
-                + moe_macs(B) + B * 8 * VOCAB)
-    dec_elec = _elec(B, 8, 16, H, 1, CTX, B, L)
-    _check(cfg, pre_macs, pre_elec, dec_macs, dec_elec)
+                + moe_macs(B, DECODE_SPLIT) + B * 8 * VOCAB)
+    _check(cfg, pre_macs, elec(bt, S, S), dec_macs, elec(B, 1, CTX))
+
+
+def test_mla_decode_scores_every_head_against_the_shared_latent():
+    # Absorbed MLA (arXiv:2405.04434 §2.1): a sequence's H heads attend
+    # one cache of C latents, kv_lora + rope = 7 wide, so each sequence a
+    # layer has one (H, 7, CTX) score GEMM and one (H, CTX, kv_lora)
+    # context GEMM, not H of M = 1. Batch 3, so M = H = 2 is no batch:
+    # counts are L * 3 sequence-layers a step, NT steps.
+    wl = _wl(MLA_CFG, "decode", batch=3)
+    shapes = {tuple(g[:3]): g[3] for g in wl.gemm_array.tolist()}
+    assert shapes[(2, 7, CTX)] == 3 * 3 * NT
+    assert shapes[(2, CTX, 5)] == 3 * 3 * NT
+    assert (1, 7, CTX) not in shapes and (1, CTX, 5) not in shapes
+
+
+# ---------------------------------------------------------------------------
+# MoE routing, decode off-chip bytes, and DeepSeek-V3 at published sizes
+# ---------------------------------------------------------------------------
+
+DSV3_MOE = MoEConfig(n_experts=256, top_k=8, d_expert=2048)
+
+
+@pytest.mark.parametrize("tokens,touched,split", [
+    # T*k = 64 < E: 256*(1 - (31/32)^8) = 256*(1 - 0.77611) = 57.3 -> 57;
+    # 64 slots over 57 experts: 7 with 2 rows, 50 with 1.
+    (8, 57, [(2, 7), (1, 50)]),
+    # T*k = 256 = E: 256*(1 - (31/32)^32) = 256*(1 - 0.36205) = 163.3
+    # -> 163; 256 slots: 93 experts with 2 rows, 70 with 1.
+    (32, 163, [(2, 93), (1, 70)]),
+    # T*k = 1024 > E: 256*(1 - (31/32)^128) = 256*(1 - 0.01718) = 251.6
+    # -> 252; 1024 slots: r = 4, 16 experts with 5 rows, 236 with 4.
+    (128, 252, [(5, 16), (4, 236)]),
+    # one row touches exactly its k experts
+    (1, 8, [(1, 8)]),
+])
+def test_routing_prices_the_experts_a_batch_touches(tokens, touched, split):
+    assert experts_touched(DSV3_MOE, tokens) == touched
+    assert expert_rows(DSV3_MOE, tokens) == split
+    assert sum(r * n for r, n in split) == tokens * 8
+
+
+def test_decode_reads_the_kv_cache_off_chip():
+    # act_io per step = activations in and out (B*d*2 at 4 bits = 16 B)
+    # plus the cache the step reads, at 4 bits; decode scales by NT.
+    # GQA: B * CTX * layers * 2 * kv_heads * head_dim = 2*8*2*2*1*4.
+    assert _wl(DENSE, "decode").act_io_bytes == NT * (16 + 256 * 0.5) == 432
+    # Sliding window 2 on one of the two layers: 8 + 2 positions a token.
+    swa = dataclasses.replace(DENSE, sliding_window=2, swa_pattern=2)
+    assert _wl(swa, "decode").act_io_bytes == NT * (16 + 2 * 10 * 8 * 0.5)
+    # MLA: the latent, kv_lora + rope = 7 values a token a layer.
+    assert _wl(MLA_CFG, "decode").act_io_bytes == \
+        NT * (16 + 2 * 8 * 3 * 7 * 0.5) == 552
+    # prefill reads no cache
+    assert _wl(DENSE, "prefill").act_io_bytes == B * S * 8 * 2 * 0.5
+
+
+def test_decode_streams_the_touched_experts_weights():
+    cfg = ModelConfig(
+        name="g-moe", family="moe", n_layers=3, d_model=8, n_heads=2,
+        n_kv_heads=2, d_ff=16, vocab=VOCAB,
+        moe=MoEConfig(n_experts=4, top_k=2, d_expert=8, n_shared=1,
+                      d_shared=8, first_dense_layers=1))
+    # params: embeddings 10*8*2 = 160; attention 8*(2+4)*4 + 8*8 = 256 a
+    # layer; dense FFN 3*8*16 = 384; MoE FFN 4 experts * 3*8*8 + shared
+    # 3*8*8 + router 8*4 = 992 a layer: 160 + (256+384) + 2*(256+992).
+    assert cfg.param_count() == 3296
+    # decode at B = 2 touches D = 3 of the 4 experts: one expert's 192
+    # params idle in each of the 2 MoE layers; 4-bit weights, NT steps.
+    assert _wl(cfg, "decode").weight_bytes == NT * (3296 - 2 * 192) * 0.5
+    # prefill's 8 rows touch all 4
+    assert _wl(cfg, "prefill").weight_bytes == 3296 * 0.5
+
+
+def test_deepseek_v3_at_published_sizes():
+    from repro.configs import get_config
+    from repro.core.performance_model import require_i32_dims
+
+    cfg = get_config("deepseek-v3-671b")
+    assert abs(cfg.param_count() / 671e9 - 1) < 0.005
+    assert abs(cfg.active_param_count() / 37e9 - 1) < 0.02
+    wl = workload_for(cfg, ShapeConfig("decode32k", 32768, 32, "decode",
+                                       new_tokens=32))
+    assert len(wl.gemms) == 18
+    require_i32_dims(wl.gemm_array)
 
 
 # ---------------------------------------------------------------------------

@@ -148,3 +148,24 @@ def test_dse_decode_rows_compiles(compile_v5e):
     fn = functools.partial(K.dse_decode_rows, radices=R20, n_blocks=4,
                            interpret=False)
     assert _custom_call(compile_v5e(fn, AXES_20, META), "dse_decode_rows")
+
+
+def test_dse_pareto_decoded_compiles_deepseek_v3_decode(compile_v5e):
+    # The DeepSeek-V3 decode cell's launch: 18 GEMM rows over all of 24^5.
+    from repro.configs import get_config
+    from repro.configs.base import ShapeConfig
+    from repro.core.extract import workload_for
+    from repro.kernels.ops import _bucket_blocks
+
+    wl = workload_for(get_config("deepseek-v3-671b"),
+                      ShapeConfig("d", 32768, 32, "decode", new_tokens=32))
+    assert len(wl.gemms) == 18
+    fn = functools.partial(
+        K.dse_pareto_decoded, radices=(24,) * 5,
+        n_blocks=_bucket_blocks(24 ** 5, floor=8, block=K.BLOCK),
+        workloads=(workload_statics(wl, CONSTANTS),), objectives=OBJECTIVES,
+        has_carry=False, constants=CONSTANTS, interpret=False)
+    assert _custom_call(
+        compile_v5e(fn, _f32(5, 24), META, _f32(1, 4),
+                    _f32(K.CARRY_FRONT, len(OBJECTIVES))),
+        "dse_pareto_decoded")
