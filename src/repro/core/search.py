@@ -2180,8 +2180,8 @@ def _bnb_batch_slices(sizes: np.ndarray, max_points: Optional[int] = None):
 
 def _bnb_leaf_items(fspace, ranges, chunk_size):
     """A leaf slab as decoded-launch work items [(start, count, slab), ...]
-    for the pallas span-list driver: the slab's bounding index range,
-    chunked to at most `chunk_size` lanes per launch (the kernel masks
+    for the pallas span-list drivers: the slab's bounding index range,
+    chunked to at most `chunk_size` lanes per item (the kernel masks
     non-member lanes, so chunk splits never change membership)."""
     from .factorized import slab_bounding_span
     b0, b1 = slab_bounding_span(fspace.radices, ranges)
@@ -2197,26 +2197,24 @@ def _bnb_eval_edp(engine, fspace, wl, constraints, c, interpret,
     numpy/jax evaluate the batch's ascending concatenated index vector
     (chunked by `chunk_size`, fanned out by `shard`). pallas picks its
     launch form per batch: coarse slabs (the probe phase) go through the
-    span-list driver — one decoded launch per leaf over its bounding
-    span, the slab meta masking non-members — while batches of fine
-    refined slabs (whose members are scattered single indices, hopeless
-    as spans) materialize just the survivor rows and reuse the
-    grid-operand kernel, one bucketed launch per chunk. Either way only
-    survivor-sized data ever exists on the host."""
+    span-list driver — one decoded launch for the whole batch, a meta row
+    per block of each leaf's bounding span, the slab ranges masking
+    non-members — while batches of fine refined slabs (whose members are
+    scattered single indices, hopeless as spans) materialize just the
+    survivor rows and reuse the grid-operand kernel, one bucketed launch
+    per chunk. Either way only survivor-sized data ever exists on the
+    host."""
     from .factorized import slab_indices_batch, slab_size
     best = (-1, float("inf"))
     nf = 0
     if engine == "pallas" and any(slab_size(r) > BNB_FINE
                                   for r in ranges_list):
         from repro.kernels.ops import dse_search_spans_factorized
-        for ranges in ranges_list:
-            items = _bnb_leaf_items(fspace, ranges, chunk_size)
-            bi, be, bn = dse_search_spans_factorized(
-                fspace, items, [wl], [constraints], c, interpret,
-                shard=shard)
-            nf += int(bn[0])
-            best = _merge_best_indexed(best, (int(bi[0]), float(be[0])))
-        return best[0], best[1], nf
+        items = [it for ranges in ranges_list
+                 for it in _bnb_leaf_items(fspace, ranges, chunk_size)]
+        (bi,), (be,), (bn,) = dse_search_spans_factorized(
+            fspace, items, [wl], [constraints], c, interpret, shard=shard)
+        return bi, be, bn
     idx = slab_indices_batch(fspace.radices, ranges_list)
     cs = int(chunk_size) if chunk_size else len(idx)
     for s in range(0, len(idx), cs):

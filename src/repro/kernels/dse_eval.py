@@ -39,7 +39,7 @@ caller-side padding.
 Both search-mode kernels also come in a *decoded* (factorized-space)
 variant (`dse_search_decoded` / `dse_pareto_decoded`): when the grid is a
 Cartesian product of per-axis candidate sets, the kernel takes only the
-(5, max_radix) candidate-value matrix plus a [start, end) index span, and
+(5, max_radix) candidate-value matrix plus [start, end) index spans, and
 every lane reconstructs its own config row on device via iota -> mixed-radix
 decode (`_decode_block`) — the (5, G) grid is never materialized on the
 host, and the only per-launch traffic is the per-block reduction output.
@@ -57,6 +57,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.photonic_model import DeviceConstants
 
@@ -232,15 +233,14 @@ def _dse_kernel(gemms, wl_scalars, c: DeviceConstants, cfg_ref, out_ref):
     out_ref[3:4, :] = latency
 
 
-def _decode_block(radices, axes_ref, meta_ref, shape=(1, BLOCK)):
+def _decode_block(radices, axes_ref, base, meta, shape=(1, BLOCK)):
     """On-device candidate generation: one block's configs from its index.
 
     The factorized kernels never see a (5, G) config operand — each lane
-    reconstructs its own candidate row from the launch's base offset plus
+    reconstructs its own candidate row from the block's base index plus
     the per-axis candidate vectors:
 
-      global index = meta[0, 0] (chunk base) + program_id * block
-                     + row-major position in the `shape` tile,
+      global index = base + row-major position in the `shape` tile,
 
     mixed-radix decoded with the static `radices` (meshgrid axis order
     t, c, v, h, lambda — N_lambda fastest) via the same
@@ -249,17 +249,17 @@ def _decode_block(radices, axes_ref, meta_ref, shape=(1, BLOCK)):
     clamped select chain per axis over the axes_ref row (`radix - 1`
     vector selects: the TPU lowers no 1-D gather).
 
-    Validity is a *slab* test, not just a span test: meta rows are
-    [start, end, lo_t, hi_t, lo_c, hi_c, lo_v, hi_v, lo_h, hi_h,
-    lo_l, hi_l] (META_COLS int32 entries) and a lane is valid when its
-    global index sits inside [start, end) *and* every decoded digit sits
-    inside its axis's [lo, hi) range. A contiguous span is the special
-    case of full ranges; the bound-guided (branch-and-bound) search uses
-    the general form to launch one kernel over a pruned slab's bounding
-    index range with the non-member lanes masked out. Invalid lanes (the
-    padded tail of the last block, indices past the space, slab
-    non-members) gather a clamped — still valid, never div-by-zero —
-    candidate value and are masked out of every reduction.
+    Validity is a *slab* test, not just a span test: `meta(k)` reads
+    column k of the block's meta row [base or start, end, lo_t, hi_t,
+    lo_c, hi_c, lo_v, hi_v, lo_h, hi_h, lo_l, hi_l] (META_COLS int32
+    entries), and a lane is valid when its global index sits below `end`
+    *and* every decoded digit sits inside its axis's [lo, hi) range. A
+    contiguous span is the special case of full ranges; the bound-guided
+    (branch-and-bound) search uses the general form to launch over a
+    pruned slab's bounding index range with the non-member lanes masked
+    out. Invalid lanes (the padded tail of the last block, indices past
+    the space, slab non-members) gather a clamped — still valid, never
+    div-by-zero — candidate value and are masked out of every reduction.
 
     Returns ((n_t, n_c, n_h, n_v, n_lambda) float32 `shape` tiles, float32
     global indices, validity mask). Emitted indices are exact for spaces
@@ -267,15 +267,13 @@ def _decode_block(radices, axes_ref, meta_ref, shape=(1, BLOCK)):
     """
     from repro.core.factorized import decode_digits
 
-    block = shape[0] * shape[1]
-    gidx = (meta_ref[0, 0] + pl.program_id(0) * block + _lane_iota(shape))
+    gidx = base + _lane_iota(shape)
     digits = decode_digits(gidx, radices, jnp)
     d_t, d_c, d_v, d_h, d_l = digits
 
-    valid = gidx < meta_ref[0, 1]
+    valid = gidx < meta(1)
     for ax, d in enumerate(digits):
-        valid &= (d >= meta_ref[0, 2 + 2 * ax]) \
-            & (d < meta_ref[0, 3 + 2 * ax])
+        valid &= (d >= meta(2 + 2 * ax)) & (d < meta(3 + 2 * ax))
 
     def pick(row, digit):
         # axes[row, clip(digit, 0, radix - 1)] as an ascending select chain.
@@ -287,6 +285,17 @@ def _decode_block(radices, axes_ref, meta_ref, shape=(1, BLOCK)):
     cols = (pick(0, d_t), pick(1, d_c), pick(3, d_h),
             pick(2, d_v), pick(4, d_l))
     return cols, gidx.astype(jnp.float32), valid
+
+
+def _span_block(radices, axes_ref, meta_ref, shape=(1, BLOCK)):
+    """`_decode_block` for the span kernels: one (1, META_COLS) meta row
+    per launch, block `program_id` starting at its base + program_id x
+    the block size."""
+    def meta(k):
+        return meta_ref[0, k]
+
+    base = meta(0) + pl.program_id(0) * (shape[0] * shape[1])
+    return _decode_block(radices, axes_ref, base, meta, shape)
 
 
 def _search_reduce(workloads, c: DeviceConstants, cols, valid, idx,
@@ -372,14 +381,24 @@ def _dse_search_kernel(workloads, c: DeviceConstants,
 
 
 def _dse_search_decode_kernel(workloads, radices, c: DeviceConstants,
-                              axes_ref, meta_ref, cons_ref, carry_ref,
+                              axes_ref, table_ref, cons_ref, carry_ref,
                               out_ref):
     """Factorized-space variant of `_dse_search_kernel`: configs decoded on
     device (see `_decode_block`, DECODE_BLOCK lanes per step) instead of
     streamed in, and the emitted index is the *global* flat-space index
-    (the decode already knows it), so the host wrapper needs no per-shard
-    base bookkeeping."""
-    cols, idx, valid = _decode_block(radices, axes_ref, meta_ref,
+    (the decode already knows it), so the host wrapper needs no base
+    bookkeeping.
+
+    Grid step i reads row i of the flattened meta table in SMEM: its own
+    block base, span end and slab digit ranges, so one launch covers any
+    list of blocks — the leaves of a whole branch-and-bound batch, or the
+    blocks of one span."""
+    row = pl.program_id(0) * META_COLS
+
+    def meta(k):
+        return table_ref[row + k]
+
+    cols, idx, valid = _decode_block(radices, axes_ref, meta(0), meta,
                                      (DECODE_BLOCK // LANES, LANES))
     _search_reduce(workloads, c, cols, valid, idx, cons_ref, carry_ref,
                    out_ref)
@@ -520,7 +539,7 @@ def _dse_pareto_decode_kernel(workloads, objectives, has_carry: bool,
                               out_ref):
     """Factorized-space variant of `_dse_pareto_kernel`: configs decoded on
     device from the chunk base + per-axis candidate vectors."""
-    cols, _, valid = _decode_block(radices, axes_ref, meta_ref)
+    cols, _, valid = _span_block(radices, axes_ref, meta_ref)
     _pareto_reduce(workloads, objectives, has_carry, c, cols, valid,
                    cons_ref, carry_ref, out_ref)
 
@@ -529,7 +548,7 @@ def _decode_rows_kernel(radices, axes_ref, meta_ref, out_ref):
     """Decode-proof kernel: emits the decoded (5, BLOCK) config columns plus
     a validity row, so tests can pin the on-device mixed-radix decode
     against `config_grid` rows directly."""
-    cols, _, valid = _decode_block(radices, axes_ref, meta_ref)
+    cols, _, valid = _span_block(radices, axes_ref, meta_ref)
     for r, col in enumerate(cols):
         out_ref[r:r + 1, :] = col
     out_ref[5:6, :] = valid.astype(jnp.float32)
@@ -688,14 +707,17 @@ def dse_pareto_padded(cfg_cols, mask, cons, carry, *, workloads: tuple,
 # ---------------------------------------------------------------------------
 #
 # The decode wrappers take the tiny (5, max_radix) candidate-value matrix
-# plus a (1, META_COLS) int32 meta row — the [chunk base, chunk end) index
-# span and the slab digit ranges (full ranges = a plain span) — instead of
-# config columns: the kernels reconstruct every candidate row on device
-# (`_decode_block`), so nothing grid-sized ever crosses the host/device
-# boundary in either direction except the per-block reduction rows.
-# `n_blocks` is static (the launch geometry); callers bucket it to a power
-# of two exactly like `_bucketed_cols` buckets grid shapes, so streamed
-# sweeps of varying chunk sizes reuse O(log G) jit entries.
+# plus int32 meta rows — an index span and the slab digit ranges (full
+# ranges = a plain span) — instead of config columns: the kernels
+# reconstruct every candidate row on device (`_decode_block`), so nothing
+# grid-sized ever crosses the host/device boundary in either direction
+# except the per-block reduction rows. The search kernel reads a table of
+# meta rows, one per grid step, the grid as long as the table's live rows
+# (callers pad the table to one fixed size); the frontier and decode-proof
+# kernels read one (1, META_COLS) row and a static `n_blocks`, which
+# callers bucket to a power of two exactly like `_bucketed_cols` buckets
+# grid shapes, so streamed sweeps of varying chunk sizes reuse O(log G)
+# jit entries.
 
 def _axes_meta_specs(axes, w: int, extra):
     return [pl.BlockSpec(axes.shape, lambda i: (0, 0)),
@@ -704,32 +726,41 @@ def _axes_meta_specs(axes, w: int, extra):
             extra]
 
 
-@functools.partial(jax.jit, static_argnames=("radices", "n_blocks",
-                                             "workloads", "constants",
-                                             "interpret"))
-def dse_search_decoded(axes, meta, cons, carry, *, radices: tuple,
-                       n_blocks: int, workloads: tuple,
-                       constants: DeviceConstants,
+@functools.partial(jax.jit, static_argnames=("radices", "workloads",
+                                             "constants", "interpret"))
+def dse_search_decoded(axes, table, cons, carry, *, radices: tuple,
+                       workloads: tuple, constants: DeviceConstants,
                        interpret: Optional[bool] = None):
-    """Fused search over the index span (and slab digit ranges) named by
-    the (1, META_COLS) meta row, over a product space with static
-    `radices`; same operand contract and output layout as
-    `dse_search_padded`, except configs are decoded on device and emitted
-    indices are global flat-space indices (no launch-local rebasing)."""
+    """Fused search over the blocks a (R, META_COLS) int32 meta table names,
+    one row per grid step — [block base, span end, five slab digit
+    ranges] (see `_dse_search_decode_kernel`) — over a product space with
+    static `radices`. Same operand contract and output layout as
+    `dse_search_padded`, one output column per table row, except configs
+    are decoded on device and emitted indices are global flat-space
+    indices (no launch-local rebasing). The table sits in SMEM, flattened
+    (a scalar read per column per step).
+
+    Live rows (span end > block base) come first, padding rows after
+    them: the grid's length is the live row count, read from the table on
+    device, so one executable serves every row count up to R and padding
+    costs no grid step. The columns of padding rows are never written;
+    read only the live rows' columns."""
     w = len(workloads)
     kernel = functools.partial(_dse_search_decode_kernel, workloads,
                                tuple(radices), constants)
-    out_spec, out_shape = _search_out_spec(w, n_blocks)
+    out_spec, out_shape = _search_out_spec(w, table.shape[0])
     out = pl.pallas_call(
         kernel,
-        grid=(n_blocks,),
-        in_specs=_axes_meta_specs(axes, w,
-                                  pl.BlockSpec((w, 1), lambda i: (0, 0))),
+        grid=(jnp.sum(table[:, 1] > table[:, 0], dtype=jnp.int32),),
+        in_specs=[pl.BlockSpec(axes.shape, lambda i: (0, 0)),
+                  pl.BlockSpec(memory_space=pltpu.SMEM),
+                  pl.BlockSpec((w, 4), lambda i: (0, 0)),
+                  pl.BlockSpec((w, 1), lambda i: (0, 0))],
         out_specs=out_spec,
         out_shape=out_shape,
         interpret=resolve_interpret(interpret),
         name="dse_search_decoded",
-    )(axes, meta, cons, carry)
+    )(axes, table.reshape(-1), cons, carry)
     return out[:, ::LANES]
 
 

@@ -55,11 +55,11 @@ def _wait(out) -> np.ndarray:
         return np.asarray(out)
 
 
-def _launched(sp, lanes: int, workloads=()) -> None:
-    """One launch of `lanes` lanes: its `launch` span's stat, and its lanes
-    x GEMM rows (summed over its workloads) to every open
-    `gemm_lane_tally`."""
-    sp.set_metadata(lanes=lanes)
+def _launched(sp, lanes: int, workloads=(), **stats) -> None:
+    """One launch of `lanes` lanes: its `launch` span's stats (`lanes`, and
+    `rows` on a decoded search launch), and its lanes x GEMM rows (summed
+    over its workloads) to every open `gemm_lane_tally`."""
+    sp.set_metadata(lanes=lanes, **stats)
     count_gemm_lanes(lanes * sum(len(g) for g, _ in workloads))
 
 
@@ -328,28 +328,10 @@ def dse_search_multi(grid: np.ndarray, wls, constraints_seq,
             out = _wait(_dse.dse_search_padded(
                 cols, mask, cons, carry, workloads=workloads, constants=c,
                 interpret=interpret))
-            col_base = np.zeros(out.shape[1], np.int64)
+            col_base = None
         _launched(sp, out.shape[1] * _dse.BLOCK, workloads)
         _integrity_check(out, "dse_search")
-        best_idx, best_edp, n_feasible = [], [], []
-        for w in range(len(workloads)):
-            edp_b, idx_b, nf_b = out[_dse.SEARCH_ROWS * w:
-                                     _dse.SEARCH_ROWS * (w + 1)]
-            nf = int(round(float(nf_b.sum())))
-            n_feasible.append(nf)
-            # Shard-local indices -> grid-global (sentinels stay put).
-            idx_g = np.where(idx_b >= 0, idx_b + col_base, idx_b)
-            # Min EDP across blocks; ties broken towards the lowest global
-            # index, matching the sequential/numpy engines' first-hit rule
-            # (CARRY_IDX sorts before every real index, so a carried tie wins).
-            jb = np.lexsort((idx_g, edp_b))[0]
-            i = int(idx_g[jb])
-            best_edp.append(float(edp_b[jb]))
-            if nf == 0 and carry_edp is None:
-                best_idx.append(-1)
-                continue
-            best_idx.append(i if i >= 0 else int(_dse.CARRY_IDX))
-        return best_idx, best_edp, n_feasible
+        return _search_best(out, carry_edp, col_base)
 
 
 def dse_pareto_multi(grid: np.ndarray, wls, constraints_seq,
@@ -494,10 +476,11 @@ def _bucket_blocks(count: int, floor: int = 8,
 @functools.lru_cache(maxsize=32)
 def _sharded_decoded_fn(kind: str, statics: tuple, k: int, radices: tuple,
                         n_blocks: int):
-    """Jit-cached shard_map wrapper of a decoded-kernel launch: the (k, 2)
-    per-shard [base, end) spans are sharded over the candidate mesh, the
-    tiny axes/cons/carry operands are replicated, and each shard runs
-    `n_blocks` blocks of its own index range."""
+    """Jit-cached shard_map wrapper of a decoded-kernel launch: the meta
+    rows are sharded over the candidate mesh and the tiny axes/cons/carry
+    operands replicated. "search": each shard runs the live rows of its
+    `n_blocks`-row share of the meta table, one block per row; "pareto":
+    each shard runs `n_blocks` blocks from its own row's base."""
     from jax.sharding import PartitionSpec as P
 
     from repro.launch.mesh import make_candidate_mesh
@@ -512,7 +495,7 @@ def _sharded_decoded_fn(kind: str, statics: tuple, k: int, radices: tuple,
         def body(axes, meta_l, cons, carry):
             return _dse.dse_search_decoded(
                 axes, meta_l, cons, carry, radices=radices,
-                n_blocks=n_blocks, workloads=workloads, constants=constants,
+                workloads=workloads, constants=constants,
                 interpret=interpret)
     else:
         workloads, objectives, has_carry, constants, interpret = statics
@@ -543,48 +526,132 @@ def _check_decode_span(limit: int):
             f"Use the jax or numpy factorized engines for larger spaces.")
 
 
-def _decoded_launch(space, start: int, count: int, kind: str, statics: tuple,
-                    cons, carry, shard, slab=None):
-    """Run a decoded-kernel launch over [start, start + count), optionally
-    fanned out over the candidate mesh and optionally masked to a slab's
-    digit ranges. Returns (out, blk_lo): the stacked per-block reduction
-    columns and each column's first global index."""
+def _decoded_launch(space, start: int, count: int, statics: tuple, cons,
+                    carry, shard, slab=None, table=None):
+    """Run a decoded-kernel launch over the index window [start, start +
+    count) of a product space, optionally fanned out over the candidate
+    mesh. Returns (out, blk_lo): the stacked per-block reduction columns
+    and each column's first global index.
+
+    The frontier kernel (five statics) covers the window, optionally
+    masked to a slab's digit ranges. The search kernel (three statics and
+    a meta `table`, see `_search_table`) runs the table's rows whose
+    blocks start inside the window: at most SEARCH_TABLE_ROWS per device,
+    each device's share padded to that size; only the live rows' columns
+    come back."""
     axes_cols, radices = _axes_operand(space)
+    k = _candidate_shards(shard)
+    if table is not None:
+        part = table[(table[:, 0] >= start) & (table[:, 0] < start + count)]
+        per = -(-len(part) // k)
+        padded = np.zeros((k, SEARCH_TABLE_ROWS, _dse.META_COLS), np.int32)
+        for i in range(k):
+            share = part[i * per:(i + 1) * per]
+            padded[i, :len(share)] = share
+        padded = padded.reshape(-1, _dse.META_COLS)
+        if k > 1:
+            fn = _sharded_decoded_fn("search", statics, k, radices,
+                                     SEARCH_TABLE_ROWS)
+        else:
+            workloads, constants, interpret = statics
+            fn = functools.partial(_dse.dse_search_decoded, radices=radices,
+                                   workloads=workloads, constants=constants,
+                                   interpret=interpret)
+        out = _wait(fn(axes_cols, jnp.asarray(padded), cons, carry))
+        live = padded[:, 1] > padded[:, 0]
+        return out[:, live], padded[live, 0].astype(np.int64)
     limit = min(start + count, space.size)
     _check_decode_span(limit)
-    # The decoded search kernel generates its lanes from an iota, so it
-    # runs much wider blocks than the operand-streaming kernels (see
-    # dse_eval.DECODE_BLOCK); the frontier kernel keeps BLOCK (its
-    # dominance pass is quadratic in the block).
-    block = _dse.DECODE_BLOCK if kind == "search" else _dse.BLOCK
-    if shard is not None and int(shard) > 1:
-        from repro.launch.mesh import make_candidate_mesh
-        k = make_candidate_mesh(shard).devices.size
+    block = _dse.BLOCK
+    if k > 1:
         bps = _bucket_blocks(-(-count // k), floor=1, block=block)
         bases = start + np.arange(k) * bps * block
         meta = _meta_rows(radices, bases, limit, slab)
-        fn = _sharded_decoded_fn(kind, statics, k, radices, bps)
+        fn = _sharded_decoded_fn("pareto", statics, k, radices, bps)
         out = _wait(fn(axes_cols, jnp.asarray(meta), cons, carry))
         blk_lo = (np.repeat(meta[:, 0].astype(np.int64), bps)
                   + np.tile(np.arange(bps, dtype=np.int64), k) * block)
         return out, blk_lo
-    n_blocks = _bucket_blocks(count, floor=1 if kind == "search" else 8,
-                              block=block)
+    n_blocks = _bucket_blocks(count, block=block)
     meta = jnp.asarray(_meta_rows(radices, [start], limit, slab))
-    if kind == "search":
-        workloads, constants, interpret = statics
-        out = _dse.dse_search_decoded(
-            axes_cols, meta, cons, carry, radices=radices,
-            n_blocks=n_blocks, workloads=workloads, constants=constants,
-            interpret=interpret)
-    else:
-        workloads, objectives, has_carry, constants, interpret = statics
-        out = _dse.dse_pareto_decoded(
-            axes_cols, meta, cons, carry, radices=radices,
-            n_blocks=n_blocks, workloads=workloads, objectives=objectives,
-            has_carry=has_carry, constants=constants, interpret=interpret)
+    workloads, objectives, has_carry, constants, interpret = statics
+    out = _dse.dse_pareto_decoded(
+        axes_cols, meta, cons, carry, radices=radices, n_blocks=n_blocks,
+        workloads=workloads, objectives=objectives, has_carry=has_carry,
+        constants=constants, interpret=interpret)
     blk_lo = start + np.arange(n_blocks, dtype=np.int64) * block
     return _wait(out), blk_lo
+
+
+def _candidate_shards(shard) -> int:
+    """Devices of the candidate mesh a `shard=` request fans out over."""
+    if shard is None or int(shard) <= 1:
+        return 1
+    from repro.launch.mesh import make_candidate_mesh
+    return make_candidate_mesh(shard).devices.size
+
+
+# Meta rows a decoded search launch holds per device: every table is padded
+# to this size (one executable per search, whatever the row count: the
+# kernel's grid runs the live rows alone, see `dse_search_decoded`), and a
+# longer table is split into launches of this size. A branch-and-bound
+# probe batch of a few leaves takes a handful of rows, a warm revival
+# batch of hundreds of small slabs up to a few launches, the whole 24^5
+# space (486 blocks) one; 512 rows keep the flattened table at 24 KiB of
+# SMEM.
+SEARCH_TABLE_ROWS = 512
+
+
+def _search_table(radices, size: int, items) -> np.ndarray:
+    """(R, META_COLS) int32 decoded-search meta table of a work list:
+    one row per DECODE_BLOCK of each (start, count, slab) item's span
+    [start, min(start + count, size)), with the item's slab digit ranges
+    (the whole space when slab is None). Refuses spans past 2**24, as
+    every decoded launch does (`_check_decode_span`)."""
+    from repro.core.factorized import full_ranges
+    full = full_ranges(radices)
+    starts = np.asarray([s for s, _, _ in items], np.int64)
+    limits = np.minimum(starts + np.asarray([n for _, n, _ in items],
+                                            np.int64), size)
+    slabs = np.asarray([full if sl is None else sl for _, _, sl in items],
+                       np.int64).reshape(-1, 10)
+    if len(limits):
+        _check_decode_span(int(limits.max()))
+    n_blk = np.maximum(-(-(limits - starts) // _dse.DECODE_BLOCK), 0)
+    item = np.repeat(np.arange(len(starts)), n_blk)
+    first = np.cumsum(n_blk) - n_blk
+    table = np.empty((len(item), _dse.META_COLS), np.int32)
+    table[:, 0] = (starts[item] + (np.arange(len(item)) - first[item])
+                   * _dse.DECODE_BLOCK)
+    table[:, 1] = limits[item]
+    table[:, 2:] = slabs[item]
+    return table
+
+
+def _search_best(out, carry_edp, col_base=None):
+    """Per-workload (best_idx, best_edp, n_feasible) lists from a search
+    launch's (SEARCH_ROWS * W, n_blocks) reduction columns (indices
+    rebased by `col_base` where launch-local). Min EDP across columns,
+    exact ties to the lowest index — the sequential engines' first-hit
+    rule — and CARRY_IDX sorts before every real index, so a carried
+    tie wins. best_idx is -1 when nothing is feasible and no carry was
+    given, CARRY_IDX when the carry stands."""
+    best_idx, best_edp, n_feasible = [], [], []
+    for w in range(out.shape[0] // _dse.SEARCH_ROWS):
+        edp_b, idx_b, nf_b = out[_dse.SEARCH_ROWS * w:
+                                 _dse.SEARCH_ROWS * (w + 1)]
+        nf = int(round(float(nf_b.sum(dtype=np.float64))))
+        n_feasible.append(nf)
+        if col_base is not None:
+            idx_b = np.where(idx_b >= 0, idx_b + col_base, idx_b)
+        jb = np.lexsort((idx_b, edp_b))[0]
+        i = int(idx_b[jb])
+        best_edp.append(float(edp_b[jb]))
+        if nf == 0 and carry_edp is None:
+            best_idx.append(-1)
+            continue
+        best_idx.append(i if i >= 0 else int(_dse.CARRY_IDX))
+    return best_idx, best_edp, n_feasible
 
 
 def dse_search_multi_factorized(space, start: int, count: int, wls,
@@ -599,34 +666,12 @@ def dse_search_multi_factorized(space, start: int, count: int, wls,
     on device (decoded from `space`) and `best_idx` is a global flat-space
     index (materialize the winning row with `space.decode`). `slab` (five
     [lo, hi) digit ranges) additionally masks the span's lanes to the
-    slab's members in-kernel — the bound-guided search launches each
-    surviving slab over its bounding index range this way.
+    slab's members in-kernel. The span's blocks are the rows of one
+    decoded search launch (`dse_search_spans_factorized`).
     """
-    with span("launch") as sp:
-        workloads = tuple(workload_statics(wl, c) for wl in wls)
-        cons = _constraint_rows(constraints_seq)
-        carry = _search_carry_rows(carry_edp, len(workloads))
-        out, blk_lo = _decoded_launch(space, start, count, "search",
-                                      (workloads, c, interpret), cons, carry,
-                                      shard, slab)
-        _launched(sp, len(blk_lo) * _dse.DECODE_BLOCK, workloads)
-        _integrity_check(out, "dse_search_decoded")
-        best_idx, best_edp, n_feasible = [], [], []
-        for w in range(len(workloads)):
-            edp_b, idx_b, nf_b = out[_dse.SEARCH_ROWS * w:
-                                     _dse.SEARCH_ROWS * (w + 1)]
-            nf = int(round(float(nf_b.sum())))
-            n_feasible.append(nf)
-            # Indices are already global; min EDP with ties to the lowest index
-            # (CARRY_IDX sorts before every real index, so a carried tie wins).
-            jb = np.lexsort((idx_b, edp_b))[0]
-            i = int(idx_b[jb])
-            best_edp.append(float(edp_b[jb]))
-            if nf == 0 and carry_edp is None:
-                best_idx.append(-1)
-                continue
-            best_idx.append(i if i >= 0 else int(_dse.CARRY_IDX))
-        return best_idx, best_edp, n_feasible
+    return dse_search_spans_factorized(
+        space, [(start, count, slab)], wls, constraints_seq, c, interpret,
+        shard=shard, carry_edp=carry_edp)
 
 
 def dse_pareto_multi_factorized(space, start: int, count: int, wls,
@@ -650,7 +695,7 @@ def dse_pareto_multi_factorized(space, start: int, count: int, wls,
             p is not None and len(p) for p in carry_points)
         carry = _front_carry_rows(carry_points, len(workloads), len(objectives))
         out, blk_lo = _decoded_launch(
-            space, start, count, "pareto",
+            space, start, count,
             (workloads, objectives, has_carry, c, interpret), cons, carry,
             shard, slab)
         _launched(sp, len(blk_lo) * _dse.BLOCK, workloads)
@@ -689,34 +734,48 @@ def dse_search_spans_factorized(space, items, wls, constraints_seq,
                                 c: DeviceConstants = CONSTANTS,
                                 interpret: Optional[bool] = None, *, shard=None,
                                 carry_edp=None):
-    """Compose `dse_search_multi_factorized` launches over a work list.
+    """Fused search over a work list in as few decoded launches as its
+    meta table allows.
 
-    `items` is a sequence of (start, count, slab) triples in ascending
-    index order (slab None = plain contiguous span) — the surviving leaf
-    slabs of the bound-guided search, or a chunked split of one. Each
-    workload's running best EDP rides between launches through the
-    kernels' existing carry operand, so exact ties keep the earlier item's
-    winner (the global first-hit rule). Returns (best_idx, best_edp,
-    n_feasible) lists like `dse_search_multi_factorized`; `best_idx` is -1
-    when nothing was feasible anywhere (or CARRY_IDX when only the
-    caller's `carry_edp` stands).
+    `items` is a sequence of (start, count, slab) triples (slab None = plain
+    contiguous span) — the leaf slabs of one branch-and-bound batch, or one
+    span. Each item's span becomes one meta-table row per DECODE_BLOCK
+    (`_search_table`), SEARCH_TABLE_ROWS rows a launch (per shard on a
+    `shard=N` mesh), and every row is reduced against the caller's
+    `carry_edp` alone. One `launch` span per launch, with the launch's
+    `rows`. The host then takes the strictly lowest EDP over all rows with
+    exact ties to the lowest flat index, whatever the order of the items —
+    the global first-hit rule, the carry winning its ties. Returns
+    (best_idx, best_edp, n_feasible) lists; `best_idx` is -1 when nothing
+    was feasible anywhere (or CARRY_IDX when only the caller's `carry_edp`
+    stands).
     """
+    table = _search_table(space.radices, space.size, items)
     w = len(wls)
-    carry = list(carry_edp) if carry_edp is not None \
-        else [float("inf")] * w
-    best_idx = [-1 if carry_edp is None else int(_dse.CARRY_IDX)] * w
-    best_edp = list(carry)
-    n_feasible = [0] * w
-    for start, count, slab in items:
-        bi, be, bn = dse_search_multi_factorized(
-            space, start, count, wls, constraints_seq, c, interpret,
-            shard=shard, carry_edp=carry, slab=slab)
-        for wi in range(w):
-            n_feasible[wi] += bn[wi]
-            if bi[wi] >= 0:  # beat the carry (ties stay with the carry)
-                best_idx[wi], best_edp[wi] = bi[wi], be[wi]
-                carry[wi] = be[wi]
-    return best_idx, best_edp, n_feasible
+    # The carry's own column: what every block emits that it wins, and the
+    # whole answer of an empty table.
+    rows = _dse.SEARCH_ROWS
+    carried = np.zeros((rows * w, 1), np.float32)
+    carried[0::rows, 0] = np.inf if carry_edp is None else carry_edp
+    carried[1::rows, 0] = _dse.CARRY_IDX
+    outs = [carried]
+    top = _candidate_shards(shard) * SEARCH_TABLE_ROWS
+    for lo in range(0, len(table), top):
+        with span("launch") as sp:
+            if lo == 0:  # once per call, in its first launch
+                workloads = tuple(workload_statics(wl, c) for wl in wls)
+                operands = (_constraint_rows(constraints_seq),
+                            _search_carry_rows(carry_edp, w))
+            part = table[lo:lo + top]
+            first = int(part[:, 0].min())
+            out, _ = _decoded_launch(
+                space, first, int(part[:, 1].max()) - first,
+                (workloads, c, interpret), *operands, shard, table=part)
+            _launched(sp, out.shape[1] * _dse.DECODE_BLOCK, workloads,
+                      rows=out.shape[1])
+            _integrity_check(out, "dse_search_decoded")
+            outs.append(out)
+    return _search_best(np.concatenate(outs, axis=1), carry_edp)
 
 
 def dse_pareto_spans_factorized(space, items, wls, constraints_seq,

@@ -110,13 +110,49 @@ def test_dse_search_padded_compiles(compile_v5e):
         "dse_search_padded")
 
 
-def test_dse_search_decoded_compiles(compile_v5e):
-    # Branch-and-bound's coarse slabs: one DECODE_BLOCK per launch.
-    fn = functools.partial(K.dse_search_decoded, radices=R20, n_blocks=1,
+def _search_decoded():
+    from repro.kernels.ops import SEARCH_TABLE_ROWS
+
+    fn = functools.partial(K.dse_search_decoded, radices=(24,) * 5,
                            workloads=_workloads("deit-b"),
                            constants=CONSTANTS, interpret=False)
-    assert _custom_call(compile_v5e(fn, AXES_20, META, _f32(1, 4),
+    return fn, ((SEARCH_TABLE_ROWS, K.META_COLS), jnp.int32)
+
+
+def test_dse_search_decoded_compiles(compile_v5e):
+    # Branch-and-bound leaf batches and single spans over 24^5 with DeiT-B
+    # statics: one meta table of SEARCH_TABLE_ROWS rows, the grid as long
+    # as its live rows.
+    fn, table = _search_decoded()
+    assert _custom_call(compile_v5e(fn, _f32(5, 24), table, _f32(1, 4),
                                     _f32(1, 1)), "dse_search_decoded")
+
+
+def test_dse_search_decoded_compiles_sharded(topo, no_compile_cache):
+    # The `shard=` fan-out: each chip of the 2x2 mesh runs the live rows of
+    # its own share of the table.
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from repro.parallel.sharding import CANDIDATE_AXIS, candidate_spec
+
+    mesh = Mesh(np.array(topo.devices), (CANDIDATE_AXIS,))
+    body, ((rows, cols), dtype) = _search_decoded()
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(P(None, None), candidate_spec(2, 0),
+                                 P(None, None), P(None, None)),
+                       out_specs=candidate_spec(2, 1), check_vma=False)
+
+    def arg(shape, dtype=jnp.float32, spec=P(None, None)):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    compiled = jax.jit(fn).lower(
+        arg((5, 24)), arg((mesh.size * rows, cols), dtype,
+                          candidate_spec(2, 0)),
+        arg((1, 4)), arg((1, 1))).compile()
+    assert _custom_call(compiled, "dse_search_decoded")
 
 
 def test_dse_pareto_padded_compiles(compile_v5e):

@@ -106,14 +106,17 @@ def test_results_count_gemm_lanes_of_their_launches(monkeypatch):
     import dataclasses
 
     from repro.core.search import ParetoResult, SearchResult, search
-    from repro.kernels import ops
+    from repro.kernels import dse_eval, ops
 
     wl = _tiny_workload()
     rec = _Recorder()
     monkeypatch.setattr(ops, "span", rec)
     res = search(wl, engine="pallas", factorized=True, n_z=4)
     launches = rec.named("launch")
-    assert launches and all(set(s) == {"lanes"} for s in launches)
+    # decoded search launches: their meta-table rows beside their lanes
+    assert launches and all(set(s) == {"lanes", "rows"} for s in launches)
+    assert all(s["lanes"] == s["rows"] * dse_eval.DECODE_BLOCK
+               for s in launches)
     assert res.n_gemm_lanes == sum(s["lanes"] for s in launches) * 3 > 0
     # lane padding is how an engine ran, not the answer
     assert dataclasses.replace(res, n_gemm_lanes=0) == res
