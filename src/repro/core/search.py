@@ -15,7 +15,10 @@ The paper-level entry points:
 
 Beyond-paper, the unified engine layer (`search` / `search_workloads`): four
 interchangeable backends over the same cost model, all returning identical
-`SearchResult`s —
+`SearchResult`s. A materialized grid runs through one chunked loop per
+objective, with the engine chosen from that objective's chunk table
+(EDP_CHUNK_ENGINES / PARETO_CHUNK_ENGINES); a one-shot search is the
+single-chunk case —
 
   * `python` — the paper-faithful Alg. 2 sequential loop (the oracle).
   * `numpy`  — the whole grid as one broadcasted float64 computation.
@@ -43,15 +46,15 @@ min-EDP point they return the whole non-dominated feasible set over
 their own way (sequential incremental front, exact float64 mask, jit
 sort-and-scan, per-block dominance reduction in the fused kernel) and every
 proposal is refined through the float64 reference model, so identical
-frontiers come back byte-identical; see PARETO_ENGINES below.
+frontiers come back byte-identical; see PARETO_CHUNK_ENGINES below.
 
 Scaling past one device / one resident grid, both entry points take
 `shard=` (shard_map fan-out over a 1-D candidate-axis mesh) and
 `chunk_size=` (host-side streaming of grid chunks, with a running argmin /
 bounded running frontier carried across chunks — and, on pallas, *into* the
 kernels, whose launches compose through carry operands). Every
-(shard, chunk_size) setting is byte-identical to the one-shot sweep on
-every engine and objective; tests/test_sharded_search.py is the
+(shard, chunk_size) setting is byte-identical to the single unsharded
+chunk on every engine and objective; tests/test_sharded_search.py is the
 differential harness that pins that down.
 
 When the grid is a Cartesian product of per-parameter candidate sets (every
@@ -587,37 +590,6 @@ def _prefiltered(grid, wl, constraints, c, hierarchical):
     return sub, len(sub)
 
 
-def _python_engine(grid, wl, constraints, c, hierarchical, interpret):
-    r = _sequential_search(grid, wl, constraints, prune=hierarchical,
-                           collect=False, c=c, edp_init=float("inf"))
-    row = None if r.best_cfg is None else r.best_cfg.as_array()
-    return _make_result(row, r.n_feasible, wl, c, len(grid),
-                        r.n_workload_evals, r.wall_time_s)
-
-
-def _vector_engine(grid, wl, constraints, c, hierarchical, xp):
-    t0 = time.perf_counter()
-    sub, n_wl = _prefiltered(grid, wl, constraints, c, hierarchical)
-    if len(sub) == 0:
-        return _make_result(None, 0, wl, c, len(grid), 0,
-                            time.perf_counter() - t0)
-    m = evaluate_grid(sub, wl, c, xp)
-    ok = np.asarray(constraints.satisfied(
-        np.asarray(m["area"]), np.asarray(m["power"]),
-        np.asarray(m["energy"]), np.asarray(m["latency"])))
-    n_feasible = int(ok.sum())
-    if n_feasible == 0:
-        return _make_result(None, 0, wl, c, len(grid), n_wl,
-                            time.perf_counter() - t0)
-    edp = np.where(ok, np.asarray(m["edp"]), np.inf)
-    return _make_result(sub[int(np.argmin(edp))], n_feasible, wl, c,
-                        len(grid), n_wl, time.perf_counter() - t0)
-
-
-def _numpy_engine(grid, wl, constraints, c, hierarchical, interpret):
-    return _vector_engine(grid, wl, constraints, c, hierarchical, xp=np)
-
-
 @functools.lru_cache(maxsize=128)
 def _jax_search_fn(gemms, wl_scalars, c: DeviceConstants):
     """Jit-cached fused (argmin_idx, its EDP, n_feasible) for one workload.
@@ -625,7 +597,7 @@ def _jax_search_fn(gemms, wl_scalars, c: DeviceConstants):
     launch) are dynamic operands, so scenario sweeps reuse the cache entry;
     only three scalars leave the device. The returned EDP is the engine's
     own float32 value — the cross-chunk running argmin compares natively,
-    so streaming composes bit-exactly with the one-shot sweep."""
+    so every chunking picks the winner a single chunk picks."""
     import jax
     import jax.numpy as jnp
 
@@ -654,40 +626,6 @@ def _constraint_vec(constraints):
     return jnp.asarray([constraints.area_mm2, constraints.power_w,
                         constraints.energy_j, constraints.latency_s],
                        jnp.float32)
-
-
-def _jax_engine(grid, wl, constraints, c, hierarchical, interpret):
-    import jax.numpy as jnp
-    t0 = time.perf_counter()
-    sub, n_wl = _prefiltered(grid, wl, constraints, c, hierarchical)
-    if len(sub) == 0:
-        return _make_result(None, 0, wl, c, len(grid), 0,
-                            time.perf_counter() - t0)
-    gemms, scalars = workload_statics(wl, c)
-    fn = _jax_search_fn(gemms, scalars, c)
-    i, _, nf = fn(jnp.asarray(sub.T, jnp.float32),
-                  jnp.ones(len(sub), bool), _constraint_vec(constraints))
-    i, nf = int(i), int(nf)
-    row = sub[i] if nf > 0 else None
-    return _make_result(row, nf, wl, c, len(grid), n_wl,
-                        time.perf_counter() - t0)
-
-
-def _pallas_engine(grid, wl, constraints, c, hierarchical, interpret):
-    from repro.kernels.ops import dse_search_grid  # deferred: kernels import core
-    t0 = time.perf_counter()
-    sub, n_wl = _prefiltered(grid, wl, constraints, c, hierarchical)
-    if len(sub) == 0:
-        return _make_result(None, 0, wl, c, len(grid), 0,
-                            time.perf_counter() - t0)
-    i, _, nf = dse_search_grid(sub, wl, constraints, c, interpret)
-    row = sub[i] if i >= 0 else None
-    return _make_result(row, nf, wl, c, len(grid), n_wl,
-                        time.perf_counter() - t0)
-
-
-ENGINES = {"python": _python_engine, "numpy": _numpy_engine,
-           "jax": _jax_engine, "pallas": _pallas_engine}
 
 
 # ---------------------------------------------------------------------------
@@ -769,43 +707,6 @@ def _sequential_pareto(grid, wl: Workload, constraints: Constraints,
         front_rows.append(np.asarray(row))
         front_pts.append(p)
     return front_rows, n_feasible, n_wl
-
-
-def _pareto_result(cand_rows, n_feasible, wl, constraints, c, objectives,
-                   n_evaluated, n_wl, t0) -> ParetoResult:
-    with span("search.refine"):
-        front, met, _ = _pareto_from_rows(cand_rows, wl, constraints, c,
-                                          objectives)
-    return ParetoResult(front=front, metrics=met, objectives=objectives,
-                        n_evaluated=n_evaluated, n_feasible=n_feasible,
-                        n_workload_evals=n_wl,
-                        wall_time_s=time.perf_counter() - t0)
-
-
-def _pareto_python(grid, wl, constraints, c, hierarchical, interpret,
-                   objectives):
-    t0 = time.perf_counter()
-    rows, n_feasible, n_wl = _sequential_pareto(grid, wl, constraints,
-                                                hierarchical, c, objectives)
-    cand = np.asarray(rows, np.int64).reshape(-1, 5)
-    return _pareto_result(cand, n_feasible, wl, constraints, c, objectives,
-                          len(grid), n_wl, t0)
-
-
-def _pareto_numpy(grid, wl, constraints, c, hierarchical, interpret,
-                  objectives):
-    t0 = time.perf_counter()
-    sub, n_wl = _prefiltered(grid, wl, constraints, c, hierarchical)
-    if len(sub) == 0:
-        return _pareto_result(sub, 0, wl, constraints, c, objectives,
-                              len(grid), 0, t0)
-    m = evaluate_grid(sub, wl, c, xp=np)
-    front, met, n_feasible = _pareto_from_rows(sub, wl, constraints, c,
-                                               objectives, m=m)
-    return ParetoResult(front=front, metrics=met, objectives=objectives,
-                        n_evaluated=len(grid), n_feasible=n_feasible,
-                        n_workload_evals=n_wl,
-                        wall_time_s=time.perf_counter() - t0)
 
 
 # Sorted points per scan step and running-frontier buffer bound of the jax
@@ -892,57 +793,22 @@ def _jax_pareto_fn(gemms, wl_scalars, c: DeviceConstants, objectives: tuple):
     return jax.jit(fn)
 
 
-def _pareto_jax(grid, wl, constraints, c, hierarchical, interpret,
-                objectives):
-    import jax.numpy as jnp
-    t0 = time.perf_counter()
-    sub, n_wl = _prefiltered(grid, wl, constraints, c, hierarchical)
-    if len(sub) == 0:
-        return _pareto_result(sub, 0, wl, constraints, c, objectives,
-                              len(grid), 0, t0)
-    cols, valid = _padded_candidate_cols(sub, JAX_PARETO_CHUNK)
-    gemms, scalars = workload_statics(wl, c)
-    fn = _jax_pareto_fn(gemms, scalars, c, objectives)
-    mask, nf = fn(jnp.asarray(cols), jnp.asarray(valid),
-                  _constraint_vec(constraints))
-    cand = sub[np.asarray(mask)[:len(sub)]]
-    return _pareto_result(cand, int(nf), wl, constraints, c, objectives,
-                          len(grid), n_wl, t0)
-
-
-def _pareto_pallas(grid, wl, constraints, c, hierarchical, interpret,
-                   objectives):
-    from repro.kernels.ops import dse_pareto_multi  # deferred: kernels import core
-    t0 = time.perf_counter()
-    sub, n_wl = _prefiltered(grid, wl, constraints, c, hierarchical)
-    if len(sub) == 0:
-        return _pareto_result(sub, 0, wl, constraints, c, objectives,
-                              len(grid), 0, t0)
-    (cand_idx, nf, n_over), = dse_pareto_multi(sub, [wl], [constraints], c,
-                                               interpret,
-                                               objectives=objectives)
-    r = _pareto_result(sub[cand_idx], nf, wl, constraints, c, objectives,
-                       len(grid), n_wl, t0)
-    r.n_overflow = n_over
-    return r
-
-
-PARETO_ENGINES = {"python": _pareto_python, "numpy": _pareto_numpy,
-                  "jax": _pareto_jax, "pallas": _pareto_pallas}
-
-
 # ---------------------------------------------------------------------------
-# Sharded + streamed evaluation layer (shard= / chunk_size=)
+# Materialized-grid drivers: one chunked loop per objective
 #
-# `chunk_size=` streams the candidate grid through the engines in host-side
-# chunks, carrying a running argmin (EDP mode) or a bounded running frontier
-# (pareto mode) across chunks — no full (G, 5) grid or (4, G) metrics array
-# ever has to be resident at once. `shard=` fans each chunk's evaluation out
-# over a 1-D candidate-axis device mesh with shard_map (jax/pallas engines;
-# the host engines split the chunk the same way so every backend exercises
-# the identical reduction). Both knobs are exact: any (shard, chunk_size)
-# setting returns byte-identical results to the one-shot sweep, which
-# tests/test_sharded_search.py enforces per engine x objective.
+# A (G, 5) grid streams through the engines in host-side chunks of
+# `chunk_size=` candidates (None: the whole grid as a single chunk, the
+# one-shot sweep), carrying a running argmin (EDP mode) or a bounded running
+# frontier (pareto mode) across chunks — no full (4, G) metrics array ever
+# has to be resident at once. One chunk table per objective
+# (EDP_CHUNK_ENGINES, PARETO_CHUNK_ENGINES) selects the engine; every chunk
+# evaluator is handed the carry, and only pallas folds it into its launch.
+# `shard=` fans each chunk's evaluation out over a 1-D candidate-axis device
+# mesh with shard_map (jax/pallas engines; the host engines split the chunk
+# the same way so every backend exercises the identical reduction). Both
+# knobs are exact: any (shard, chunk_size) setting returns byte-identical
+# results to a single unsharded chunk, which tests/test_sharded_search.py
+# enforces per engine x objective.
 # ---------------------------------------------------------------------------
 
 def _iter_chunks(grid, chunk_size: int):
@@ -965,7 +831,7 @@ def merge_running_best(carry, candidate):
     Strict-< replacement: exact EDP ties keep the incumbent, which arrived
     from an earlier chunk/shard and therefore has the lower global grid
     index — composing this merge over any partition of the grid reproduces
-    the one-shot engines' first-hit argmin rule exactly.
+    a single chunk's first-hit argmin rule exactly.
     """
     row, edp = candidate
     if row is not None and edp < carry[1]:
@@ -974,7 +840,7 @@ def merge_running_best(carry, candidate):
 
 
 def _edp_chunk_python(chunk, wl, constraints, c, hierarchical, interpret,
-                      shard):
+                      shard, carry):
     best = (None, float("inf"))
     nf = n_wl = 0
     for part in _host_shards(chunk, shard):
@@ -988,7 +854,7 @@ def _edp_chunk_python(chunk, wl, constraints, c, hierarchical, interpret,
 
 
 def _edp_chunk_numpy(chunk, wl, constraints, c, hierarchical, interpret,
-                     shard):
+                     shard, carry):
     best = (None, float("inf"))
     nf = n_wl = 0
     for part in _host_shards(chunk, shard):
@@ -1088,7 +954,7 @@ def _jax_sharded_argmin(fn, sub, cons_vec, shard):
 
 
 def _edp_chunk_jax(chunk, wl, constraints, c, hierarchical, interpret,
-                   shard):
+                   shard, carry):
     import jax.numpy as jnp
     sub, n_wl = _prefiltered(chunk, wl, constraints, c, hierarchical)
     if len(sub) == 0:
@@ -1108,18 +974,25 @@ def _edp_chunk_jax(chunk, wl, constraints, c, hierarchical, interpret,
 
 
 def _edp_chunk_pallas(chunk, wl, constraints, c, hierarchical, interpret,
-                      shard, carry_edp):
+                      shard, carry):
+    """The kernel folds the carried best EDP into its own reduction (the
+    carry wins ties), so per-chunk launches compose on-device."""
     from repro.kernels.ops import dse_search_grid
     sub, n_wl = _prefiltered(chunk, wl, constraints, c, hierarchical)
     if len(sub) == 0:
         return None, float("inf"), 0, n_wl
-    i, e, nf = dse_search_grid(sub, wl, constraints, c, interpret,
-                               shard=shard, carry_edp=carry_edp)
+    i, e, nf = dse_search_grid(
+        sub, wl, constraints, c, interpret, shard=shard,
+        carry_edp=carry[1] if carry[0] is not None else None)
     return (sub[i] if i >= 0 else None), e, nf, n_wl
 
 
+# Every chunk evaluator: (chunk, wl, constraints, c, hierarchical,
+# interpret, shard, carry) -> (best row or None, its EDP, n_feasible, n_wl),
+# where `carry` is the running (row, edp) best of the earlier chunks.
 EDP_CHUNK_ENGINES = {"python": _edp_chunk_python, "numpy": _edp_chunk_numpy,
-                     "jax": _edp_chunk_jax}
+                     "jax": _edp_chunk_jax, "pallas": _edp_chunk_pallas}
+ENGINES = tuple(sorted(EDP_CHUNK_ENGINES))
 
 
 def _rt_fp(tag, wl, constraints, engine, c, interpret, shard, chunk_size,
@@ -1137,25 +1010,10 @@ def _rt_fp(tag, wl, constraints, engine, c, interpret, shard, chunk_size,
                         shard=shard, chunk=chunk_size, **extra)
 
 
-def _edp_chunk_thunks(chunk, wl, constraints, c, hierarchical, interpret,
-                      shard, best):
-    """Byte-identical per-engine evaluations of one streamed EDP chunk for
-    the resilient runtime's retry / fallback / quarantine guard."""
-    def pallas():
-        carry = best[1] if best[0] is not None else None
-        return _edp_chunk_pallas(chunk, wl, constraints, c, hierarchical,
-                                 interpret, shard, carry)
-
-    thunks = {"pallas": pallas}
-    for eng, fn in EDP_CHUNK_ENGINES.items():
-        thunks[eng] = functools.partial(fn, chunk, wl, constraints, c,
-                                        hierarchical, interpret, shard)
-    return thunks
-
-
 def _search_streamed(grid, wl, constraints, engine, hierarchical, c,
                      interpret, shard, chunk_size, rt=None) -> SearchResult:
-    """Chunked (and optionally sharded) min-EDP driver, any engine."""
+    """Min-EDP driver over a materialized grid, any engine (one-shot is
+    the single-chunk case; `shard=` fans each chunk out)."""
     t0 = time.perf_counter()
     n = len(grid)
     cs = int(chunk_size) if chunk_size else max(n, 1)
@@ -1175,21 +1033,15 @@ def _search_streamed(grid, wl, constraints, engine, hierarchical, c,
     for u, chunk in enumerate(_iter_chunks(grid, cs)):
         if u < start:
             continue
+        # Byte-identical per-engine evaluations of the chunk: the runtime
+        # guard falls back along them.
+        thunks = {eng: functools.partial(fn, chunk, wl, constraints, c,
+                                         hierarchical, interpret, shard, best)
+                  for eng, fn in EDP_CHUNK_ENGINES.items()}
         if rt is not None:
-            row, e, cf, cw = rt.eval_unit(
-                engine, _edp_chunk_thunks(chunk, wl, constraints, c,
-                                          hierarchical, interpret, shard,
-                                          best))
-        elif engine == "pallas":
-            # The kernel folds the carried best into its own reduction
-            # (carry wins ties), so per-chunk launches compose on-device.
-            carry = best[1] if best[0] is not None else None
-            row, e, cf, cw = _edp_chunk_pallas(chunk, wl, constraints, c,
-                                               hierarchical, interpret,
-                                               shard, carry)
+            row, e, cf, cw = rt.eval_unit(engine, thunks)
         else:
-            row, e, cf, cw = EDP_CHUNK_ENGINES[engine](
-                chunk, wl, constraints, c, hierarchical, interpret, shard)
+            row, e, cf, cw = thunks[engine]()
         nf += cf
         n_wl += cw
         best = merge_running_best(best, (row, e))
@@ -1202,7 +1054,7 @@ def _search_streamed(grid, wl, constraints, engine, hierarchical, c,
 
 
 def _pareto_chunk_python(chunk, wl, constraints, c, hierarchical, interpret,
-                         shard, objectives):
+                         shard, objectives, carry_rows):
     cands = []
     nf = n_wl = 0
     for part in _host_shards(chunk, shard):
@@ -1211,11 +1063,11 @@ def _pareto_chunk_python(chunk, wl, constraints, c, hierarchical, interpret,
         cands += list(rows)
         nf += f
         n_wl += nw
-    return np.asarray(cands, np.int64).reshape(-1, 5), nf, n_wl
+    return np.asarray(cands, np.int64).reshape(-1, 5), nf, n_wl, 0
 
 
 def _pareto_chunk_numpy(chunk, wl, constraints, c, hierarchical, interpret,
-                        shard, objectives):
+                        shard, objectives, carry_rows):
     cands = []
     nf = n_wl = 0
     for part in _host_shards(chunk, shard):
@@ -1229,8 +1081,8 @@ def _pareto_chunk_numpy(chunk, wl, constraints, c, hierarchical, interpret,
         nf += f
         cands.append(front)
     if not cands:
-        return np.zeros((0, 5), np.int64), nf, n_wl
-    return np.concatenate(cands, axis=0), nf, n_wl
+        return np.zeros((0, 5), np.int64), nf, n_wl, 0
+    return np.concatenate(cands, axis=0), nf, n_wl, 0
 
 
 def _jax_sharded_pareto_mask(fn, sub, cons_vec, shard):
@@ -1249,20 +1101,20 @@ def _jax_sharded_pareto_mask(fn, sub, cons_vec, shard):
 
 
 def _pareto_chunk_jax(chunk, wl, constraints, c, hierarchical, interpret,
-                      shard, objectives):
+                      shard, objectives, carry_rows):
     import jax.numpy as jnp
     sub, n_wl = _prefiltered(chunk, wl, constraints, c, hierarchical)
     if len(sub) == 0:
-        return np.zeros((0, 5), np.int64), 0, n_wl
+        return np.zeros((0, 5), np.int64), 0, n_wl, 0
     gemms, scalars = workload_statics(wl, c)
     fn = _jax_pareto_fn(gemms, scalars, c, objectives)
     cons_vec = _constraint_vec(constraints)
     if shard is not None and int(shard) > 1:
         mask, nf = _jax_sharded_pareto_mask(fn, sub, cons_vec, shard)
-        return sub[mask], nf, n_wl
+        return sub[mask], nf, n_wl, 0
     cols, valid = _padded_candidate_cols(sub, JAX_PARETO_CHUNK)
     mask, nf = fn(jnp.asarray(cols), jnp.asarray(valid), cons_vec)
-    return sub[np.asarray(mask)[:len(sub)]], int(nf), n_wl
+    return sub[np.asarray(mask)[:len(sub)]], int(nf), n_wl, 0
 
 
 def _pallas_front_points(rows, wl, c, interpret, objectives):
@@ -1278,12 +1130,14 @@ def _pallas_front_points(rows, wl, c, interpret, objectives):
 
 def _pareto_chunk_pallas(chunk, wl, constraints, c, hierarchical, interpret,
                          shard, objectives, carry_rows):
+    """The kernel drops candidates a carried front point strictly
+    dominates, so per-chunk emissions stay frontier-sized."""
     from repro.kernels.ops import dse_pareto_multi
     sub, n_wl = _prefiltered(chunk, wl, constraints, c, hierarchical)
     if len(sub) == 0:
         return np.zeros((0, 5), np.int64), 0, n_wl, 0
     carry_points = None
-    if carry_rows is not None and len(carry_rows):
+    if len(carry_rows):
         carry_points = [_pallas_front_points(carry_rows, wl, c, interpret,
                                              objectives)]
     (idx, nf, n_over), = dse_pareto_multi(sub, [wl], [constraints], c,
@@ -1293,29 +1147,15 @@ def _pareto_chunk_pallas(chunk, wl, constraints, c, hierarchical, interpret,
     return sub[idx], nf, n_wl, n_over
 
 
+# Every chunk evaluator: (chunk, wl, constraints, c, hierarchical,
+# interpret, shard, objectives, carry_rows) -> (candidate rows, n_feasible,
+# n_wl, n_overflow), where `carry_rows` is the running front of the earlier
+# chunks; the host engines report no overflow.
 PARETO_CHUNK_ENGINES = {"python": _pareto_chunk_python,
                         "numpy": _pareto_chunk_numpy,
-                        "jax": _pareto_chunk_jax}
-
-
-def _pareto_chunk_thunks(chunk, wl, constraints, c, hierarchical, interpret,
-                         shard, objectives, run_rows):
-    """Per-engine streamed-frontier chunk evaluations, normalized to
-    (cand_rows, n_feasible, n_wl, n_overflow) for the runtime guard."""
-    def pallas():
-        return _pareto_chunk_pallas(chunk, wl, constraints, c, hierarchical,
-                                    interpret, shard, objectives, run_rows)
-
-    def host(eng):
-        cand, cf, cw = PARETO_CHUNK_ENGINES[eng](
-            chunk, wl, constraints, c, hierarchical, interpret, shard,
-            objectives)
-        return cand, cf, cw, 0
-
-    thunks = {"pallas": pallas}
-    for eng in PARETO_CHUNK_ENGINES:
-        thunks[eng] = functools.partial(host, eng)
-    return thunks
+                        "jax": _pareto_chunk_jax,
+                        "pallas": _pareto_chunk_pallas}
+PARETO_ENGINES = tuple(sorted(PARETO_CHUNK_ENGINES))
 
 
 def _empty_run_state():
@@ -1329,8 +1169,8 @@ def _merge_running_front(run_rows, run_met, cand_rows, wl, constraints, c,
     """Fold one chunk/shard's candidate rows into the bounded running
     frontier: refine the candidates through the float64 reference model,
     then keep the non-dominated union (`pareto.merge_fronts` — exact ties
-    kept, so duplicate grid rows survive streaming like they survive the
-    one-shot sweep). The carried state stays frontier-sized: a strictly
+    kept, so duplicate grid rows survive any chunking like they survive a
+    single chunk). The carried state stays frontier-sized: a strictly
     dominated point can never re-enter, so dropping it is exact."""
     from .pareto import merge_fronts
     front_c, met_c, _ = _pareto_from_rows(cand_rows, wl, constraints, c,
@@ -1351,7 +1191,8 @@ def _merge_running_front(run_rows, run_met, cand_rows, wl, constraints, c,
 def _pareto_streamed(grid, wl, constraints, engine, hierarchical, c,
                      interpret, objectives, shard, chunk_size, rt=None
                      ) -> ParetoResult:
-    """Chunked (and optionally sharded) frontier driver, any engine."""
+    """Frontier driver over a materialized grid, any engine (one-shot is
+    the single-chunk case; `shard=` fans each chunk out)."""
     t0 = time.perf_counter()
     n = len(grid)
     cs = int(chunk_size) if chunk_size else max(n, 1)
@@ -1372,20 +1213,14 @@ def _pareto_streamed(grid, wl, constraints, engine, hierarchical, c,
     for u, chunk in enumerate(_iter_chunks(grid, cs)):
         if u < start:
             continue
+        thunks = {eng: functools.partial(fn, chunk, wl, constraints, c,
+                                         hierarchical, interpret, shard,
+                                         objectives, run_rows)
+                  for eng, fn in PARETO_CHUNK_ENGINES.items()}
         if rt is not None:
-            cand, cf, cw, co = rt.eval_unit(
-                engine, _pareto_chunk_thunks(chunk, wl, constraints, c,
-                                             hierarchical, interpret, shard,
-                                             objectives, run_rows))
-        elif engine == "pallas":
-            cand, cf, cw, co = _pareto_chunk_pallas(
-                chunk, wl, constraints, c, hierarchical, interpret, shard,
-                objectives, run_rows)
+            cand, cf, cw, co = rt.eval_unit(engine, thunks)
         else:
-            cand, cf, cw = PARETO_CHUNK_ENGINES[engine](
-                chunk, wl, constraints, c, hierarchical, interpret, shard,
-                objectives)
-            co = 0
+            cand, cf, cw, co = thunks[engine]()
         nf += cf
         n_wl += cw
         n_over += co
@@ -3031,8 +2866,9 @@ def search(wl: Workload, constraints: Constraints = Constraints(), *,
       chunk_size: stream the grid through the engine in chunks of this
         many candidates, carrying a running argmin / bounded frontier
         across chunks — peak memory follows the chunk, not the grid.
-        Any (shard, chunk_size) combination is byte-identical to the
-        one-shot sweep (tests/test_sharded_search.py).
+        None (the default) is a single chunk, the one-shot sweep; any
+        (shard, chunk_size) combination is byte-identical to it
+        (tests/test_sharded_search.py).
       factorized: evaluate the grid as a *product space* from per-GEMM
         axis factor tables (core.factorized) instead of per-point model
         runs — byte-identical results at a fraction of the work whenever
@@ -3209,29 +3045,15 @@ def _search_impl(wl, constraints, engine, grid, n_z, hierarchical, c,
         raise ValueError("space= requires factorized=True (pass grid= for "
                          "materialized candidate sets)")
     grid = _full_grid(n_z) if grid is None else _check_grid(grid)
-    # A runtime routes through the streamed drivers even one-shot: the
-    # single-chunk streamed sweep is byte-identical to the one-shot path
-    # (tests/test_sharded_search.py), and it is where the unit guard and
-    # the checkpoint cursor live.
-    streamed = (shard is not None or chunk_size is not None
-                or rt is not None)
     if objective == "edp":
-        if streamed:
-            return _search_streamed(grid, wl, constraints, engine,
-                                    hierarchical, c, interpret, shard,
-                                    chunk_size, rt)
-        return ENGINES[engine](grid, wl, constraints, c, hierarchical,
-                               interpret)
+        return _search_streamed(grid, wl, constraints, engine, hierarchical,
+                                c, interpret, shard, chunk_size, rt)
     if objective != "pareto":
         raise ValueError(f"unknown objective {objective!r}; "
                          f"pick 'edp' or 'pareto'")
     metrics = _check_pareto_metrics(engine, pareto_metrics)
-    if streamed:
-        return _pareto_streamed(grid, wl, constraints, engine, hierarchical,
-                                c, interpret, metrics, shard, chunk_size,
-                                rt)
-    return PARETO_ENGINES[engine](grid, wl, constraints, c, hierarchical,
-                                  interpret, metrics)
+    return _pareto_streamed(grid, wl, constraints, engine, hierarchical, c,
+                            interpret, metrics, shard, chunk_size, rt)
 
 
 def _union_prefiltered(chunk, wls, names, cons_for, c, hierarchical):
@@ -3253,9 +3075,10 @@ def _union_prefiltered(chunk, wls, names, cons_for, c, hierarchical):
 def _workloads_pallas_streamed(wls, names, cons_for, grid, hierarchical, c,
                                interpret, objective, metrics, shard,
                                chunk_size):
-    """Chunked/sharded batched driver: the per-chunk fused launch still
-    covers all W workloads at once; per-workload carries (best EDP /
-    running front) ride between launches."""
+    """Batched pallas driver over a materialized grid: each chunk (the
+    whole grid when `chunk_size` is None) is one fused launch covering all
+    W workloads; per-workload carries (best EDP / running front) ride
+    between launches."""
     from repro.kernels.ops import dse_pareto_multi, dse_search_multi
     t0 = time.perf_counter()
     n = len(grid)
@@ -3507,51 +3330,8 @@ def _search_workloads_impl(wls, constraints, engine, grid, n_z,
                          "materialized candidate sets)")
     if grid is None:
         grid = _full_grid(n_z)
-    grid = np.asarray(grid)
-
-    names = list(wls)
-    if objective == "pareto":
-        metrics = _check_pareto_metrics(engine, pareto_metrics)
-    else:
-        metrics = None
-    if shard is not None or chunk_size is not None:
-        return _workloads_pallas_streamed(wls, names, cons_for, grid,
-                                          hierarchical, c, interpret,
-                                          objective, metrics, shard,
-                                          chunk_size)
-
-    t0 = time.perf_counter()
-    sub = _union_prefiltered(grid, wls, names, cons_for, c, hierarchical)
-    n_wl = len(sub)
-
-    if objective == "pareto":
-        if n_wl == 0:
-            return {name: _pareto_result(sub, 0, wls[name], cons_for(name),
-                                         c, metrics, len(grid), 0, t0)
-                    for name in names}
-        from repro.kernels.ops import dse_pareto_multi
-        per_wl = dse_pareto_multi(sub, [wls[n] for n in names],
-                                  [cons_for(n) for n in names], c, interpret,
-                                  objectives=metrics)
-        wall = time.perf_counter() - t0
-        out = {}
-        for name, (cand_idx, nf, n_over) in zip(names, per_wl):
-            r = _pareto_result(sub[cand_idx], nf, wls[name], cons_for(name),
-                               c, metrics, len(grid), n_wl, t0)
-            r.wall_time_s = wall
-            r.n_overflow = n_over
-            out[name] = r
-        return out
-
-    from repro.kernels.ops import dse_search_multi
-    if n_wl == 0:
-        wall = time.perf_counter() - t0
-        return {name: _make_result(None, 0, wls[name], c, len(grid), 0, wall)
-                for name in names}
-    best, _, nf = dse_search_multi(sub, [wls[n] for n in names],
-                                   [cons_for(n) for n in names], c,
-                                   interpret)
-    wall = time.perf_counter() - t0
-    return {name: _make_result(sub[i] if i >= 0 else None, f, wls[name], c,
-                               len(grid), n_wl, wall)
-            for name, i, f in zip(names, best, nf)}
+    metrics = (_check_pareto_metrics(engine, pareto_metrics)
+               if objective == "pareto" else None)
+    return _workloads_pallas_streamed(wls, list(wls), cons_for, grid,
+                                      hierarchical, c, interpret, objective,
+                                      metrics, shard, chunk_size)

@@ -2,6 +2,8 @@
 jax, pallas) must return identical results — best_cfg, n_feasible, and the
 finalized metrics — on every paper workload, flat and hierarchical, plus the
 zero-feasible edge case and the batched multi-workload path."""
+import importlib
+
 import numpy as np
 import pytest
 
@@ -105,6 +107,29 @@ def test_search_workloads_per_workload_constraints_and_hierarchy():
 def test_search_rejects_unknown_engine():
     with pytest.raises(ValueError, match="unknown engine"):
         search(load("deit-t"), engine="cuda")
+
+
+@pytest.mark.parametrize("name, engines", [
+    ("ENGINES", {"python", "numpy", "jax", "pallas"}),
+    ("PARETO_ENGINES", {"python", "numpy", "jax", "pallas"}),
+    ("FACTORIZED_ENGINES", {"numpy", "jax", "pallas"}),
+    ("ROBUST_ENGINES", {"numpy", "jax", "pallas"}),
+])
+def test_public_engine_name_sets(name, engines):
+    # Parametrised tests across the suite take their engine lists from
+    # these names: a wrong set would drop cases instead of failing one.
+    names = getattr(importlib.import_module("repro.core.search"), name)
+    assert set(names) == engines and len(names) == len(engines)
+
+
+@pytest.mark.parametrize("engine, kwargs, match", [
+    ("python", {"factorized": True}, "factorized"),
+    ("cuda", {}, "unknown engine"),
+    ("cuda", {"objective": "pareto"}, "unknown engine"),
+])
+def test_engines_outside_the_sets_are_refused(engine, kwargs, match):
+    with pytest.raises(ValueError, match=match):
+        search(load("deit-t"), engine=engine, n_z=2, **kwargs)
 
 
 def test_dxpta_search_engine_dispatch():
