@@ -358,23 +358,32 @@ def slab_indices_batch(radices, ranges_list) -> np.ndarray:
     per-axis starts — so slabs are grouped by width shape and each group
     expands as one (B, P) broadcast add instead of B separate little
     5-D broadcasts. The bound-guided evaluation batches are thousands of
-    near-identical fine slabs, which is exactly this shape."""
+    near-identical fine slabs, which is exactly this shape.
+
+    Takes a (B, 5, 2) digit-range array or a sequence of range tuples;
+    the grouping, bases and expansion are array operations, with one
+    Python step per distinct width shape, never per slab."""
     radices = tuple(int(r) for r in radices)
-    strides = [1] * 5
+    arr = np.asarray(ranges_list, np.int64).reshape(-1, 5, 2)
+    if not len(arr):
+        return np.zeros(0, np.int64)
+    strides = np.ones(5, np.int64)
     for i in range(3, -1, -1):
         strides[i] = strides[i + 1] * radices[i + 1]
-    groups: Dict[Tuple[int, ...], list] = {}
-    for ranges in ranges_list:
-        widths = tuple(hi - lo for lo, hi in ranges)
-        base = sum(lo * s for (lo, _), s in zip(ranges, strides))
-        groups.setdefault(widths, []).append(base)
+    widths = arr[:, :, 1] - arr[:, :, 0]
+    bases = arr[:, :, 0] @ strides
+    # A width is in [0, radix]: mixed radix (radix + 1) keys each shape.
+    key_radix = np.ones(5, np.int64)
+    for i in range(3, -1, -1):
+        key_radix[i] = key_radix[i + 1] * (radices[i + 1] + 1)
+    _, inverse = np.unique(widths @ key_radix, return_inverse=True)
+    order = np.argsort(inverse, kind="stable")
+    groups = np.split(order, np.cumsum(np.bincount(inverse))[:-1])
     parts = []
-    for widths, bases in groups.items():
-        pattern = slab_indices(radices, tuple((0, w) for w in widths))
-        parts.append((np.asarray(bases, np.int64)[:, None]
-                      + pattern[None, :]).reshape(-1))
-    if not parts:
-        return np.zeros(0, np.int64)
+    for rows in groups:
+        pattern = slab_indices(
+            radices, tuple((0, int(w)) for w in widths[rows[0]]))
+        parts.append((bases[rows][:, None] + pattern[None, :]).reshape(-1))
     return np.sort(np.concatenate(parts))
 
 

@@ -2000,17 +2000,25 @@ def _bnb_batch_slices(sizes: np.ndarray, max_points: Optional[int] = None):
     """Consecutive [s, e) leaf slices of at most `max_points` total points
     (default BNB_BATCH; a lone bigger leaf still forms its own slice)."""
     cap = BNB_BATCH if max_points is None else int(max_points)
+    cum = np.concatenate([[0], np.cumsum(np.asarray(sizes, np.int64))])
+    n = len(cum) - 1
     out = []
     s = 0
-    pts = 0
-    for j, n in enumerate(sizes):
-        if j > s and pts + int(n) > cap:
-            out.append((s, j))
-            s, pts = j, 0
-        pts += int(n)
-    if s < len(sizes):
-        out.append((s, len(sizes)))
+    while s < n:
+        # Greedy: the longest run from s whose points fit the cap, and at
+        # least the one leaf at s.
+        e = int(np.searchsorted(cum, cum[s] + cap, side="right")) - 1
+        e = max(e, s + 1)
+        out.append((s, e))
+        s = e
     return out
+
+
+def _bnb_coarse_batch(ranges_list) -> bool:
+    """Whether a leaf batch holds a slab above BNB_FINE points: the
+    pallas drivers' launch-form test (decoded span list, not gathered
+    survivor rows)."""
+    return bool((_slab_sizes(ranges_list) > BNB_FINE).any())
 
 
 def _bnb_leaf_items(fspace, ranges, chunk_size):
@@ -2039,11 +2047,10 @@ def _bnb_eval_edp(engine, fspace, wl, constraints, c, interpret,
     survivor rows and reuse the grid-operand kernel, one bucketed launch
     per chunk. Either way only survivor-sized data ever exists on the
     host."""
-    from .factorized import slab_indices_batch, slab_size
+    from .factorized import slab_indices_batch
     best = (-1, float("inf"))
     nf = 0
-    if engine == "pallas" and any(slab_size(r) > BNB_FINE
-                                  for r in ranges_list):
+    if engine == "pallas" and _bnb_coarse_batch(ranges_list):
         from repro.kernels.ops import dse_search_spans_factorized
         items = [it for ranges in ranges_list
                  for it in _bnb_leaf_items(fspace, ranges, chunk_size)]
@@ -2076,15 +2083,14 @@ def _bnb_eval_pareto(engine, fspace, wl, constraints, c, interpret,
                      ranges_list, shard, chunk_size, objectives, run_rows):
     """(cand gidx array, n_feasible, n_overflow) over one batch of leaf
     slabs; launch forms as in `_bnb_eval_edp`."""
-    from .factorized import slab_indices_batch, slab_size
+    from .factorized import slab_indices_batch
     cands = []
     nf = n_over = 0
     carry_points = None
     if engine == "pallas" and len(run_rows):
         carry_points = [_pallas_front_points(run_rows, wl, c, interpret,
                                              objectives)]
-    if engine == "pallas" and any(slab_size(r) > BNB_FINE
-                                  for r in ranges_list):
+    if engine == "pallas" and _bnb_coarse_batch(ranges_list):
         from repro.kernels.ops import dse_pareto_spans_factorized
         for ranges in ranges_list:
             items = _bnb_leaf_items(fspace, ranges, chunk_size)
