@@ -1,7 +1,7 @@
 """Architecture registry: `--arch <id>` resolves here."""
 from .archs import ARCHS, reduced
-from .base import (SHAPES, SHAPES_BY_NAME, MLAConfig, MoEConfig, ModelConfig,
-                   ShapeConfig, SSMConfig)
+from .base import (SHAPES, SHAPES_BY_NAME, LinearAttnConfig, MLAConfig,
+                   MoEConfig, ModelConfig, ShapeConfig, SSMConfig)
 
 
 def get_config(name: str) -> ModelConfig:
@@ -14,6 +14,6 @@ def list_archs():
     return sorted(ARCHS)
 
 
-__all__ = ["ARCHS", "SHAPES", "SHAPES_BY_NAME", "MLAConfig", "MoEConfig",
-           "ModelConfig", "SSMConfig", "ShapeConfig", "get_config",
-           "list_archs", "reduced"]
+__all__ = ["ARCHS", "SHAPES", "SHAPES_BY_NAME", "LinearAttnConfig",
+           "MLAConfig", "MoEConfig", "ModelConfig", "SSMConfig",
+           "ShapeConfig", "get_config", "list_archs", "reduced"]

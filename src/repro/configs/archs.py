@@ -8,7 +8,8 @@ from __future__ import annotations
 
 import dataclasses
 
-from .base import MLAConfig, MoEConfig, ModelConfig, SSMConfig
+from .base import (LinearAttnConfig, MLAConfig, MoEConfig, ModelConfig,
+                   SSMConfig)
 
 # --- llava-next-34b [vlm] — hf:llava-hf/llava-v1.6 (34B backbone) ---------
 LLAVA_NEXT_34B = ModelConfig(
@@ -75,6 +76,24 @@ SEAMLESS_M4T_MEDIUM = ModelConfig(
 RWKV6_7B = ModelConfig(
     name="rwkv6-7b", family="rwkv", n_layers=32, d_model=4096,
     n_heads=64, n_kv_heads=64, head_dim=64, d_ff=14336, vocab=65536)
+
+# --- kimi-linear-48b-a3b [KDA + MLA + MoE] --------------------------------
+# hf:moonshotai/Kimi-Linear-48B-A3B-Instruct/blob/main/config.json (KDA:
+# arXiv:2510.26692). 20 KDA layers beside 7 global MLA layers (4, 8, ...,
+# 24, 27; no q-LoRA, NoPE), layer 1 a dense FFN, then 256 experts top-8.
+# Not in ARCHS: the design-space search prices it through `workload_for`
+# and needs no JAX implementation of the model.
+KIMI_LINEAR_48B = ModelConfig(
+    name="kimi-linear-48b-a3b", family="kda_mla_moe", n_layers=27,
+    d_model=2304, n_heads=32, n_kv_heads=32, d_ff=9216, vocab=163840,
+    mla=MLAConfig(q_lora_rank=0, kv_lora_rank=512, rope_head_dim=64,
+                  nope_head_dim=128, v_head_dim=128),
+    moe=MoEConfig(n_experts=256, top_k=8, d_expert=1024, n_shared=1,
+                  first_dense_layers=1, d_shared=1024, route_scale=2.446),
+    linear_attn=LinearAttnConfig(
+        kda_layers=(1, 2, 3, 5, 6, 7, 9, 10, 11, 13, 14, 15, 17, 18, 19,
+                    21, 22, 23, 25, 26),
+        n_heads=32, head_dim=128, conv_kernel=4))
 
 
 ARCHS = {

@@ -2,7 +2,8 @@
 
 One `ModelConfig` covers every assigned architecture family (dense GQA,
 sliding-window, MLA+MoE, plain MoE, Mamba2 hybrid, RWKV6, enc-dec, VLM
-backbone). Family-specific fields are ignored by other families. Every arch
+backbone) and the KDA linear-attention + MLA + MoE hybrid.
+Family-specific fields are ignored by other families. Every arch
 module in repro.configs exposes:
 
     CONFIG            — the full published configuration
@@ -15,6 +16,9 @@ from __future__ import annotations
 
 import dataclasses
 from typing import Optional, Tuple
+
+
+MOE_FAMILIES = ("moe", "mla_moe", "kda_mla_moe")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -50,10 +54,20 @@ class SSMConfig:
 
 
 @dataclasses.dataclass(frozen=True)
+class LinearAttnConfig:
+    """Kimi Delta Attention layers (arXiv:2510.26692) of a kda_mla_moe
+    model; every other layer is a global MLA layer."""
+    kda_layers: Tuple[int, ...] = ()   # 1-indexed, as published
+    n_heads: int = 32
+    head_dim: int = 128                # d_k = d_v
+    conv_kernel: int = 4               # short convolution on q, k and v
+
+
+@dataclasses.dataclass(frozen=True)
 class ModelConfig:
     name: str = "model"
-    family: str = "dense"        # dense | moe | mla_moe | hybrid_ssm | rwkv
-                                 # | encdec | vlm
+    family: str = "dense"        # dense | moe | mla_moe | kda_mla_moe
+                                 # | hybrid_ssm | rwkv | encdec | vlm
     n_layers: int = 12
     d_model: int = 512
     n_heads: int = 8
@@ -76,6 +90,7 @@ class ModelConfig:
     moe: Optional[MoEConfig] = None
     mla: Optional[MLAConfig] = None
     ssm: Optional[SSMConfig] = None
+    linear_attn: Optional[LinearAttnConfig] = None
     # enc-dec
     enc_layers: int = 0
     dec_layers: int = 0
@@ -90,6 +105,13 @@ class ModelConfig:
         return self.head_dim or self.d_model // self.n_heads
 
     @property
+    def n_kda_layers(self) -> int:
+        """Linear-attention layers of a kda_mla_moe model (0 otherwise)."""
+        if self.family != "kda_mla_moe" or self.linear_attn is None:
+            return 0
+        return len(self.linear_attn.kda_layers)
+
+    @property
     def is_attention_free(self) -> bool:
         return self.family == "rwkv"
 
@@ -98,8 +120,9 @@ class ModelConfig:
         """Sub-quadratic path exists (long_500k eligibility, DESIGN.md §5)."""
         if self.family in ("hybrid_ssm", "rwkv"):
             return True
-        # SWA-dominant: bounded KV on all/most layers.
-        return self.sliding_window > 0
+        # Bounded state on most layers: a sliding window, or KDA layers
+        # beside a few global ones.
+        return self.sliding_window > 0 or self.n_kda_layers > 0
 
     def param_count(self) -> int:
         """Approximate parameter count (embeddings + blocks), for 6ND math."""
@@ -120,14 +143,15 @@ class ModelConfig:
                 + self.n_heads * m.v_head_dim * d
         ffn_dense = 3 * d * self.d_ff
         layers = self.enc_layers + self.dec_layers or self.n_layers
-        if self.family in ("moe", "mla_moe") and self.moe:
+        if self.family in MOE_FAMILIES and self.moe:
             mo = self.moe
             moe_ffn = 3 * d * mo.d_expert * mo.n_experts \
                 + 3 * d * (mo.d_shared or mo.d_expert) * mo.n_shared \
                 + d * mo.n_experts
             n_moe = layers - mo.first_dense_layers
-            return emb + mo.first_dense_layers * (attn + ffn_dense) \
-                + n_moe * (attn + moe_ffn)
+            return emb + (layers - self.n_kda_layers) * attn \
+                + self._kda_params() \
+                + mo.first_dense_layers * ffn_dense + n_moe * moe_ffn
         if self.family == "hybrid_ssm":
             s = self.ssm or SSMConfig()
             d_in = s.expand * d
@@ -141,9 +165,24 @@ class ModelConfig:
                 + self.dec_layers * (attn + cross + ffn_dense)
         return emb + layers * (attn + ffn_dense)
 
+    def _kda_params(self) -> int:
+        """The KDA layers' weights, each layer's: the q, k, v projections
+        and their short convolutions, the decay and output gates'
+        low-rank projections (d -> head_dim -> H head_dim) with beta's
+        (d -> H), the output projection. The per-channel decay parameters
+        and norms are not counted."""
+        if not self.n_kda_layers:
+            return 0
+        la, d = self.linear_attn, self.d_model
+        hk = la.n_heads * la.head_dim
+        return self.n_kda_layers * (
+            d * 3 * hk + la.conv_kernel * 3 * hk
+            + d * (2 * la.head_dim + la.n_heads) + 2 * la.head_dim * hk
+            + hk * d)
+
     def active_param_count(self) -> int:
         """Activated params per token (MoE: top-k + shared only)."""
-        if self.family not in ("moe", "mla_moe") or not self.moe:
+        if self.family not in MOE_FAMILIES or not self.moe:
             return self.param_count()
         full = self.param_count()
         mo = self.moe

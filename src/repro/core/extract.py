@@ -12,20 +12,31 @@ Per-family GEMM decomposition notes (DESIGN.md §5):
     (clamped to [1, min(E, T*k)]), and the T*k slots fill those D in
     integer rows (`expert_rows`); weights stream D experts a MoE layer;
   * MLA low-rank compress/expand are GEMMs;
+  * kda_mla_moe (Kimi Linear) prices each layer by its own kind: the
+    published KDA layers as a gated delta-rule step (`_kda_gemms`, decode
+    only), the rest as MLA layers;
   * decode workloads have M = batch (tiny-M GEMMs -> poor DDot-array
     utilization; visible in the DSE results), and each step also reads the
-    KV cache off chip (`_kv_cache_bytes`).
+    KV cache off chip (`_kv_cache_bytes`) and reads and writes the
+    recurrent state of KDA layers (`_state_bytes`).
+
+`workload_for` runs inside the span `extract`, whose stats are the GEMM
+rows by layer kind (`rows_latent`: MLA; `rows_linear`: KDA; `rows_moe`:
+router and experts; `rows_other`: the rest) and one decode step's
+`cache_bytes` and `state_bytes` (0 outside decode).
 """
 from __future__ import annotations
 
 from typing import List
 
-from repro.configs.base import ModelConfig, MoEConfig, ShapeConfig
+from repro.configs.base import (MOE_FAMILIES, ModelConfig, MoEConfig,
+                                ShapeConfig)
 from repro.tracing import span
 
 from .workload import Gemm, Workload
 
-MOE_FAMILIES = ("moe", "mla_moe")
+MLA_FAMILIES = ("mla_moe", "kda_mla_moe")
+ROW_KINDS = ("latent", "linear", "moe", "other")
 
 
 def experts_touched(moe: MoEConfig, tokens: int) -> int:
@@ -91,6 +102,28 @@ def _mla_gemms(cfg, n_ctx, bt, batch, layers, gemms: List[Gemm],
     gemms.append(Gemm(bt, h * m.v_head_dim, d, layers))
 
 
+def _kda_gemms(cfg, bt, batch, layers, gemms: List[Gemm]):
+    """One decode step of Kimi Delta Attention (arXiv:2510.26692) in
+    `layers` layers, bt = batch rows: the q, k, v projections; the decay
+    and output gates' down-projections with beta, which share their input
+    (d -> 2 d_k + H); the gates' up-projections (d_k -> H d_k); the state
+    products; the output projection. Per head the state S (d_k x d_v)
+    decays channel-wise, S' = Diag(alpha) S, and with
+    o = S'^T q + beta (k^T q)(v - S'^T k) and
+    S = S' + beta k (v - S'^T k)^T one (2, d_k, d_v) GEMM of [k; q]
+    against S' per head per sequence gives both products exactly. The
+    decay, the rank-1 update, the gates and the short convolution are
+    element-wise (`_elec_ops`)."""
+    la = cfg.linear_attn
+    d, dk, h = cfg.d_model, la.head_dim, la.n_heads
+    hk = h * dk
+    gemms.append(Gemm(bt, d, 3 * hk, layers))                  # q, k, v
+    gemms.append(Gemm(bt, d, 2 * dk + h, layers))              # gates, beta
+    gemms.append(Gemm(bt, dk, hk, 2 * layers))                 # gate ups
+    gemms.append(Gemm(2, dk, dk, layers * batch * h))          # [k; q] S'
+    gemms.append(Gemm(bt, hk, d, layers))                      # output
+
+
 def _ffn_gemms(cfg, bt, layers, gemms: List[Gemm]):
     gemms.append(Gemm(bt, cfg.d_model, cfg.d_ff, 2 * layers))  # wi + wg
     gemms.append(Gemm(bt, cfg.d_ff, cfg.d_model, layers))
@@ -138,8 +171,9 @@ def _rwkv_gemms(cfg, bt, layers, gemms: List[Gemm]):
     gemms.append(Gemm(bt, d, d, layers))               # channel-mix r
 
 
-def _elec_ops(cfg, n_ctx, bt, batch, layers, decode=False):
+def _elec_ops(cfg, n_ctx, bt, batch, layers, decode=False, linear=0):
     """Softmax / LN / activations / recurrences on the electronic unit.
+    `linear` of the `layers` are KDA layers, the rest attention layers.
 
     Every branch scales with the `layers` parameter, never `cfg.n_layers`:
     the two only coincide when the caller happens to pass the full depth,
@@ -161,7 +195,15 @@ def _elec_ops(cfg, n_ctx, bt, batch, layers, decode=False):
             max(s.chunk, 1) * 3 * layers                    # inter-chunk
         ops += bt * d_in * 2 * layers                       # conv + gates
     else:
-        ops += batch * cfg.n_heads * q_tokens * n_ctx * 3 * layers  # softmax
+        ops += batch * cfg.n_heads * q_tokens * n_ctx * 3 \
+            * (layers - linear)                             # softmax
+        if linear:
+            # KDA: the short convolution of q, k and v, the decay and the
+            # rank-1 state update, the gates and the gated output norm
+            la = cfg.linear_attn
+            hk = la.n_heads * la.head_dim
+            ops += bt * (la.conv_kernel * 3 * hk + 2 * hk * la.head_dim
+                         + 3 * hk) * linear
         if cfg.family in MOE_FAMILIES and cfg.moe:
             # activations at each layer's real width: the dense FFN of
             # the leading layers, then the T*k routed expert rows and the
@@ -202,15 +244,17 @@ def _weight_bytes(cfg, tokens, weight_bits=4):
 
 def _kv_cache_bytes(cfg, n_ctx, batch, act_bits=4):
     """KV cache one decode step reads off chip, at `act_bits`: MLA's
-    latent (kv_lora + rope values a token a layer); GQA's K and V of every
-    attention layer, window-bounded layers capped at their window (and
-    enc-dec's cross-attention K and V over its source); none for RWKV."""
+    latent (kv_lora + rope values a token an MLA layer); GQA's K and V of
+    every attention layer, window-bounded layers capped at their window
+    (and enc-dec's cross-attention K and V over its source); none for
+    RWKV."""
     fam = cfg.family
     if fam == "rwkv":
         return 0.0
-    if fam == "mla_moe":
+    if fam in MLA_FAMILIES:
         m = cfg.mla
-        values = n_ctx * cfg.n_layers * (m.kv_lora_rank + m.rope_head_dim)
+        values = n_ctx * (cfg.n_layers - cfg.n_kda_layers) \
+            * (m.kv_lora_rank + m.rope_head_dim)
     else:
         if fam == "hybrid_ssm":
             positions = n_ctx * (cfg.n_layers // cfg.ssm.attn_every)
@@ -225,8 +269,24 @@ def _kv_cache_bytes(cfg, n_ctx, batch, act_bits=4):
     return batch * values * act_bits / 8.0
 
 
+def _state_bytes(cfg, batch, act_bits=4):
+    """Recurrent state one decode step reads and writes off chip, at
+    `act_bits`: each KDA layer's H d_k d_v state and its short
+    convolutions' last (kernel - 1) inputs of q, k and v. No other
+    family's recurrent state is counted."""
+    n = cfg.n_kda_layers
+    if not n:
+        return 0.0
+    la = cfg.linear_attn
+    hk = la.n_heads * la.head_dim
+    values = n * (hk * la.head_dim + (la.conv_kernel - 1) * 3 * hk)
+    return 2 * batch * values * act_bits / 8.0
+
+
 def _build(cfg: ModelConfig, name, seq, batch, *, decode=False,
-           n_ctx=None, act_bits=4) -> Workload:
+           n_ctx=None, act_bits=4, stats=None) -> Workload:
+    """One forward pass (or one decode step). `stats`, when given, takes
+    the `extract` span's stats (module docstring)."""
     n_ctx = n_ctx or seq
     if cfg.n_prefix_embeds and cfg.family != "encdec":
         # VLM/audio prefix embeddings are real sequence positions: in
@@ -239,7 +299,12 @@ def _build(cfg: ModelConfig, name, seq, batch, *, decode=False,
             n_ctx += cfg.n_prefix_embeds
     bt = batch * seq
     gemms: List[Gemm] = []
+    kinds: List[str] = []             # the layer kind of each GEMM row
     fam = cfg.family
+
+    def tag(kind):
+        """Give `kind` to every row appended since the last tag."""
+        kinds.extend([kind] * (len(gemms) - len(kinds)))
 
     attn_layers = cfg.n_layers
     if fam == "encdec":
@@ -270,9 +335,20 @@ def _build(cfg: ModelConfig, name, seq, batch, *, decode=False,
         layers_for_elec = cfg.n_layers
     else:
         window, n_local, n_global = _attn_layers(cfg)
-        if fam == "mla_moe":
-            _mla_gemms(cfg, n_ctx, bt, batch, cfg.n_layers, gemms,
-                       decode=decode)
+        n_kda = cfg.n_kda_layers
+        if fam in MLA_FAMILIES:
+            if n_kda and not decode:
+                raise ValueError(
+                    f"{cfg.name}: prefill and training of KDA layers need "
+                    f"the chunked KDA form (arXiv:2412.06464), which has "
+                    f"no lowering yet; only decode is priced")
+            if cfg.n_layers > n_kda:
+                _mla_gemms(cfg, n_ctx, bt, batch, cfg.n_layers - n_kda,
+                           gemms, decode=decode)
+            tag("latent")
+            if n_kda:
+                _kda_gemms(cfg, bt, batch, n_kda, gemms)
+            tag("linear")
         else:
             if n_local:
                 _attn_gemms(cfg, n_ctx, bt, batch, n_local, gemms,
@@ -285,31 +361,42 @@ def _build(cfg: ModelConfig, name, seq, batch, *, decode=False,
             n_moe = cfg.n_layers - mo.first_dense_layers
             if mo.first_dense_layers:
                 _ffn_gemms(cfg, bt, mo.first_dense_layers, gemms)
+            tag("other")
             _moe_gemms(cfg, bt, n_moe, gemms)
+            tag("moe")
         else:
             _ffn_gemms(cfg, bt, cfg.n_layers, gemms)
         layers_for_elec = cfg.n_layers
 
     gemms.append(Gemm(bt, cfg.d_model, cfg.vocab, 1))   # LM head
+    tag("other")
 
-    elec = _elec_ops(cfg, n_ctx, bt, batch, layers_for_elec, decode)
+    elec = _elec_ops(cfg, n_ctx, bt, batch, layers_for_elec, decode,
+                     linear=cfg.n_kda_layers)
     wb = _weight_bytes(cfg, bt)
     max_act = bt * max(cfg.d_ff, 3 * cfg.d_model) * act_bits / 8.0
-    act_io = bt * cfg.d_model * 2 * act_bits / 8.0
-    if decode:
-        act_io += _kv_cache_bytes(cfg, n_ctx, batch, act_bits)
+    cache = _kv_cache_bytes(cfg, n_ctx, batch, act_bits) if decode else 0.0
+    state = _state_bytes(cfg, batch, act_bits) if decode else 0.0
+    act_io = bt * cfg.d_model * 2 * act_bits / 8.0 + cache + state
+    if stats is not None:
+        stats.update({f"rows_{k}": kinds.count(k) for k in ROW_KINDS},
+                     cache_bytes=cache, state_bytes=state)
     return Workload(name=name, gemms=tuple(gemms), elec_ops=elec,
                     weight_bytes=float(wb), act_io_bytes=float(act_io),
                     max_act_bytes=float(max_act), batch=batch)
 
 
-def prefill_workload(cfg: ModelConfig, seq: int, batch: int) -> Workload:
-    return _build(cfg, f"{cfg.name}-prefill{seq}b{batch}", seq, batch)
+def prefill_workload(cfg: ModelConfig, seq: int, batch: int,
+                     stats=None) -> Workload:
+    return _build(cfg, f"{cfg.name}-prefill{seq}b{batch}", seq, batch,
+                  stats=stats)
 
 
-def training_workload(cfg: ModelConfig, seq: int, batch: int) -> Workload:
+def training_workload(cfg: ModelConfig, seq: int, batch: int,
+                      stats=None) -> Workload:
     """Forward+backward ~ 3x forward GEMM MACs (standard accounting)."""
-    fwd = _build(cfg, f"{cfg.name}-train{seq}b{batch}", seq, batch)
+    fwd = _build(cfg, f"{cfg.name}-train{seq}b{batch}", seq, batch,
+                 stats=stats)
     gemms = tuple(Gemm(g.m, g.k, g.n, g.count * 3) for g in fwd.gemms)
     return Workload(name=fwd.name, gemms=gemms, elec_ops=fwd.elec_ops * 2,
                     weight_bytes=fwd.weight_bytes * 3,
@@ -318,7 +405,7 @@ def training_workload(cfg: ModelConfig, seq: int, batch: int) -> Workload:
 
 
 def serving_workload(cfg: ModelConfig, seq_len: int, batch: int,
-                     new_tokens: int) -> Workload:
+                     new_tokens: int, stats=None) -> Workload:
     """Decode of `new_tokens` tokens against a seq_len context: M = batch
     per GEMM per step, context-length score GEMMs, re-streamed (active)
     weights every step.
@@ -329,7 +416,7 @@ def serving_workload(cfg: ModelConfig, seq_len: int, batch: int,
     so the names must not collide.
     """
     one = _build(cfg, f"{cfg.name}-decode{seq_len}b{batch}n{new_tokens}",
-                 1, batch, decode=True, n_ctx=seq_len)
+                 1, batch, decode=True, n_ctx=seq_len, stats=stats)
     gemms = tuple(Gemm(g.m, g.k, g.n, g.count * new_tokens)
                   for g in one.gemms)
     return Workload(name=one.name, gemms=gemms,
@@ -346,15 +433,22 @@ def workload_for(cfg: ModelConfig, shape: ShapeConfig) -> Workload:
     decode length ("decode" kind only). Historically the decode length was
     hard-coded to 32 here, which silently gave every decode shape —
     `decode_32k` and `long_500k` alike — the same generation length; now
-    it threads through from the shape. Runs inside the span `extract`.
+    it threads through from the shape. Runs inside the span `extract`,
+    whose stats the lowering gives (module docstring).
     """
-    with span("extract"):
+    with span("extract") as sp:
+        stats = {}
         if shape.kind == "train":
-            return training_workload(cfg, shape.seq_len, shape.global_batch)
-        if shape.kind == "prefill":
-            return prefill_workload(cfg, shape.seq_len, shape.global_batch)
-        if shape.kind != "decode":
+            wl = training_workload(cfg, shape.seq_len, shape.global_batch,
+                                   stats)
+        elif shape.kind == "prefill":
+            wl = prefill_workload(cfg, shape.seq_len, shape.global_batch,
+                                  stats)
+        elif shape.kind == "decode":
+            wl = serving_workload(cfg, shape.seq_len, shape.global_batch,
+                                  new_tokens=shape.new_tokens, stats=stats)
+        else:
             raise ValueError(f"unknown shape kind {shape.kind!r}; pick "
                              f"'train', 'prefill' or 'decode'")
-        return serving_workload(cfg, shape.seq_len, shape.global_batch,
-                                new_tokens=shape.new_tokens)
+        sp.set_metadata(**stats)
+        return wl

@@ -292,6 +292,163 @@ def test_deepseek_v3_at_published_sizes():
     require_i32_dims(wl.gemm_array)
 
 
+def test_deepseek_v3_decode_32k_is_pinned_field_for_field():
+    # The kda_mla_moe branch shares `_build`, `_mla_gemms`, `_moe_gemms`
+    # and `_kv_cache_bytes` with mla_moe: DeepSeek-V3 at the assigned
+    # decode_32k shape (B 128, C 32,768, 32 tokens) keeps every field.
+    from repro.configs import SHAPES_BY_NAME, get_config
+    from repro.core.workload import Gemm, Workload
+
+    wl = workload_for(get_config("deepseek-v3-671b"),
+                      SHAPES_BY_NAME["decode_32k"])
+    rows = [(128, 7168, 1536, 1952), (128, 1536, 24576, 1952),
+            (128, 7168, 576, 1952), (128, 128, 512, 249856),
+            (128, 576, 32768, 249856), (128, 32768, 512, 249856),
+            (128, 512, 128, 249856), (128, 16384, 7168, 1952),
+            (128, 7168, 18432, 192), (128, 18432, 7168, 96),
+            (128, 7168, 256, 1856), (5, 7168, 2048, 59392),
+            (4, 7168, 2048, 876032), (5, 2048, 7168, 29696),
+            (4, 2048, 7168, 438016), (128, 7168, 2048, 3712),
+            (128, 2048, 7168, 1856), (128, 7168, 129280, 32)]
+    assert wl == Workload(
+        name="deepseek-v3-671b-decode32768b128n32",
+        gemms=tuple(Gemm(*g) for g in rows), elec_ops=3166431084544.0,
+        weight_bytes=10572929171456.0, act_io_bytes=2357966405632.0,
+        max_act_bytes=1376256.0, batch=128)
+
+
+# ---------------------------------------------------------------------------
+# kda_mla_moe (Kimi Linear): KDA layers beside MLA layers
+# ---------------------------------------------------------------------------
+
+def _kimi():
+    from repro.configs.archs import KIMI_LINEAR_48B
+    return KIMI_LINEAR_48B
+
+
+def test_kimi_linear_decode_at_published_widths():
+    # One decode step at long_500k (B 1, C 524,288), 32 steps. Layers:
+    # 20 KDA (H_k 32, d_k = d_v 128, conv 4), 7 MLA (H 32, no q-LoRA,
+    # nope 128, rope 64, kv_lora 512, v 128); d 2,304; layer 1 dense
+    # FFN 9,216; 26 MoE layers of 256 experts 1,024 wide, top-8, 1 shared.
+    from repro.configs import SHAPES_BY_NAME
+
+    d, C, T = 2304, 524288, 32
+    Lm, H, Lk, Hk, dk = 7, 32, 20, 32, 128
+    hk = Hk * dk
+    wl = workload_for(_kimi(), SHAPES_BY_NAME["long_500k"])
+    mla = (d * H * 192 * Lm                        # full-rank query
+           + d * 576 * Lm                          # KV down
+           + 128 * 512 * Lm * H                    # W_UK, per head
+           + H * 576 * C * Lm                      # scores, per sequence
+           + H * C * 512 * Lm                      # context
+           + 512 * 128 * Lm * H                    # W_UV
+           + H * 128 * d * Lm)                     # output
+    kda = (d * 3 * hk * Lk                         # q, k, v
+           + d * (2 * dk + Hk) * Lk                # gate downs, beta
+           + dk * hk * 2 * Lk                      # gate ups
+           + 2 * dk * dk * Lk * Hk                 # [k; q] against S'
+           + hk * d * Lk)                          # output
+    ffn = (d * 9216 * 3                            # dense layer 1
+           + d * 256 * 26                          # router
+           + d * 1024 * 3 * 8 * 26                 # B k = 8 slots, 8 experts
+           + d * 1024 * 3 * 26                     # shared
+           + d * 163840)                           # head
+    assert mla + kda + ffn == 130902016000
+    assert wl.total_macs == T * 130902016000
+    assert len(wl.gemms) == 7 + 5 + 2 + 5 + 1 == 20
+    # electronic: 10 d-wide passes x 27 layers; softmax over the 7 MLA
+    # layers; KDA conv 4*3*4096 + decay and update 2*4096*128 + gates and
+    # norm 3*4096 over 20 layers; activations 9,216 + (8 + 1) * 1,024 x 26
+    elec = (d * 10 * 27 + H * C * 3 * Lm
+            + (4 * 3 * hk + 2 * hk * dk + 3 * hk) * Lk
+            + 9216 + 9 * 1024 * 26)
+    assert elec == 622080 + 352321536 + 22200320 + 9216 + 239616
+    assert wl.elec_ops == T * elec
+    # weights: embeddings 754,974,720; MLA 29,114,368 x 7; KDA 39,510,016
+    # x 20; dense FFN 63,700,992; each MoE layer 8 touched experts,
+    # the shared one and the router, 64,290,816 x 26; 4-bit
+    params = (2 * 163840 * d + 29114368 * 7 + 39510016 * 20 + 63700992
+              + 64290816 * 26)
+    assert params == 3484237824
+    assert wl.weight_bytes == T * params * 0.5
+    # off chip besides weights: activations 2 x 2,304; the latent cache
+    # of the 7 MLA layers only, 576 values a position; KDA state, 32 x
+    # 128 x 128 plus 3 x 3 x 4,096 conv values a layer, read and written
+    cache = C * Lm * 576 * 0.5
+    state = 2 * Lk * (Hk * dk * dk + 3 * 3 * hk) * 0.5
+    assert (cache, state) == (1056964608, 11223040)
+    assert wl.act_io_bytes == T * (d * 2 * 0.5 + cache + state)
+    assert wl.max_act_bytes == 9216 * 0.5
+
+
+def test_kimi_linear_params():
+    cfg = _kimi()
+    assert cfg.n_kda_layers == 20 and cfg.supports_long_context
+    # 3.48 B active of 49.1 B (published: 48B total, 3B active)
+    assert cfg.param_count() == 49122459648
+    assert cfg.active_param_count() == 3484237824
+
+
+def test_kda_family_with_no_kda_layer_lowers_as_mla_moe():
+    from repro.configs.base import LinearAttnConfig
+
+    hybrid = dataclasses.replace(MLA_CFG, family="kda_mla_moe",
+                                 linear_attn=LinearAttnConfig(
+                                     kda_layers=(), n_heads=2, head_dim=4))
+    for kind in ("decode", "prefill", "train"):
+        assert _wl(hybrid, kind) == _wl(MLA_CFG, kind), kind
+    assert hybrid.param_count() == MLA_CFG.param_count()
+
+
+def test_small_kda_hybrid_golden():
+    # 3 layers: KDA, KDA, MLA (MLA_CFG's widths); KDA H_k 2, d_k 4,
+    # conv 3. B = 2, CTX = 8, NT = 3 steps.
+    from repro.configs.base import LinearAttnConfig
+
+    cfg = dataclasses.replace(MLA_CFG, family="kda_mla_moe",
+                              linear_attn=LinearAttnConfig(
+                                  kda_layers=(1, 2), n_heads=2, head_dim=4,
+                                  conv_kernel=3))
+    wl = _wl(cfg, "decode")
+    H, qd = 2, 6
+    mla = (B * 8 * 6 + B * 6 * (H * qd) + B * 8 * 7 + B * 4 * 5 * H
+           + H * 7 * CTX * B + H * CTX * 5 * B + B * 5 * 4 * H
+           + B * (H * 4) * 8)                      # one MLA layer
+    kda = 2 * (B * 8 * 24 + B * 8 * (8 + 2) + 2 * B * 4 * 8
+               + 2 * 4 * 4 * B * 2 + B * 8 * 8)    # two KDA layers
+    moe = (B * 8 * 16 * 3                          # dense FFN, layer 1
+           + 2 * (B * 8 * 4                        # router, 2 MoE layers
+                  + 3 * 8 * 8 * (2 * 1 + 1 * 2)))  # DECODE_SPLIT's rows
+    assert wl.total_macs == NT * (mla + kda + moe + B * 8 * VOCAB)
+    elec = (B * 8 * 10 * 3 + B * H * CTX * 3 * 1
+            + B * (3 * 3 * 8 + 2 * 8 * 4 + 3 * 8) * 2
+            + B * 16 + B * 2 * 8 * 2)
+    assert wl.elec_ops == NT * elec
+    cache = B * CTX * 1 * 7 * 0.5
+    state = 2 * B * 2 * (8 * 4 + 2 * 3 * 8) * 0.5
+    assert wl.act_io_bytes == NT * (B * 8 * 2 * 0.5 + cache + state)
+
+
+@pytest.mark.parametrize("kind", ["prefill", "train"])
+def test_kda_prefill_and_train_raise(kind):
+    from repro.configs import SHAPES_BY_NAME
+
+    shape = SHAPES_BY_NAME["prefill_32k" if kind == "prefill"
+                           else "train_4k"]
+    with pytest.raises(ValueError, match="chunked KDA form"):
+        workload_for(_kimi(), shape)
+
+
+def test_kimi_linear_long_500k_fits_the_int32_kernels():
+    from repro.configs import SHAPES_BY_NAME
+    from repro.core.performance_model import require_i32_dims
+
+    wl = workload_for(_kimi(), SHAPES_BY_NAME["long_500k"])
+    assert int(wl.gemm_array[:, :3].max()) == 524288
+    require_i32_dims(wl.gemm_array)
+
+
 # ---------------------------------------------------------------------------
 # hybrid_ssm
 # ---------------------------------------------------------------------------

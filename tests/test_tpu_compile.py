@@ -191,11 +191,29 @@ def test_dse_pareto_decoded_compiles_deepseek_v3_decode(compile_v5e):
     from repro.configs import get_config
     from repro.configs.base import ShapeConfig
     from repro.core.extract import workload_for
-    from repro.kernels.ops import _bucket_blocks
 
     wl = workload_for(get_config("deepseek-v3-671b"),
                       ShapeConfig("d", 32768, 32, "decode", new_tokens=32))
     assert len(wl.gemms) == 18
+    _pareto_decoded_compiles_24(compile_v5e, wl)
+
+
+def test_dse_pareto_decoded_compiles_kimi_linear_decode(compile_v5e):
+    # The Kimi-Linear decode cell's launch: 20 GEMM rows (KDA beside MLA
+    # layers, a 524,288-position context) over all of 24^5.
+    from repro.configs import SHAPES_BY_NAME
+    from repro.configs.archs import KIMI_LINEAR_48B
+    from repro.core.extract import workload_for
+
+    wl = workload_for(KIMI_LINEAR_48B, SHAPES_BY_NAME["long_500k"])
+    assert len(wl.gemms) == 20
+    _pareto_decoded_compiles_24(compile_v5e, wl)
+
+
+def _pareto_decoded_compiles_24(compile_v5e, wl):
+    """The decoded Pareto kernel over all of 24^5 for `wl` compiles."""
+    from repro.kernels.ops import _bucket_blocks
+
     fn = functools.partial(
         K.dse_pareto_decoded, radices=(24,) * 5,
         n_blocks=_bucket_blocks(24 ** 5, floor=8, block=K.BLOCK),

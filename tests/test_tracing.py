@@ -86,10 +86,46 @@ def test_workload_for_runs_inside_the_extract_span(monkeypatch):
 
     rec = _Recorder()
     monkeypatch.setattr(extract, "span", rec)
-    for kind in ("decode", "prefill", "train"):
-        extract.workload_for(get_config("qwen2.5-3b"),
-                             ShapeConfig("p", 64, 2, kind))
-    assert rec.named("extract") == [{}, {}, {}]
+    wls = [extract.workload_for(get_config("qwen2.5-3b"),
+                                ShapeConfig("p", 64, 2, kind))
+           for kind in ("decode", "prefill", "train")]
+    spans = rec.named("extract")
+    assert len(spans) == 3
+    for wl, stats in zip(wls, spans):
+        # a dense model's rows are all of the "other" kind
+        assert stats["rows_other"] == len(wl.gemms)
+        assert stats["rows_latent"] == stats["rows_linear"] == \
+            stats["rows_moe"] == stats["state_bytes"] == 0
+    assert spans[0]["cache_bytes"] > 0            # decode reads its cache
+    assert spans[1]["cache_bytes"] == spans[2]["cache_bytes"] == 0
+
+
+@pytest.mark.parametrize("arch", ["kimi-linear", "deepseek-v3-671b"])
+def test_extract_span_stats_add_up_to_the_workload(monkeypatch, arch):
+    from repro.configs import SHAPES_BY_NAME, get_config
+    from repro.configs.archs import KIMI_LINEAR_48B
+    from repro.core import extract
+
+    rec = _Recorder()
+    monkeypatch.setattr(extract, "span", rec)
+    cfg = KIMI_LINEAR_48B if arch == "kimi-linear" else get_config(arch)
+    shape = SHAPES_BY_NAME["long_500k" if arch == "kimi-linear"
+                           else "decode_32k"]
+    wl = extract.workload_for(cfg, shape)
+    stats, = rec.named("extract")
+    rows = {k: stats[f"rows_{k}"] for k in extract.ROW_KINDS}
+    assert sum(rows.values()) == len(wl.gemms)
+    if arch == "kimi-linear":
+        assert rows == {"latent": 7, "linear": 5, "moe": 5, "other": 3}
+        assert stats["state_bytes"] == 11223040
+    else:
+        assert rows == {"latent": 8, "linear": 0, "moe": 7, "other": 3}
+        assert stats["state_bytes"] == 0
+    # one step's activations in and out, cache and state: the act_io
+    # bytes of each of the shape's new tokens
+    step = (shape.global_batch * cfg.d_model * 2 * 0.5
+            + stats["cache_bytes"] + stats["state_bytes"])
+    assert wl.act_io_bytes == shape.new_tokens * step
 
 
 def _tiny_workload(name="t", gemms=None):
